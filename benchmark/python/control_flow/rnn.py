@@ -26,9 +26,8 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), *[".."] * 3))
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from common import pin_cpu_if_requested, timeit  # noqa: E402
+from common import timeit  # noqa: E402
 
-pin_cpu_if_requested()
 
 HIDDEN = 512
 

@@ -22,9 +22,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), *[".."] * 3))
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from common import pin_cpu_if_requested, timeit  # noqa: E402
-
-pin_cpu_if_requested()
+from common import timeit  # noqa: E402
 
 
 def _rand_csr(rows, cols, density, rng):

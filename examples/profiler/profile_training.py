@@ -15,11 +15,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 import mxnet_tpu as mx
 from mxnet_tpu import gluon, profiler
 from mxnet_tpu.gluon import nn

@@ -21,10 +21,6 @@ import numpy as _np
 
 from .base import MXNetError
 
-# (importing this module always executes the package __init__ first, which
-# re-asserts an explicit JAX_PLATFORMS=cpu choice — including in an
-# EMBEDDED interpreter booted by a plain-C host where no conftest runs)
-
 # the reference's dtype enum (python/mxnet/base.py _DTYPE_MX_TO_NP order,
 # mirrored by include/mxnet/ndarray.h)
 _DTYPE_MX_TO_NP = {0: _np.float32, 1: _np.float64, 2: _np.float16,

@@ -21,14 +21,14 @@ from .key import ExecutableKey
 from .manifest import (list_manifests, model_manifest_id, prefetch,
                        read_manifest, write_manifest)
 from .persist import cache_dir
-from .registry import (Registry, begin_touch_log, clear_staged, end_touch_log,
-                       get_or_build, instance_token, invalidate_tag,
-                       keys_since, lookup, mark, prefetch_paths, registry,
-                       reset, stats)
+from .registry import (Registry, begin_touch_log, clear_staged, compiled,
+                       end_touch_log, get_or_build, instance_token,
+                       invalidate_tag, keys_since, lookup, mark,
+                       prefetch_paths, registry, reset, stats)
 
 __all__ = [
     "ExecutableKey", "Registry", "registry", "get_or_build", "lookup",
-    "invalidate_tag", "reset", "stats", "mark", "keys_since",
+    "invalidate_tag", "reset", "stats", "compiled", "mark", "keys_since",
     "prefetch_paths", "clear_staged", "instance_token", "cache_dir",
     "begin_touch_log", "end_touch_log",
     "model_manifest_id", "write_manifest", "read_manifest", "prefetch",

@@ -30,6 +30,7 @@ any local user can dial.
 from __future__ import annotations
 
 import json
+import logging
 import os
 import pickle
 import time
@@ -37,6 +38,9 @@ import zlib
 
 from .. import env as _env
 from ..base import atomic_writer
+from ..telemetry import recorder as _tm_rec
+
+_LOG = logging.getLogger("mxnet_tpu.compile")
 
 __all__ = ["cache_dir", "artifact_path", "store", "load", "scan",
            "read_header", "prune", "MAGIC", "FORMAT"]
@@ -50,7 +54,7 @@ def cache_dir(create=False):
     """The persistent tier's directory from ``MXTPU_COMPILE_CACHE``
     (``1``/``on`` -> the repo-local ``.mxtpu_compile_cache`` default), or
     None when the tier is disabled. Read per call — arming the cache after
-    import (bench.py's post-dial pattern) just works."""
+    import (bench.py does, once it has found an accelerator) just works."""
     choice = _env.raw("MXTPU_COMPILE_CACHE") or ""
     if not choice or choice.lower() in _FALSY:
         return None
@@ -95,11 +99,23 @@ def store(directory, key, compiled, label=None, flops=None, memory=None):
     try:
         payload = pickle.dumps(_se.serialize(compiled),
                                protocol=pickle.HIGHEST_PROTOCOL)
-    except Exception:
+    except Exception as e:  # noqa: BLE001 — whatever the backend raises
+        # not stored, and said so: a tier that cannot serialise this
+        # backend's executables must not look like one that merely missed
+        _LOG.warning("compile cache: %s executable %r cannot be "
+                     "serialized, not stored: %r", backend, label, e)
+        _tm_rec.record_event("compile_persist_unserializable", op=label,
+                             backend=backend, error=repr(e)[:300])
         return None
     header = json.dumps({
         "format": FORMAT,
         "digest": digest,
+        # the devices the executable runs on, in its own order: jax 0.9
+        # loads a serialized executable onto EVERY device of the backend
+        # unless told, which a one-device program in an eight-device
+        # process (or on a four-chip host) then refuses to run on
+        "devices": [d.id for d in
+                    compiled.runtime_executable().local_devices()],
         "key": key.to_json(),
         "label": label,
         "jax": jaxver,
@@ -172,10 +188,14 @@ def load_path(path):
             header.get("backend") != _backend():
         return None, None, None
     try:
+        import jax
         from jax.experimental import serialize_executable as _se
 
+        by_id = {d.id: d for d in jax.devices()}
         payload_bytes, in_tree, out_tree = pickle.loads(payload)
-        fn = _se.deserialize_and_load(payload_bytes, in_tree, out_tree)
+        fn = _se.deserialize_and_load(
+            payload_bytes, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in header["devices"]])
     except Exception:
         return None, None, None
     return fn, header.get("flops"), header.get("memory")

@@ -46,7 +46,8 @@ from ..telemetry import tracing as _tracing
 from . import persist as _persist
 
 __all__ = ["Registry", "registry", "get_or_build", "lookup", "invalidate_tag",
-           "reset", "stats", "mark", "keys_since", "prefetch_paths",
+           "reset", "stats", "compiled", "mark", "keys_since",
+           "prefetch_paths",
            "clear_staged", "instance_token", "begin_touch_log",
            "end_touch_log"]
 
@@ -521,6 +522,16 @@ class Registry:
         return n
 
     # -- introspection -----------------------------------------------------
+    def compiled(self, key):
+        """The `jax.stages.Compiled` behind an AOT-filled memory entry
+        (sharded/donating fills, persistent-tier stores and loads), or
+        None — for callers that read the program itself (`as_text()`:
+        which kernels and collectives the compiler put in)."""
+        value = self._table.get(key)
+        if getattr(value, "_mxtpu_aot", False):
+            return value._fn
+        return None
+
     def stats(self):
         with self._lock:
             return {
@@ -582,6 +593,10 @@ def reset():
 
 def stats():
     return registry().stats()
+
+
+def compiled(key):
+    return registry().compiled(key)
 
 
 def mark():

@@ -51,10 +51,7 @@ def wait_all():
     import jax
 
     (jax.device_put(0) + 0).block_until_ready()
-    try:
-        jax.effects_barrier()
-    except Exception:  # pragma: no cover - older jax
-        pass
+    jax.effects_barrier()
 
 
 def set_bulk_size(size):

@@ -1145,9 +1145,14 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
     lengths (B,) int32 — tokens [0, lengths[b]) of sequence b are live,
     laid out page_tables[b, t // page_size] slot t % page_size. A row
     with length 0 returns zeros-ish garbage that callers mask out (its
-    scores are uniformly _NEG_INF, which is finite by design — no NaNs)."""
+    scores are uniformly _NEG_INF, which is finite by design — no NaNs).
+    Both contractions run at HIGHEST precision: an oracle whose f32
+    scores the MXU rounded to bf16 could not tell a right kernel from a
+    wrong one on the chip."""
+    import jax
     import jax.numpy as jnp
 
+    hi = jax.lax.Precision.HIGHEST
     b, h, d = q.shape
     ps = k_pages.shape[2]
     maxp = page_tables.shape[1]
@@ -1156,12 +1161,12 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
     k = jnp.moveaxis(k, 2, 1).reshape(b, h, maxp * ps, d)
     v = jnp.moveaxis(v, 2, 1).reshape(b, h, maxp * ps, d)
     s = jnp.einsum("bhd,bhld->bhl", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * sm_scale
+                   k.astype(jnp.float32), precision=hi) * sm_scale
     ids = jnp.arange(maxp * ps)[None, None, :]
     s = jnp.where(ids < lengths[:, None, None], s, _NEG_INF)
     p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    o = jnp.einsum("bhl,bhld->bhd", p, v.astype(jnp.float32))
+    o = jnp.einsum("bhl,bhld->bhd", p, v.astype(jnp.float32), precision=hi)
     return o.astype(q.dtype)
 
 
@@ -1183,9 +1188,10 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[0].astype(jnp.float32) * sm_scale          # (Hp, Dp)
     k = k_ref[0].astype(jnp.float32)                     # (Hp, ps, Dp)
     v = v_ref[0].astype(jnp.float32)
-    # per-head scores against this page: batch dim = head, contract = D
-    s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)  # (Hp, ps)
+    # per-head scores against this page on the VPU: one query row per head
+    # leaves the MXU nothing to tile, and Mosaic refuses a batched dot whose
+    # lhs has no free dimension (interpret mode accepts it)
+    s = jnp.sum(q[:, None, :] * k, axis=-1)              # (Hp, ps)
     col = j * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(col < len_ref[b], s, _NEG_INF)
     m = m_scr[:, 0:1]
@@ -1194,9 +1200,7 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     alpha = jnp.exp(m - new_m)
     p = jnp.exp(s - new_m)
     l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)              # (Hp, Dp)
+    acc = acc_scr[...] * alpha + jnp.sum(p[:, :, None] * v, axis=1)
     m_scr[...] = jnp.broadcast_to(new_m, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l, l_scr.shape)
     acc_scr[...] = acc

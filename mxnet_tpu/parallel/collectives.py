@@ -201,28 +201,7 @@ def _enable_cpu_collectives(jax):
              or "")
     if "cpu" not in [p.strip() for p in plats.split(",")]:
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-    except Exception:
-        pass  # config absent on this jax: keep the old single-process-only
-        # behavior rather than failing the rendezvous
-
-
-def _group_initialized(jax):
-    """Is the jax.distributed client already up? `jax.distributed
-    .is_initialized` only exists on newer jax; older releases (this image's
-    0.4.37 included) expose the state via the module-level singleton. This
-    gap made init_process_group raise on EVERY multi-process worker — the
-    five seed test_dist_kvstore failures."""
-    if hasattr(jax.distributed, "is_initialized"):
-        return jax.distributed.is_initialized()
-    try:
-        from jax._src import distributed as _dist
-
-        state = getattr(_dist, "global_state", None)
-        return state is not None and state.client is not None
-    except Exception:
-        return False
+    jax.config.update("jax_cpu_collectives_implementation", impl)
 
 
 def init_process_group(coordinator_address=None, num_processes=None,
@@ -280,7 +259,7 @@ def init_process_group(coordinator_address=None, num_processes=None,
         process_id = _env_int("MXTPU_PROCESS_ID", "DMLC_WORKER_ID",
                               "OMPI_COMM_WORLD_RANK", "PMI_RANK",
                               "SLURM_PROCID")
-    if _group_initialized(jax):
+    if jax.distributed.is_initialized():
         return  # idempotent re-entry
     if timeout is None:
         # registry default 300; explicit 0 means "fail immediately"

@@ -110,10 +110,7 @@ def pipeline_apply(stage_fn, stacked_params, x, num_microbatches=None,
     """
     import jax
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax: only the experimental location exists
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .mesh import current_mesh
@@ -147,13 +144,8 @@ def pipeline_apply(stage_fn, stacked_params, x, num_microbatches=None,
     pspec = jax.tree_util.tree_map(
         lambda _: P(axis_name), stacked_params)
     body = functools.partial(_pipeline_loop, fn, axis_name=axis_name)
-    try:
-        smapped = shard_map(body, mesh=mesh,
-                            in_specs=(pspec, tuple(data_spec for _ in xs)),
-                            out_specs=data_spec, check_vma=False)
-    except TypeError:  # pre-0.9 jax uses check_rep
-        smapped = shard_map(body, mesh=mesh,
-                            in_specs=(pspec, tuple(data_spec for _ in xs)),
-                            out_specs=data_spec, check_rep=False)
+    smapped = shard_map(body, mesh=mesh,
+                        in_specs=(pspec, tuple(data_spec for _ in xs)),
+                        out_specs=data_spec, check_vma=False)
     out = smapped(stacked_params, xs)
     return out.reshape((b,) + x.shape[1:])

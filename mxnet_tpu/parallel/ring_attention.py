@@ -93,10 +93,7 @@ def ring_attention_sharded(q, k, v, mesh=None, axis_name="sp", causal=False,
     import jax
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from .mesh import current_mesh
 
@@ -107,12 +104,8 @@ def ring_attention_sharded(q, k, v, mesh=None, axis_name="sp", causal=False,
 
     body = functools.partial(ring_attention, axis_name=axis_name,
                              causal=causal, scale=scale)
-    try:
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_vma=False)
-    except TypeError:  # pre-0.9 jax uses check_rep
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
+    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                   out_specs=spec, check_vma=False)
     if seq is None:
         raise ValueError(f"mesh {mesh.axis_names} has no '{axis_name}' axis")
     return fn(q, k, v)

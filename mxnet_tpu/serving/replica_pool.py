@@ -224,9 +224,17 @@ class ReplicaPool:
         # snapshot even while a resize is landing; slot/gauge creation
         # holds the lock for the same discipline add_replica follows
         self._slots = []
-        with self._lock:
-            for _ in range(replicas):
-                self._slots = self._slots + [self._new_slot()]
+        try:
+            with self._lock:
+                for _ in range(replicas):
+                    self._slots = self._slots + [self._new_slot()]
+        except MXNetError:
+            # more chip-owning replicas than the host has chips: give back
+            # what the earlier slots took and fail the load
+            for slot in self._slots:
+                slot.proc.close()
+            self._listener.close()
+            raise
 
         self._accept_thread = threading.Thread(
             target=self._accept_loop, daemon=True,
@@ -427,7 +435,7 @@ class ReplicaPool:
                 send_msg(conn, {"kind": "shutdown"})
             except OSError:
                 pass
-        slot.proc.teardown()
+        slot.proc.close()
         if conn is not None:
             try:
                 conn.close()
@@ -838,7 +846,7 @@ class ReplicaPool:
                     send_msg(conn, {"kind": "shutdown"})
                 except OSError:
                     pass
-            slot.proc.teardown()
+            slot.proc.close()
             if conn is not None:
                 try:
                     conn.close()

@@ -67,7 +67,9 @@ import sys
 import threading
 import time
 
+from .. import chip_binding as _chips
 from .. import env as _env
+from ..base import MXNetError
 
 _LOG = logging.getLogger("mxnet_tpu.serving.supervisor")
 
@@ -187,6 +189,45 @@ def _pump(stream, label):
     stream.close()
 
 
+class _ChipSlots:
+    """Which of this host's TPU chips the process has handed to replica
+    workers (`chip_binding`: a chip belongs to one process). Process-wide:
+    every pool of the router draws from the same chips."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._owners = {}   # chip -> label of the replica bound to it
+
+    def acquire(self, label):
+        if _chips.parent_holds_tpu():
+            raise MXNetError(
+                "replica %s needs a TPU chip of its own, but this process "
+                "already holds the host's TPU (it ran a jax computation "
+                "there). A chip belongs to one process: keep the router "
+                "off the chips (JAX_PLATFORMS=cpu) when it serves through "
+                "replica workers" % label)
+        n = _chips.host_chip_count()
+        with self._lock:
+            for chip in range(n):
+                if chip not in self._owners:
+                    self._owners[chip] = label
+                    return chip
+            raise MXNetError(
+                "replica %s needs a TPU chip of its own, but all %d of "
+                "this host are bound (%s). A chip belongs to one process: "
+                "ask for fewer replicas, or pin the workers to the CPU "
+                "(extra_env={'JAX_PLATFORMS': 'cpu'})"
+                % (label, n, ", ".join(
+                    "%d: %s" % kv for kv in sorted(self._owners.items()))))
+
+    def release(self, chip):
+        with self._lock:
+            self._owners.pop(chip, None)
+
+
+_CHIP_SLOTS = _ChipSlots()
+
+
 class ReplicaProcess:
     """Spawn/teardown state for one replica slot.
 
@@ -208,6 +249,14 @@ class ReplicaProcess:
         self.generation = -1  # no spawn yet
         self.proc = None
         self._pump_thread = None
+        # a worker that would claim a TPU is told which chip is its own,
+        # for the life of the slot (close() gives it back); a slot the
+        # host has no free chip for fails HERE, in the caller's thread
+        self.chip = None
+        if "TPU_VISIBLE_CHIPS" not in self.extra_env \
+                and _chips.owns_chip({**os.environ, **self.extra_env}):
+            self.chip = _CHIP_SLOTS.acquire(
+                "%s/r%d" % (self.model, self.replica_id))
 
     @property
     def pid(self):
@@ -236,6 +285,8 @@ class ReplicaProcess:
             os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = repo_root + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+        if self.chip is not None:
+            env.update(_chips.replica_env(self.chip))
         argv = [sys.executable, "-m", "mxnet_tpu.serving.replica_worker",
                 "--connect", "%s:%d" % self.connect_addr,
                 "--replica", str(self.replica_id),
@@ -255,6 +306,14 @@ class ReplicaProcess:
     def teardown(self):
         if self.proc is not None:
             teardown(self.proc, self.teardown_grace)
+
+    def close(self):
+        """Final teardown of the slot: the worker dies and its chip goes
+        back to the host's free chips."""
+        self.teardown()
+        if self.chip is not None:
+            _CHIP_SLOTS.release(self.chip)
+            self.chip = None
 
     def exit_code(self):
         return self.proc.poll() if self.proc is not None else None

@@ -52,7 +52,7 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 _STEP_FLOPS = [None]     # model FLOPs per optimizer step (fwd+bwd), if known
-_PEAK_FLOPS = [False]    # False = not yet resolved; None = unknown chip
+_PEAK_FLOPS = [False]    # False = not yet resolved; None = CPU (no MFU)
 
 
 def set_step_flops(flops):
@@ -70,22 +70,20 @@ if _env.is_set("MXTPU_STEP_FLOPS"):
 
 
 def _peak_flops():
-    """Aggregate peak bf16 FLOP/s of the local devices (cached; None when
-    the chip is unknown — e.g. CPU test runs)."""
+    """Aggregate peak bf16 FLOP/s of the local devices (cached). None on
+    the CPU backend, which has no MFU; an accelerator whose `device_kind`
+    is missing from `runtime.PEAK_BF16_TFLOPS` raises."""
     if _PEAK_FLOPS[0] is False:
-        peak = None
-        try:
-            import jax
+        import jax
 
-            from .. import runtime
+        from .. import runtime
 
-            devs = jax.devices()
-            per_chip = runtime.chip_peak_tflops(devs[0])
-            if per_chip:
-                peak = per_chip * 1e12 * len(devs)
-        except Exception:
-            peak = None
-        _PEAK_FLOPS[0] = peak
+        devs = jax.devices()
+        if devs[0].platform == "cpu":
+            _PEAK_FLOPS[0] = None
+        else:
+            _PEAK_FLOPS[0] = (runtime.chip_peak_tflops(devs[0]) * 1e12
+                              * len(devs))
     return _PEAK_FLOPS[0]
 
 

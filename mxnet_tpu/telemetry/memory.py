@@ -392,9 +392,9 @@ def sample_devices():
     gracefully, once (the capability is cached). NEVER called from the
     signal path (the dump reads the cached last sample), and NEVER the
     first thing to touch the backend: a telemetry flusher/scrape thread
-    must not initialize XLA — or block on a wedged accelerator dial, the
-    failure class `runtime.dial_devices` bounds — so sampling waits
-    until some real computation has already brought the backend up."""
+    must not initialize XLA (and with it claim the chip), so sampling
+    waits until some real computation has already brought the backend
+    up."""
     if _STATE.caps is False or not enabled():
         return _STATE.devices if _STATE.caps else None
     if "jax" not in sys.modules:

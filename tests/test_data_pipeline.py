@@ -385,7 +385,6 @@ def _run_stream_fit(ckpt_dir, rec_dir, resume=None):
 _STREAM_FIT_BODY = r"""
 import sys
 sys.path.insert(0, %(root)r)
-import jax; jax.config.update("jax_platforms", "cpu")
 from test_data_pipeline import _run_stream_fit
 resume = sys.argv[3] if len(sys.argv) > 3 else None
 print("FIT_DONE wsum=%%.17g"

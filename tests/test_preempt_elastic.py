@@ -138,7 +138,6 @@ def test_sharded_and_plain_formats_refuse_each_other(tmp_path):
 _KILL_CKPT_BODY = r"""
 import os, sys
 sys.path.insert(0, %(root)r)
-import jax; jax.config.update("jax_platforms", "cpu")
 from mxnet_tpu.parallel.resilience import CheckpointManager
 mgr = CheckpointManager(sys.argv[2], keep_last=4)
 if sys.argv[1] == "plain":
@@ -184,7 +183,6 @@ def test_kill_during_ckpt_crash_consistency(tmp_path, fmt):
 _PREEMPT_BODY = r"""
 import os, signal, sys
 sys.path.insert(0, %(root)r)
-import jax; jax.config.update("jax_platforms", "cpu")
 from mxnet_tpu.parallel import resilience
 assert resilience.install_preemption_handler()
 assert not resilience.preemption_requested()
@@ -281,7 +279,6 @@ def test_launcher_preempt_without_budget_fails_fast(tmp_path):
 _FIT_BODY = r"""
 import sys
 sys.path.insert(0, %(root)r)
-import jax; jax.config.update("jax_platforms", "cpu")
 from test_preempt_elastic import _run_fit
 print("FIT_DONE wsum=%%.8f" %% _run_fit(sys.argv[1], resume="auto"),
       flush=True)

@@ -51,7 +51,7 @@ def _group_support():
     tests WITH the probe's diagnostic instead of timing out for minutes."""
     global _GROUP_PROBE
     if _GROUP_PROBE is None:
-        body = ("import jax; jax.config.update('jax_platforms','cpu');"
+        body = ("import jax;"
                 "from mxnet_tpu.parallel import collectives;"
                 "collectives.init_process_group();"
                 "assert jax.process_count()==2; print('GROUP_PROBE_OK')")
@@ -361,8 +361,7 @@ def test_rendezvous_timeout_is_bounded(tmp_path):
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]  # freed on close; nobody will serve it
-    body = ("import jax; jax.config.update('jax_platforms','cpu');"
-            "from mxnet_tpu.parallel import collectives;"
+    body = ("from mxnet_tpu.parallel import collectives;"
             "collectives.init_process_group()")
     timeout_s = 8
     t0 = time.time()

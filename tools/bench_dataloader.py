@@ -44,10 +44,6 @@ class JpegDS:
 
 
 def main():
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     from PIL import Image
 
     from mxnet_tpu.gluon.data import DataLoader

@@ -38,9 +38,9 @@ import re
 import sys
 import time
 
-# the row group is LAZY so a trailing `_stale` relabel (bench_capture.sh
-# dial-failure path) lands in the stale group instead of being swallowed
-# into the row name — stale captures must render as stale
+# the row group is LAZY so a trailing `_stale` relabel (committed captures
+# from before PR 23 carry it) lands in the stale group instead of being
+# swallowed into the row name — stale captures must render as stale
 _NAME_RE = re.compile(r"BENCH_(?:(?P<scope>local)_)?r(?P<round>\d+)"
                       r"(?:_(?P<row>[A-Za-z0-9_]+?))?(?P<stale>_stale)?"
                       r"\.json$")

@@ -105,7 +105,6 @@ def main(argv=None):
                    help="per-run spawn->ready budget (seconds)")
     args = p.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     # the bench process itself must not populate the cache the COLD run
     # is supposed to find empty
     os.environ.pop("MXTPU_COMPILE_CACHE", None)

@@ -418,17 +418,52 @@ def _spawn_and_wait(make_cmds, max_restarts=0, backoff=1.0):
         delay = min(max(delay, 0.5) * 2, 60.0)
 
 
+def _chip_binding():
+    """mxnet_tpu/chip_binding.py, loaded by file path: importing it through
+    the package would pull in the framework and jax, which the launcher
+    must never pay for (or depend on) just to supervise processes."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "mxnet_tpu", "chip_binding.py")
+    spec = importlib.util.spec_from_file_location("_chip_binding", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def _launch_local(args):
+    n = args.num_workers
+    chips = _chip_binding()
+    # A TPU chip belongs to one process. One local rank keeps the whole
+    # host (it drives every chip through a mesh); several ranks that would
+    # each claim the TPU get one chip each, as ONE TPU system, and more of
+    # them than the host has chips is refused here instead of dying in
+    # libtpu's lockfile one by one.
+    rank_env = dict(os.environ)
+    rank_env.update(kv.partition("=")[::2] for kv in args.env)
+    bind = n > 1 and chips.owns_chip(rank_env)
+    if bind:
+        try:
+            chips.group_env(0, n, [0] * n)
+        except ValueError as e:   # more ranks than chips, or no such box
+            _log("%s. Use a rank count that fits (one rank drives every "
+                 "chip through a mesh), or pin the ranks to the CPU with "
+                 "--env JAX_PLATFORMS=cpu" % e)
+            return 2
+
     def make_cmds(generation):
         # fresh port per generation: --port pins one (the old coordinator is
         # dead by restart time, so rebinding it is safe), else probe anew
         port = args.port or _free_port()
         coord = "127.0.0.1:%d" % port
+        tpu_ports = [_free_port() for _ in range(n)] if bind else None
         cmds = []
-        for rank in range(args.num_workers):
+        for rank in range(n):
             env = dict(os.environ)
-            env.update(_protocol_env(args.num_workers, coord, args.env, rank,
-                                     generation))
+            if bind:
+                env.update(chips.group_env(rank, n, tpu_ports))
+            env.update(_protocol_env(n, coord, args.env, rank, generation))
             cmds.append((args.command, env, "rank %d" % rank))
         return cmds
 

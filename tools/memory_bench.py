@@ -45,7 +45,6 @@ def main(argv=None):
     p.add_argument("--max-batch", type=int, default=8)
     args = p.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     workdir = tempfile.mkdtemp(prefix="memory_bench_")
     # armed persistent tier: memory figures come from the AOT fill hook
     # and survive in the MXTPUEXE1 headers

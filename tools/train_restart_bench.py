@@ -146,7 +146,6 @@ def _spawn_run(tag, steps, cache_dir, workdir, timeout_s):
     os.makedirs(tdir, exist_ok=True)
     env = dict(os.environ, MXTPU_COMPILE_CACHE=cache_dir,
                MXTPU_TELEMETRY_DIR=tdir, PYTHONPATH=_ROOT)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     r = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--worker",
          "--steps", str(steps)],
@@ -255,7 +254,6 @@ def _steps_lost(save_period, preempt_step):
 
 
 def _preempt_main(args):
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sync, asyn, payload_bytes = _preempt_ab(args.save_period, args.saves,
                                             args.payload_mb, args.step_ms)
     reduction = (sync["per_save_stall_s"] / asyn["per_save_stall_s"]
@@ -328,7 +326,6 @@ def main(argv=None):
     if args.mode == "preempt":
         return _preempt_main(args)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     # the bench process itself never trains; nothing here may seed the
     # cache the COLD life must find empty
     workdir = tempfile.mkdtemp(prefix="train_restart_bench_")
