@@ -201,7 +201,8 @@ class Trainer:
                 "instead of record()/backward()/step() "
                 "(docs/sharded_training.md)")
         t0 = time.perf_counter()
-        telemetry.goodput.step_start(kind="train", t0=t0)
+        telemetry.goodput.step_start(kind="train", t0=t0,
+                                     step=self._step_count + 1)
         # distributed tracing: a sampled step records allreduce/optimizer
         # phase spans (no-op span when tracing is unarmed)
         with telemetry.tracing.root("train.step", component="train",

@@ -297,7 +297,8 @@ class BaseModule(object):
                 # goodput bracket opens back-dated to t_wait (the iterator
                 # wait belongs to the step) but only after a successful
                 # next() — StopIteration must not leave a dangling bracket
-                telemetry.goodput.step_start(kind="fit", t0=t_wait)
+                telemetry.goodput.step_start(kind="fit", t0=t_wait,
+                                             step=fit_updates + 1)
                 telemetry.goodput.add("data_wait", t_step - t_wait)
                 if monitor is not None:
                     monitor.tic()

@@ -124,6 +124,7 @@ def _fwd_compiled(shape_key):
 
     call = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         out_shape=(jax.ShapeDtypeStruct((bh, lq_pad, d), _np.dtype(dtype)),
                    jax.ShapeDtypeStruct((bh, 8, lq_pad), _np.float32)),
         grid=(bh, n_q),
@@ -262,6 +263,7 @@ def _bwd_compiled(shape_key):
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           lk=lk, block_q=block_q, block_k=block_k,
                           n_kblocks=n_k),
+        name="flash_attention_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((bh, lq_pad, d), _np.dtype(dtype)),
         grid=(bh, n_q),
         in_specs=[
@@ -287,6 +289,7 @@ def _bwd_compiled(shape_key):
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           lq=lq, lk=lk, block_q=block_q, block_k=block_k,
                           n_qblocks=n_q),
+        name="flash_attention_bwd_dkv",
         out_shape=(jax.ShapeDtypeStruct((bh, lk_pad, d), _np.dtype(dtype)),
                    jax.ShapeDtypeStruct((bh, lk_pad, d), _np.dtype(dtype))),
         grid=(bh, n_k),
@@ -547,6 +550,7 @@ def _lstm_infer_compiled(key):
 
     return pl.pallas_call(
         functools.partial(_lstm_infer_kernel, hp=hp),
+        name="lstm_layer_infer",
         grid=(nt,),
         in_specs=[
             pl.BlockSpec((1, bp, 4 * hp), lambda t: (t, 0, 0),
@@ -584,6 +588,7 @@ def _lstm_fwd_compiled(key):
 
     return pl.pallas_call(
         functools.partial(_lstm_fwd_kernel, hp=hp),
+        name="lstm_layer_fwd",
         grid=(nt,),
         in_specs=[
             pl.BlockSpec((1, bp, 4 * hp), lambda t: (t, 0, 0),
@@ -625,6 +630,7 @@ def _lstm_bwd_compiled(key):
     rev = lambda rt: (nt - 1 - rt, 0, 0)
     return pl.pallas_call(
         functools.partial(_lstm_bwd_kernel, nt=nt, hp=hp),
+        name="lstm_layer_bwd",
         grid=(nt,),
         in_specs=[
             pl.BlockSpec((1, bp, hp), rev, memory_space=pltpu.VMEM),    # dy
@@ -902,6 +908,7 @@ def _epi_stats_compiled(key):
     rb, cp, n_blocks, rp, row_spec, vec_spec, vec_shape = _epi_specs(r, c)
     return pl.pallas_call(
         functools.partial(_epi_stats_kernel, rb=rb, r=r),
+        name="conv_epilogue_stats",
         grid=(n_blocks,),
         in_specs=[row_spec, vec_spec],
         out_shape=(vec_shape, vec_shape),
@@ -919,6 +926,7 @@ def _epi_apply_compiled(key):
     rb, cp, n_blocks, rp, row_spec, vec_spec, _ = _epi_specs(r, c)
     return pl.pallas_call(
         functools.partial(_epi_apply_kernel, relu=relu, has_res=has_res),
+        name="conv_epilogue_apply",
         grid=(n_blocks,),
         in_specs=[row_spec] * (2 if has_res else 1) + [vec_spec, vec_spec],
         out_shape=jax.ShapeDtypeStruct((rp, cp), _np.dtype(dtype)),
@@ -935,6 +943,7 @@ def _epi_reduce_compiled(key):
     rb, cp, n_blocks, rp, row_spec, vec_spec, vec_shape = _epi_specs(r, c)
     return pl.pallas_call(
         functools.partial(_epi_bwd_reduce_kernel, rb=rb, r=r, relu=relu),
+        name="conv_epilogue_bwd_reduce",
         grid=(n_blocks,),
         in_specs=[row_spec] * (3 if relu else 2) + [vec_spec, vec_spec],
         out_shape=(vec_shape, vec_shape),
@@ -953,6 +962,7 @@ def _epi_dx_compiled(key):
     dx_out = jax.ShapeDtypeStruct((rp, cp), _np.dtype(dtype))
     return pl.pallas_call(
         functools.partial(_epi_bwd_dx_kernel, relu=relu, has_res=has_res),
+        name="conv_epilogue_bwd_dx",
         grid=(n_blocks,),
         in_specs=[row_spec] * (3 if relu else 2) + [vec_spec] * 5,
         out_shape=(dx_out, dx_out) if has_res else dx_out,
@@ -1247,6 +1257,7 @@ def _paged_compiled(key):
     call = pl.pallas_call(
         functools.partial(_paged_kernel, sm_scale=sm_scale, ps=ps,
                           n_pages=maxp),
+        name="paged_attention_decode",
         out_shape=jax.ShapeDtypeStruct((b, hp, dp), _np.dtype(dtype)),
         grid_spec=grid_spec,
         interpret=interpret,

@@ -440,7 +440,8 @@ class PipelineTrainer:
         t0 = _time.perf_counter()
         from .. import telemetry as _telemetry
 
-        _telemetry.goodput.step_start(kind="pipeline", t0=t0)
+        _telemetry.goodput.step_start(kind="pipeline", t0=t0,
+                                      step=self._step_count + 1)
         if self._loss is not None and len(batch) < 2:
             raise MXNetError("step(*inputs, label) needs a label for the "
                              "configured loss")

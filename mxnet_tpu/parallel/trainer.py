@@ -359,7 +359,8 @@ class DistributedTrainer:
         t0 = _time.perf_counter()
         from .. import telemetry as _telemetry
 
-        _telemetry.goodput.step_start(kind="dist", t0=t0)
+        _telemetry.goodput.step_start(kind="dist", t0=t0,
+                                      step=self._step_count + 1)
         if self._loss is not None and label is None:
             raise MXNetError("this trainer was built with a loss that takes "
                              "(pred, label); step() needs a label argument")

@@ -60,6 +60,7 @@ import json
 import logging
 import math
 import os
+import statistics
 import threading
 import time
 
@@ -69,6 +70,7 @@ from .. import compile as _compile
 from .. import env as _env
 from .. import random as _random
 from .. import telemetry
+from ..telemetry import goodput as _goodput
 from ..telemetry import slo as _slo
 from ..base import MXNetError
 from ..telemetry import tracing as _tracing
@@ -192,7 +194,7 @@ class GenRequest:
         self.trace = trace if trace is not None else _tracing.capture()
         self._event = threading.Event()
         self._rlock = threading.Lock()
-        self._t_submit = time.monotonic()
+        self._t_submit = time.perf_counter()
 
     def done(self):
         return self._event.is_set()
@@ -202,7 +204,7 @@ class GenRequest:
         if not self._event.is_set():
             raise DeadlineExceededError(
                 "generation expired after %.0f ms"
-                % ((time.monotonic() - self._t_submit) * 1e3))
+                % ((time.perf_counter() - self._t_submit) * 1e3))
         if self.error is not None:
             raise self.error
         return self.outputs
@@ -241,7 +243,7 @@ class _Sequence:
         self.page_row = page_row
         self.pos = pos            # position of the NEXT token to feed
         self.generated = [first_token]
-        self.t_last = time.monotonic()
+        self.t_last = time.perf_counter()
         self.n_steps = 0
 
 
@@ -264,6 +266,15 @@ class GenerateScheduler:
          deadline resolve immediately and return their pages — the next
          lap's admissions reuse them. Requests join and leave at step
          granularity; nobody waits for the longest sequence in the batch.
+
+    Every lap is one ``serve`` bracket of the phase accountant
+    (`telemetry.goodput`, phases `goodput.SERVE_PHASES`): its phases are
+    annotations in the profiler's trace and histograms here, its whole
+    record joins ``goodput.window("serve")``, and a lap that stalls
+    writes itself down (docs/observability.md §Lap phases). The ``cv.wait``
+    with nothing queued and nothing active is outside every lap. Durations
+    are stamped on ``time.perf_counter()``, the accountant's clock;
+    deadlines are the callers', on ``time.monotonic()``.
 
     The engine must be single-threaded-driven; only the worker thread
     (plus `close` after joining it) touches it.
@@ -314,6 +325,18 @@ class GenerateScheduler:
             bounds=(.001, .0025, .005, .01, .025, .05, .1, .25, .5, 1., 2.5))
         self._m_prefill = telemetry.histogram("mxtpu_serve_prefill_seconds",
                                               labels)
+        self._m_decode = telemetry.histogram(
+            "mxtpu_serve_decode_step_seconds", labels)
+        self._m_queue_s = telemetry.histogram("mxtpu_serve_queue_seconds",
+                                              labels)
+        # where a lap goes; a histogram's sum is the phase's cumulative
+        # seconds
+        self._m_lap = {
+            p: telemetry.histogram("mxtpu_serve_lap_phase_seconds",
+                                   {"model": self.name, "phase": p})
+            for p in _goodput.SERVE_PHASES}
+        self._lap = None             # the open lap's counts
+        self._slow_lap_logged = 0.0  # perf_counter of the last warning
         # built-in generation SLOs: inter-token p99 + KV-occupancy
         # ceiling + admission-queue ceiling (docs/observability.md §SLOs)
         _slo.wire_generate_objectives(self.name,
@@ -452,8 +475,12 @@ class GenerateScheduler:
                     self._cv.wait(0.05)
                 if self._stop:
                     return
+            _goodput.step_start(kind="serve")
+            self._lap = {"n": 0, "bucket": 0, "prefills": 0, "admitted": 0,
+                         "queue_wait_s": 0.0}
             try:
-                self._admit()
+                with _goodput.phase("admit"):
+                    self._admit()
                 if self._active:
                     self._step()
             except Exception as e:  # the lone decode worker must not die
@@ -469,24 +496,52 @@ class GenerateScheduler:
                     self.allocator.free(seq.pages)
                     seq.req._resolve(error=err)
                 self._m_active.set(0)
+            lap = _goodput.step_end(model=self.name, **self._lap)
+            if lap is not None:
+                self._observe_lap(lap)
+
+    def _observe_lap(self, lap):
+        """Publish a closed lap's phases, and write a stalled one down: a
+        lap longer than both 1 s and five times the ring's median lap is
+        one event and one log line (at most a line a second), so a run that
+        holds a stall says which phase of which lap it was."""
+        wall = lap.pop("wall")
+        for p, v in lap.items():
+            self._m_lap[p].observe(v)
+        if wall <= 1.0:
+            return
+        ring = _goodput.window("serve")
+        median = statistics.median(r["t1"] - r["t0"] for r in ring)
+        now = time.perf_counter()
+        if wall <= 5.0 * median or now - self._slow_lap_logged < 1.0:
+            return
+        self._slow_lap_logged = now
+        cpu_s = next(r["cpu_s"] for r in reversed(ring)
+                     if r["model"] == self.name)
+        fields = dict(self._lap, lap_s=round(wall, 4),
+                      median_lap_s=round(median, 4), cpu_s=round(cpu_s, 4),
+                      phases={p: round(v, 4) for p, v in lap.items()})
+        telemetry.record_event("serve_slow_lap", model=self.name, **fields)
+        _LOG.warning("slow decode lap on %r: %s", self.name, fields)
 
     def _admit(self):
         """Pop waiting requests while batch slots + worst-case pages are
         available and run their prefill — the join-mid-decode half of
         continuous batching."""
+        lap = self._lap
         while len(self._active) < self.max_active:
             with self._cv:
                 if not self._queue:
                     break
                 req = self._queue[0]
-                now = time.monotonic()
-                if req.deadline is not None and now >= req.deadline:
+                if req.deadline is not None \
+                        and time.monotonic() >= req.deadline:
                     self._queue.popleft()
                     self._m_queue.set(len(self._queue))
                     self._m_rej_dead.inc()
                     req._resolve(error=DeadlineExceededError(
                         "deadline expired after %.0f ms in queue"
-                        % ((now - req._t_submit) * 1e3)))
+                        % ((time.perf_counter() - req._t_submit) * 1e3)))
                     continue
                 if req.done():       # externally aborted while queued
                     self._queue.popleft()
@@ -503,16 +558,21 @@ class GenerateScheduler:
                     break            # pool pressure: stays queued
                 self._queue.popleft()
                 self._m_queue.set(len(self._queue))
-            req.queue_seconds = time.monotonic() - req._t_submit
+            req.queue_seconds = time.perf_counter() - req._t_submit
+            trace = req.trace
+            exemplar = None if trace is None else trace.trace_id
+            self._m_queue_s.observe(req.queue_seconds, exemplar=exemplar)
+            lap["admitted"] += 1
+            lap["queue_wait_s"] += req.queue_seconds
             page_row = _np.zeros(self.engine.max_pages_per_seq, _np.int32)
             page_row[:len(pages)] = pages
-            t0 = time.monotonic()
-            t0_wall = time.time()
             try:
-                first = self.engine.prefill(
-                    req.tokens, page_row,
-                    (req.temperature, req.top_k, req.top_p),
-                    _random.next_key())
+                # the engine claims `prefill_wait` inside this phase
+                with _goodput.phase("prefill_host") as prefill:
+                    first = self.engine.prefill(
+                        req.tokens, page_row,
+                        (req.temperature, req.top_k, req.top_p),
+                        _random.next_key())
             except Exception as e:  # bad prompt/model: answer, free pages
                 self.allocator.free(pages)
                 err = ServingError("prefill on %r failed: %r"
@@ -522,21 +582,22 @@ class GenerateScheduler:
                                        model=self.name, error=repr(e))
                 req._resolve(error=err)
                 continue
-            prefill_s = time.monotonic() - t0
-            self._m_prefill.observe(
-                prefill_s,
-                exemplar=req.trace.trace_id if req.trace is not None
-                else None)
-            _tracing.emit_span(
-                "serve.queue", t0_wall - req.queue_seconds,
-                req.queue_seconds, req.trace, component="decode")
-            _tracing.emit_span(
-                "decode.prefill", t0_wall, prefill_s, req.trace,
-                component="decode",
-                attrs={"prompt": len(req.tokens), "pages": len(pages)})
+            lap["prefills"] += 1
+            self._m_prefill.observe(prefill.elapsed, exemplar=exemplar)
+            if trace is not None and trace.recorded:
+                t0_wall = time.time() - (time.perf_counter() - prefill.t0)
+                _tracing.emit_span(
+                    "serve.queue", t0_wall - req.queue_seconds,
+                    req.queue_seconds, trace, component="decode")
+                _tracing.emit_span(
+                    "decode.prefill", t0_wall, prefill.elapsed, trace,
+                    component="decode",
+                    attrs={"prompt": len(req.tokens), "pages": len(pages)})
             self._m_tokens.inc()
             seq = _Sequence(req, pages, page_row, len(req.tokens), first)
-            if not self._finish_if_done(seq):
+            with _goodput.phase("retire"):
+                done = self._finish_if_done(seq)
+            if not done:
                 with self._cv:
                     self._active.append(seq)
             self._m_active.set(len(self._active))
@@ -546,75 +607,85 @@ class GenerateScheduler:
         smallest batch bucket; then retire finished sequences."""
         # sequences resolved externally (abort, expired deadline) retire
         # first — never spend a step on an answer nobody is waiting for
-        now = time.monotonic()
-        live = []
-        for seq in self._active:
-            if seq.req.done():
-                self.allocator.free(seq.pages)
-            elif seq.req.deadline is not None and now >= seq.req.deadline:
-                self._retire(seq, None, error=DeadlineExceededError(
-                    "deadline expired after %d generated token(s)"
-                    % len(seq.generated)))
-            else:
-                live.append(seq)
-        if len(live) != len(self._active):
-            with self._cv:
-                self._active = live
-            self._m_active.set(len(live))
+        with _goodput.phase("retire"):
+            now = time.monotonic()
+            live = []
+            for seq in self._active:
+                if seq.req.done():
+                    self.allocator.free(seq.pages)
+                elif seq.req.deadline is not None \
+                        and now >= seq.req.deadline:
+                    self._retire(seq, None, error=DeadlineExceededError(
+                        "deadline expired after %d generated token(s)"
+                        % len(seq.generated)))
+                else:
+                    live.append(seq)
+            if len(live) != len(self._active):
+                with self._cv:
+                    self._active = live
+                self._m_active.set(len(live))
         if not live:
             return
-        n = len(live)
-        bucket = bucket_for(n, self.buckets)
-        ps = self.engine.page_size
-        nump = self.engine.num_pages
-        tokens = _np.zeros(bucket, _np.int32)
-        positions = _np.zeros(bucket, _np.int32)
-        dest_pages = _np.full(bucket, nump, _np.int32)  # OOB = dropped
-        dest_slots = _np.zeros(bucket, _np.int32)
-        tables = _np.zeros((bucket, self.engine.max_pages_per_seq),
-                           _np.int32)
-        lengths = _np.zeros(bucket, _np.int32)
-        temps = _np.zeros(bucket, _np.float32)
-        top_ks = _np.zeros(bucket, _np.int32)
-        top_ps = _np.ones(bucket, _np.float32)
-        for i, seq in enumerate(live):
-            tokens[i] = seq.generated[-1]
-            positions[i] = seq.pos
-            dest_pages[i] = seq.page_row[seq.pos // ps]
-            dest_slots[i] = seq.pos % ps
-            tables[i] = seq.page_row
-            lengths[i] = seq.pos + 1
-            temps[i] = seq.req.temperature
-            top_ks[i] = seq.req.top_k
-            top_ps[i] = seq.req.top_p
-        t0 = time.monotonic()
-        t0_wall = time.time()
-        nxt = self.engine.decode_step(tokens, positions, dest_pages,
-                                      dest_slots, tables, lengths, temps,
-                                      top_ks, top_ps, _random.next_key())
-        step_s = time.monotonic() - t0
+        with _goodput.phase("build"):
+            n = len(live)
+            bucket = bucket_for(n, self.buckets)
+            ps = self.engine.page_size
+            nump = self.engine.num_pages
+            tokens = _np.zeros(bucket, _np.int32)
+            positions = _np.zeros(bucket, _np.int32)
+            dest_pages = _np.full(bucket, nump, _np.int32)  # OOB = dropped
+            dest_slots = _np.zeros(bucket, _np.int32)
+            tables = _np.zeros((bucket, self.engine.max_pages_per_seq),
+                               _np.int32)
+            lengths = _np.zeros(bucket, _np.int32)
+            temps = _np.zeros(bucket, _np.float32)
+            top_ks = _np.zeros(bucket, _np.int32)
+            top_ps = _np.ones(bucket, _np.float32)
+            for i, seq in enumerate(live):
+                tokens[i] = seq.generated[-1]
+                positions[i] = seq.pos
+                dest_pages[i] = seq.page_row[seq.pos // ps]
+                dest_slots[i] = seq.pos % ps
+                tables[i] = seq.page_row
+                lengths[i] = seq.pos + 1
+                temps[i] = seq.req.temperature
+                top_ks[i] = seq.req.top_k
+                top_ps[i] = seq.req.top_p
+        # the engine claims `decode_wait` inside this phase
+        with _goodput.phase("decode_dispatch") as step:
+            nxt = self.engine.decode_step(tokens, positions, dest_pages,
+                                          dest_slots, tables, lengths, temps,
+                                          top_ks, top_ps, _random.next_key())
+        self._lap["n"], self._lap["bucket"] = n, bucket
         self._m_steps.inc()
-        now = time.monotonic()
-        still = []
-        for i, seq in enumerate(live):
-            seq.pos += 1
-            seq.n_steps += 1
-            seq.generated.append(int(nxt[i]))
-            self._m_tokens.inc()
-            self._m_intertoken.observe(
-                now - seq.t_last,
-                exemplar=seq.req.trace.trace_id
-                if seq.req.trace is not None else None)
-            seq.t_last = now
-            _tracing.emit_span(
-                "decode.step", t0_wall, step_s, seq.req.trace,
-                component="decode",
-                attrs={"bucket": bucket, "n": n, "step": seq.n_steps})
-            if not self._finish_if_done(seq):
-                still.append(seq)
-        with self._cv:
-            self._active = still
-        self._m_active.set(len(still))
+        self._m_decode.observe(step.elapsed)
+        with _goodput.phase("retire"):
+            now = time.perf_counter()
+            t0_wall = time.time() - (now - step.t0)
+            still = []
+            for i, seq in enumerate(live):
+                seq.pos += 1
+                seq.n_steps += 1
+                seq.generated.append(int(nxt[i]))
+                self._m_tokens.inc()
+                trace = seq.req.trace
+                self._m_intertoken.observe(
+                    now - seq.t_last,
+                    exemplar=None if trace is None else trace.trace_id)
+                seq.t_last = now
+                # request tracing: only a sequence whose request carries a
+                # recorded trace costs a call
+                if trace is not None and trace.recorded:
+                    _tracing.emit_span(
+                        "decode.step", t0_wall, step.elapsed, trace,
+                        component="decode",
+                        attrs={"bucket": bucket, "n": n,
+                               "step": seq.n_steps})
+                if not self._finish_if_done(seq):
+                    still.append(seq)
+            with self._cv:
+                self._active = still
+            self._m_active.set(len(still))
 
     def _finish_if_done(self, seq):
         """Retire a sequence that hit EOS or its token budget."""
@@ -882,7 +953,8 @@ class TransformerLMEngine:
                 _np.float32([temp]), _np.int32([top_k]),
                 _np.float32([top_p]), key)
         tok, self._kv = self._prefill_exe(lp, lambda: args)(*args)
-        return int(tok)
+        with _goodput.phase("prefill_wait"):    # blocked on the device
+            return int(tok)
 
     def decode_step(self, tokens, positions, dest_pages, dest_slots,
                     tables, lengths, temps, top_ks, top_ps, key):
@@ -892,7 +964,8 @@ class TransformerLMEngine:
         args = (self._params, self._kv, tokens, positions, dest_pages,
                 dest_slots, tables, lengths, temps, top_ks, top_ps, key)
         out, self._kv = self._decode_exe(len(tokens), lambda: args)(*args)
-        return _np.asarray(out)
+        with _goodput.phase("decode_wait"):     # blocked on the device
+            return _np.asarray(out)
 
     def warm(self):
         """Compile every prefill + decode bucket (dummy data, dropped

@@ -1,10 +1,12 @@
-"""Training goodput accounting: per-step stall attribution and cumulative
-phase totals (docs/observability.md §Goodput).
+"""The phase accountant of the hot loops: per-step stall attribution and
+cumulative phase totals (docs/observability.md §Goodput, §Lap phases).
 
 Every training step — gluon ``Trainer.step``, ``DistributedTrainer``/
-``ShardedTrainer``/``PipelineTrainer.step``, ``module.fit`` — brackets
-itself with :func:`step_start` / :func:`step_end` and attributes slices of
-its wall time to exhaustive, non-overlapping phases:
+``ShardedTrainer``/``PipelineTrainer.step``, ``module.fit`` — and every lap
+of the decode scheduler (``serving.generate.GenerateScheduler``, kind
+``serve``, phases :data:`SERVE_PHASES`) brackets itself with
+:func:`step_start` / :func:`step_end` and attributes slices of its wall
+time to exhaustive, non-overlapping phases. A training step's are:
 
 ``data_wait``
     iterator ``next()`` / ``device_put`` / batch-shard blocking
@@ -39,6 +41,14 @@ from each rank's final telemetry flush with the launcher's
 ``launcher-events.jsonl`` generation/downtime ledger into the whole-job
 decomposition.
 
+Every bracket and every phase inside one is also an annotation in the
+profiler's trace (``mxtpu.<kind>.step`` / ``mxtpu.serve.lap``,
+``mxtpu.<kind>.<phase>``), stamped by ``jax.profiler`` on the clock it
+stamps device operations with, so an idle gap of the device lies inside a
+named phase of the host. jax is used only if the process has already
+loaded it. Every closed bracket leaves a whole record in a bounded ring
+per kind (:func:`window`).
+
 Accounting state is thread-local: concurrent trainers (tests, serving +
 training in one process) never cross-attribute. All read paths used by
 signal handlers (:func:`snapshot`, :func:`statusz_block`) are lock-free
@@ -46,6 +56,7 @@ and allocation-light — mxlint's signal-safety checker walks them.
 """
 import atexit
 import collections
+import sys
 import threading
 import time
 
@@ -58,12 +69,23 @@ from . import tracing as _tracing
 PHASES = ("data_wait", "host_dispatch", "compile", "compute",
           "checkpoint_stall", "collective", "other")
 
+# the decode scheduler's lap (kind ``serve``): ``admit`` is queue, deadline
+# and page housekeeping; ``prefill_host`` / ``decode_dispatch`` run until
+# the executable call returns, ``prefill_wait`` / ``decode_wait`` are
+# blocked on the device; ``build`` is the numpy batch build; ``retire`` the
+# per-sequence loop
+SERVE_PHASES = ("admit", "prefill_host", "prefill_wait", "build",
+                "decode_dispatch", "decode_wait", "retire", "other")
+_KIND_PHASES = {"serve": SERVE_PHASES}   # every other kind: PHASES
+
 _TLS = threading.local()
 
-# rolling (wall, compute, stall_phase, stall_seconds) of recent steps —
-# sized lazily from MXTPU_GOODPUT_WINDOW_STEPS at first step
-_WINDOW = collections.deque(maxlen=128)
-_WINDOW_SIZED = False
+# kind -> ring of whole records of closed brackets: t0, t1 (perf_counter),
+# phases, traced, cpu_s, step and what the bracket's owner handed step_end
+_RING_LEN = 4096
+_RINGS = {}
+_LAST_TRAIN_KIND = None   # whose ring feeds the gauge and /statusz
+_GAUGE_STEPS = None       # MXTPU_GOODPUT_WINDOW_STEPS, read at first step
 
 _FIRST_STEP_TS = None  # wall-clock ts of the first completed step
 _PROC_T0 = time.time()  # module import ≈ process start (post-fork exec)
@@ -78,7 +100,7 @@ def _enabled():
 
 
 def _metrics():
-    global _METRICS, _WINDOW_SIZED, _WINDOW
+    global _METRICS, _GAUGE_STEPS
     m = _METRICS
     if m is None:
         hists = {p: _core.histogram("mxtpu_step_phase_seconds",
@@ -89,39 +111,67 @@ def _metrics():
         m = _METRICS = (hists, ctrs,
                         _core.counter("mxtpu_goodput_wall_seconds_total"),
                         _core.gauge("mxtpu_goodput_fraction"))
-    if not _WINDOW_SIZED:
-        n = max(8, int(_env.get("MXTPU_GOODPUT_WINDOW_STEPS")))
-        if n != _WINDOW.maxlen:
-            _WINDOW = collections.deque(_WINDOW, maxlen=n)
-        _WINDOW_SIZED = True  # mxlint: gil-atomic — one-time sizing latch
+    if _GAUGE_STEPS is None:
+        # mxlint: gil-atomic — one-time sizing latch
+        _GAUGE_STEPS = max(8, int(_env.get("MXTPU_GOODPUT_WINDOW_STEPS")))
     return m
+
+
+def _trace_me():
+    """``jax.profiler.TraceAnnotation`` if this process has loaded jax (the
+    telemetry package stays importable without it), else None."""
+    jax = sys.modules.get("jax")
+    return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
 
 
 def _acct():
     return getattr(_TLS, "acct", None)
 
 
-def step_start(kind="train", t0=None):
-    """Open a step accounting bracket. ``t0`` back-dates the step start
-    (``module.fit`` opens the bracket only after a successful iterator
-    ``next()`` so StopIteration leaves no dangling bracket, but the wait
-    itself belongs to the step). A bracket left open by a step that
-    raised is silently discarded — no trainer nests one step inside
-    another, so an open bracket here can only be stale."""
+def step_start(kind="train", t0=None, step=None):
+    """Open a step accounting bracket; ``kind`` selects its phase set
+    (``serve``: :data:`SERVE_PHASES`; any other: :data:`PHASES`) and names
+    its annotations. ``t0`` back-dates the step start (``module.fit`` opens
+    the bracket only after a successful iterator ``next()`` so
+    StopIteration leaves no dangling bracket, but the wait itself belongs
+    to the step); ``step`` numbers a training step in the profiler's
+    trace. A bracket left open by a step that raised is silently
+    discarded — no trainer nests one step inside another, so an open
+    bracket here can only be stale."""
     if not _enabled():
         return
+    stale = _acct()
+    if stale is not None and stale["ann"] is not None:
+        stale["ann"].__exit__(None, None, None)
     now = time.perf_counter()
     t0 = now if t0 is None else t0
-    # idle time since the previous step's end that no out-of-step add()
-    # claimed: the training loop doing neither compute nor a named stall
-    last_end = getattr(_TLS, "last_end", None)
-    if last_end is not None and t0 > last_end:
-        claimed = getattr(_TLS, "gap_attr", 0.0)
-        gap = max(0.0, (t0 - last_end) - claimed)
-        if gap > 0.0:
-            _metrics()[1]["between_steps"].inc(gap)
-    _TLS.gap_attr = 0.0
-    _TLS.acct = {"kind": kind, "t0": t0, "phases": {}, "launched": False}
+    names = _KIND_PHASES.get(kind, PHASES)
+    if names is PHASES:
+        # idle time since the previous step's end that no out-of-step
+        # add() claimed: the training loop doing neither compute nor a
+        # named stall
+        last_end = getattr(_TLS, "last_end", None)
+        if last_end is not None and t0 > last_end:
+            claimed = getattr(_TLS, "gap_attr", 0.0)
+            gap = max(0.0, (t0 - last_end) - claimed)
+            if gap > 0.0:
+                _metrics()[1]["between_steps"].inc(gap)
+        _TLS.gap_attr = 0.0
+    tm = _trace_me()
+    ann, traced = None, False
+    if tm is not None:
+        traced = tm.is_enabled()
+        if names is PHASES:
+            if step is None:
+                step = len(_RINGS.get(kind, ())) + 1
+            # TraceMe's `_r=1` is what makes an annotation a step
+            ann = tm("mxtpu.%s.step" % kind, _r=1, step_num=step)
+        else:
+            ann = tm("mxtpu.%s.lap" % kind)
+        ann.__enter__()
+    _TLS.acct = {"kind": kind, "t0": t0, "phases": {}, "launched": False,
+                 "names": names, "tm": tm, "ann": ann, "traced": traced,
+                 "step": step, "cpu0": time.thread_time()}
     global _ATEXIT_REGISTERED
     if not _ATEXIT_REGISTERED:
         _ATEXIT_REGISTERED = True  # mxlint: gil-atomic — one-time latch
@@ -131,16 +181,20 @@ def step_start(kind="train", t0=None):
 
 
 def add(phase, seconds):
-    """Attribute ``seconds`` to ``phase``. Inside an open bracket the time
-    joins the current step; outside (async checkpoint submit between
-    steps, compile at trainer construction) it goes straight to the
-    cumulative counter and reduces the next ``between_steps`` gap."""
-    if seconds <= 0.0 or phase not in PHASES or not _enabled():
+    """Attribute ``seconds`` to ``phase``. Inside an open bracket whose
+    kind has the phase the time joins the current step; otherwise a
+    training phase (async checkpoint submit between steps, compile at
+    trainer construction or inside a scheduler's lap) goes straight to the
+    cumulative counter and reduces the next ``between_steps`` gap, and any
+    other name is dropped."""
+    if seconds <= 0.0:
         return
     a = _acct()
-    if a is not None:
+    if a is not None and phase in a["names"]:
         ph = a["phases"]
         ph[phase] = ph.get(phase, 0.0) + seconds
+        return
+    if phase not in PHASES or not _enabled():
         return
     _metrics()[1][phase].inc(seconds)
     _TLS.gap_attr = getattr(_TLS, "gap_attr", 0.0) + seconds
@@ -150,22 +204,36 @@ class phase:
     """``with goodput.phase("compute"):`` — attribute the block's elapsed
     time, MINUS whatever finer-grained attribution happened inside the
     block (an op resolving through the compile registry mid-step adds
-    ``compile`` seconds; they must not also count as ``compute``). Keeps
-    phases non-overlapping by construction. Cheap no-op when disabled."""
+    ``compile`` seconds; they must not also count as ``compute``; the
+    scheduler's ``prefill_host`` holds the engine's ``prefill_wait``).
+    Keeps phases non-overlapping by construction. Inside a bracket the
+    block is also the annotation ``mxtpu.<kind>.<phase>`` of the
+    profiler's trace. ``t0`` (perf_counter) and ``elapsed`` (the whole
+    block, nothing subtracted) stay readable after the block, so its owner
+    needs no stamps of its own. Cheap no-op when disabled."""
 
-    __slots__ = ("_name", "_t0", "_nested0")
+    __slots__ = ("_name", "t0", "elapsed", "_nested0", "_ann")
 
     def __init__(self, name):
         self._name = name
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
         a = _acct()
-        self._nested0 = sum(a["phases"].values()) if a is not None else None
+        self._ann = None
+        if a is None:
+            self._nested0 = None
+        else:
+            self._nested0 = sum(a["phases"].values())
+            if a["tm"] is not None:
+                self._ann = a["tm"]("mxtpu.%s.%s" % (a["kind"], self._name))
+                self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        elapsed = time.perf_counter() - self._t0
+        elapsed = self.elapsed = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         a = _acct()
         if a is not None and self._nested0 is not None:
             elapsed -= sum(a["phases"].values()) - self._nested0
@@ -186,23 +254,50 @@ def mark_launch():
     add("host_dispatch", elapsed - sum(ph.values()))
 
 
-def step_end(step=None, examples=None):
+def step_end(step=None, **fields):
     """Close the bracket: fill ``other`` with the unattributed remainder,
-    publish per-phase histograms (exemplar = the step's sampled trace id,
-    if any) + cumulative counters, advance the rolling window and the
-    ``mxtpu_goodput_fraction`` gauge. Returns the step's phase dict
-    (plus ``wall``) — tests assert exhaustiveness on it."""
+    append the step's whole record (``step`` and ``fields`` with it) to
+    its kind's ring and, for a training step, publish per-phase histograms
+    (exemplar = the step's sampled trace id, if any) + cumulative
+    counters and advance the ``mxtpu_goodput_fraction`` gauge; the
+    scheduler publishes its lap's phases under its own model label.
+    Returns the step's phase dict (plus ``wall``) — tests assert
+    exhaustiveness on it."""
     a = _acct()
     if a is None:
         return None
     _TLS.acct = None
     now = time.perf_counter()
-    _TLS.last_end = now
+    cpu_s = time.thread_time() - a["cpu0"]
+    traced = a["traced"]
+    if a["ann"] is not None:
+        a["ann"].__exit__(None, None, None)
+        # recorded whole only if a session was on at both ends
+        traced = traced and a["tm"].is_enabled()
     wall = max(0.0, now - a["t0"])
     ph = a["phases"]
     attributed = sum(ph.values())
     if attributed < wall:
         ph["other"] = ph.get("other", 0.0) + (wall - attributed)
+    rec = {"t0": a["t0"], "t1": now, "phases": ph, "traced": traced,
+           "cpu_s": cpu_s, "step": a["step"] if step is None else step}
+    rec.update(fields)
+    kind = a["kind"]
+    ring = _RINGS.get(kind)
+    if ring is None:
+        ring = _RINGS.setdefault(kind, collections.deque(maxlen=_RING_LEN))
+    ring.append(rec)  # mxlint: gil-atomic — signal-safe ring
+    if a["names"] is PHASES:
+        _publish_training(kind, ph, wall, now)
+    out = dict(ph)
+    out["wall"] = wall
+    return out
+
+
+def _publish_training(kind, ph, wall, now):
+    global _LAST_TRAIN_KIND, _FIRST_STEP_TS
+    _TLS.last_end = now
+    _LAST_TRAIN_KIND = kind  # mxlint: gil-atomic — plain store
     hists, ctrs, wall_ctr, frac = _metrics()
     tid = _tracing.current_trace_id()
     for p, v in ph.items():
@@ -210,21 +305,13 @@ def step_end(step=None, examples=None):
             hists[p].observe(v, exemplar=tid)
             ctrs[p].inc(v)
     wall_ctr.inc(wall)
-
-    compute = ph.get("compute", 0.0)
-    stall_phase, stall_s = None, 0.0
-    for p, v in ph.items():
-        if p != "compute" and v > stall_s:
-            stall_phase, stall_s = p, v
-    _WINDOW.append((wall, compute, stall_phase, stall_s))
     w_wall = w_compute = 0.0
-    for e in _win_steps():
-        w_wall += e[0]
-        w_compute += e[1]
+    for e in _win_steps(kind):
+        w_wall += e["t1"] - e["t0"]
+        w_compute += e["phases"].get("compute", 0.0)
     if w_wall > 0.0:
         frac.set(w_compute / w_wall)
 
-    global _FIRST_STEP_TS
     if _FIRST_STEP_TS is None:
         _FIRST_STEP_TS = time.time()  # mxlint: gil-atomic — one-time stamp
         # the launcher ledger joins this against generation start to price
@@ -237,13 +324,10 @@ def step_end(step=None, examples=None):
         from . import recorder as _recorder
 
         _recorder.record_event(
-            "goodput_first_step", trainer=a["kind"],
+            "goodput_first_step", trainer=kind,
             generation=_core.restart_generation(),
             startup_s=round(max(0.0, _FIRST_STEP_TS - wall - _PROC_T0), 3),
             step_wall_s=round(wall, 4))
-    out = dict(ph)
-    out["wall"] = wall
-    return out
 
 
 def finalize():
@@ -258,7 +342,7 @@ def finalize():
     open on another thread at exit is lost, which only widens the
     report's honest ``shutdown`` remainder."""
     a = _acct()
-    if a is None or not _enabled():
+    if a is None or a["names"] is not PHASES or not _enabled():
         return
     _TLS.acct = None
     ph = a["phases"]
@@ -275,13 +359,45 @@ def finalize():
     wall_ctr.inc(attributed)
 
 
-def _win_steps():
-    """Stable copy of the rolling step window (same retry discipline as
-    core._win_entries — a trainer thread appending during a signal-context
-    read raises RuntimeError)."""
+def window(kind):
+    """A copy of ``kind``'s ring: the whole records of its last (up to
+    4096) closed brackets, oldest first. Each holds ``t0``/``t1`` on
+    ``time.perf_counter()``, ``phases``, ``traced`` (a profiler session
+    was recording at the bracket's start and at its end), ``cpu_s``
+    (``time.thread_time()`` of the bracket's thread across it: wall minus
+    device wait minus this is time spent waiting for the GIL or a lock),
+    ``step`` and the owner's fields (a lap's ``n``, ``bucket``,
+    ``prefills``, ``admitted``, ``queue_wait_s``). Same retry discipline
+    as core._win_entries — an append during a signal-context read raises
+    RuntimeError."""
+    ring = _RINGS.get(kind)
+    if ring is None:
+        return []
     for _ in range(4):
         try:
-            return list(_WINDOW)
+            return list(ring)
+        except RuntimeError:
+            continue
+    return []
+
+
+def _win_steps(kind):
+    """The last ``MXTPU_GOODPUT_WINDOW_STEPS`` records of ``kind``, newest
+    first: the rolling window behind the gauge and the ``/statusz`` block
+    (read from the ring's end, so a step does not copy 4096 records; same
+    retry discipline as :func:`window`)."""
+    ring = _RINGS.get(kind)
+    if ring is None:
+        return []
+    n = _GAUGE_STEPS or 128
+    for _ in range(4):
+        out = []
+        try:
+            for rec in reversed(ring):
+                out.append(rec)
+                if len(out) == n:
+                    break
+            return out
         except RuntimeError:
             continue
     return []
@@ -301,13 +417,19 @@ def totals():
 def statusz_block():
     """The `/statusz` ``training`` block: windowed goodput fraction, top
     stall phase over the window, cumulative totals, startup cost."""
-    entries = _win_steps()
-    w_wall = sum(e[0] for e in entries)
-    w_compute = sum(e[1] for e in entries)
+    entries = _win_steps(_LAST_TRAIN_KIND)
+    w_wall = w_compute = 0.0
     stalls = {}
     for e in entries:
-        if e[2] is not None:
-            stalls[e[2]] = stalls.get(e[2], 0.0) + e[3]
+        w_wall += e["t1"] - e["t0"]
+        w_compute += e["phases"].get("compute", 0.0)
+        # the step's largest stall: its longest phase that is not compute
+        stall_phase, stall_s = None, 0.0
+        for p, v in e["phases"].items():
+            if p != "compute" and v > stall_s:
+                stall_phase, stall_s = p, v
+        if stall_phase is not None:
+            stalls[stall_phase] = stalls.get(stall_phase, 0.0) + stall_s
     top = max(stalls.items(), key=lambda kv: kv[1]) if stalls else None
     block = {
         "enabled": bool(_enabled()),
@@ -328,11 +450,15 @@ def snapshot():
 
 
 def _reset_for_tests():
-    global _WINDOW, _WINDOW_SIZED, _METRICS, _FIRST_STEP_TS
-    _WINDOW = collections.deque(maxlen=128)
-    _WINDOW_SIZED = False
+    global _METRICS, _FIRST_STEP_TS, _LAST_TRAIN_KIND, _GAUGE_STEPS
+    _RINGS.clear()
+    _LAST_TRAIN_KIND = None
+    _GAUGE_STEPS = None
     _METRICS = None
     _FIRST_STEP_TS = None
+    stale = _acct()
+    if stale is not None and stale["ann"] is not None:
+        stale["ann"].__exit__(None, None, None)
     _TLS.acct = None
     _TLS.last_end = None
     _TLS.gap_attr = 0.0

@@ -48,7 +48,7 @@ from . import core
 __all__ = [
     "SpanRef", "Span", "configure", "mint", "root", "span", "emit_span",
     "current", "current_trace_id", "capture", "header_value", "parse_header",
-    "to_wire", "from_wire", "active_spans", "drain_pending", "set_collector",
+    "to_wire", "from_wire", "active_spans", "drain_pending",
     "HEADER", "TRACE_ID_LEN", "SPAN_ID_LEN",
 ]
 
@@ -76,7 +76,6 @@ class _TraceState:
         self.armed = None        # cached "can anything record?" decision
         self.ambient = None      # SpanRef from MXTPU_TRACE_CONTEXT
         self.ambient_read = False
-        self.collector = None    # optional in-process sink (serve_bench)
 
 
 _STATE = _TraceState()
@@ -110,14 +109,6 @@ def configure(sample=None, slow_ms=None):
     _STATE.armed = None
 
 
-def set_collector(fn):
-    """Install (or clear, with None) an in-process span sink: every
-    emitted record is also handed to ``fn(record)``. serve_bench uses this
-    to aggregate phase breakdowns without reading files back."""
-    _STATE.collector = fn
-    _STATE.armed = None
-
-
 def _sample_rate():
     if _STATE.configured:
         return _STATE.sample or 0.0
@@ -148,7 +139,7 @@ def _armed():
         _STATE.armed = bool(
             core._STATE.enabled
             and (_sample_rate() > 0.0 or _slow_ms() is not None
-                 or _ambient() is not None or _STATE.collector is not None))
+                 or _ambient() is not None))
     return _STATE.armed
 
 
@@ -184,8 +175,7 @@ def mint(ref=None):
         return ref
     if not _armed():
         return SpanRef(_gen_id(TRACE_ID_LEN))
-    sampled = (_STATE.collector is not None
-               or random.random() < _sample_rate())
+    sampled = random.random() < _sample_rate()
     deferred = not sampled and _slow_ms() is not None
     return SpanRef(_gen_id(TRACE_ID_LEN), sampled=sampled, deferred=deferred)
 
@@ -423,12 +413,6 @@ def _emit(name, trace_id, span_id, parent_id, component, start_wall, dur_s,
         rec["attrs"] = attrs
     if sampled:
         _PENDING.append(rec)
-        collector = _STATE.collector
-        if collector is not None:
-            try:
-                collector(rec)
-            except Exception:
-                pass  # a tool's sink must never break the traced path
         core.ensure_flusher()
     elif deferred:
         buf = _BUFFER.get(trace_id)
@@ -450,13 +434,6 @@ def _settle_deferred(trace_id, root_dur_s):
     for rec in buf:
         rec["slow"] = True
         _PENDING.append(rec)
-    collector = _STATE.collector
-    if collector is not None:
-        for rec in buf:
-            try:
-                collector(rec)
-            except Exception:
-                pass
     core.ensure_flusher()
 
 

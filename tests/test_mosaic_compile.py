@@ -12,6 +12,7 @@ gets lowered. Nothing runs: a compile that passes says nothing about
 numerics or speed — chip_smoke.py checks those on the chip.
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -138,11 +139,27 @@ CASES = {
 }
 
 
+KERNEL_NAMES = {
+    "flash": ("flash_attention_fwd", "flash_attention_bwd_dq",
+              "flash_attention_bwd_dkv"),
+    "lstm": ("lstm_layer_fwd", "lstm_layer_bwd"),
+    "epilogue": ("conv_epilogue_stats", "conv_epilogue_apply",
+                 "conv_epilogue_bwd_reduce", "conv_epilogue_bwd_dx"),
+    "paged": ("paged_attention_decode",),
+}
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
     monkeypatch.setattr(pk, "_use_interpret", lambda: False)
     fn, arg_shapes = CASES[case]()
     args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in arg_shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), \
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, \
         "no Mosaic kernel in the compiled program — the jnp path was taken"
+    # every kernel carries a fixed name into the program (and so into the
+    # device trace), whatever Python function its body happens to be
+    # (an instruction reads `%paged_attention_decode.3`, or, under
+    # autodiff, `%transpose_jvp_conv_epilogue_bwd_dx__.1`)
+    for name in KERNEL_NAMES[case.split("-")[0]]:
+        assert re.search(r"%%\w*%s_*\.\d+ = " % name, text), name

@@ -29,12 +29,10 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def clean_tracing():
     """Give each test a pristine tracing module and put it back after."""
     tracing.configure()
-    tracing.set_collector(None)
     tracing.drain_pending()
     tracing._BUFFER.clear()
     yield tracing
     tracing.configure()
-    tracing.set_collector(None)
     tracing.drain_pending()
     tracing._BUFFER.clear()
 
@@ -392,6 +390,12 @@ def test_trace_merge_mixed_spans_chrome_and_old_format(tmp_path):
 # tier-1 e2e: one HTTP request, three serving roles, one merged trace
 # ---------------------------------------------------------------------------
 
+def _emitted():
+    """The span records emitted and not yet flushed (this process runs no
+    flusher: it has no telemetry directory)."""
+    return list(tracing._PENDING)
+
+
 def _post_with_headers(url, payload, timeout=15):
     body = json.dumps(payload).encode()
     req = urllib.request.Request(url, data=body,
@@ -410,8 +414,6 @@ def test_trace_e2e_one_request_three_roles(clean_tracing, tmp_path):
 
     tdir = tmp_path / "tm"
     tracing.configure(sample=1.0)
-    collected = []
-    tracing.set_collector(collected.append)
     model = ServedModel.pooled(
         "traced", 1, None, 2,
         worker_args=["--stub", "echo", "--input", "x=2", "--max-batch", "4"],
@@ -437,9 +439,9 @@ def test_trace_e2e_one_request_three_roles(clean_tracing, tmp_path):
         deadline = time.monotonic() + 10
         while time.monotonic() < deadline and not any(
                 s["name"] == "serve.request" and s["trace"] == tid
-                for s in collected):
+                for s in _emitted()):
             time.sleep(0.02)
-        local = {s["name"]: s for s in collected if s["trace"] == tid}
+        local = {s["name"]: s for s in _emitted() if s["trace"] == tid}
         assert {"serve.request", "serve.queue", "serve.assembly",
                 "serve.dispatch", "serve.unpad"} <= set(local), \
             sorted(local)
@@ -495,7 +497,6 @@ def test_trace_e2e_one_request_three_roles(clean_tracing, tmp_path):
         lane_pids = {e["pid"] for e in xs}
         assert len(lane_pids) >= 3  # one lane per (component, os-pid)
     finally:
-        tracing.set_collector(None)
         srv.shutdown()
         model.close(drain=False, timeout=0)
 
@@ -509,8 +510,6 @@ def test_incoming_header_is_honored_end_to_end(clean_tracing, tmp_path):
     from mxnet_tpu import gluon
 
     tracing.configure(sample=0.0)
-    collected = []
-    tracing.set_collector(collected.append)
     # in-process model: this test is about admission, no pool needed
     net = gluon.nn.Dense(2)
     net.initialize()
@@ -541,12 +540,11 @@ def test_incoming_header_is_honored_end_to_end(clean_tracing, tmp_path):
         assert echoed.trace_id == client_ref.trace_id
         deadline = time.monotonic() + 10
         while time.monotonic() < deadline and not any(
-                s["name"] == "serve.request" for s in collected):
+                s["name"] == "serve.request" for s in _emitted()):
             time.sleep(0.02)
-        roots = [s for s in collected if s["name"] == "serve.request"]
+        roots = [s for s in _emitted() if s["name"] == "serve.request"]
         assert roots and roots[0]["trace"] == client_ref.trace_id
         assert roots[0]["parent"] == client_ref.span_id
     finally:
-        tracing.set_collector(None)
         srv.shutdown()
         model.close(drain=False, timeout=0)

@@ -1026,13 +1026,12 @@ def main(argv=None):
         "mxtpu_executor_build_total", {"what": "forward"})
     builds_after_warm = builds.value
 
-    # distributed tracing: sample bench traffic and collect spans in-
-    # process (tracing.set_collector) for the per-phase breakdown
+    # distributed tracing: sample bench traffic; the per-phase breakdown
+    # reads the emitted spans back in-process (those a telemetry directory's
+    # flusher has already written out are in its JSONL instead)
     tracing = telemetry.tracing
-    spans = []
     if args.trace_sample > 0:
         tracing.configure(sample=min(1.0, args.trace_sample))
-        tracing.set_collector(spans.append)
 
     server = ServingServer(repo, port=0, addr="127.0.0.1").start()
     endpoint = ("127.0.0.1", server.port, "/v1/models/bench:predict")
@@ -1097,7 +1096,7 @@ def main(argv=None):
     examples = snap.get("mxtpu_serve_examples_total" + label,
                         {}).get("value", 0)
 
-    phases, slowest = _phase_breakdown(spans)
+    phases, slowest = _phase_breakdown(list(tracing._PENDING))
     if phases:
         log("  phase breakdown (p50 ms): %s" % {
             k: v["p50_ms"] for k, v in phases.items()})
@@ -1106,7 +1105,6 @@ def main(argv=None):
             "trace_merge.py --trace %s -o slow.json <telemetry jsonl>)"
             % (slowest["total_ms"], slowest["trace_id"],
                slowest["trace_id"]))
-    tracing.set_collector(None)
     tracing.configure()
 
     speedup = round(batched["rps"] / seq["rps"], 2) if seq["rps"] else None
