@@ -761,8 +761,10 @@ def _mfu_segments(out, dev, net, ctx, x, fwd_flops_per_img, iters=None):
         dt_f = timed(jitted, xb)
         out["seg_fwd_ms"] = round(dt_f * 1e3, 2)
         if peak:
-            out["seg_fwd_mfu"] = round(
-                batch * fwd_flops_per_img / dt_f / 1e12 / peak, 4)
+            mfu_f = batch * fwd_flops_per_img / dt_f / 1e12 / peak
+            # as for the matmul ceiling above: a tiny contract run on a
+            # loaded CPU must not round to 0.0
+            out["seg_fwd_mfu"] = round(mfu_f, 4 if mfu_f >= 1e-3 else 9)
 
         # grad w.r.t. the INPUT only (weights are closure constants): the
         # executable is fwd + the dgrad chain = ~2x fwd FLOPs. wgrad is the

@@ -664,8 +664,8 @@ def _reference_decode(engine, prompt, max_new):
 
 def _paged_kernel_vs_reference(engine):
     """The decode kernel against the dense oracle on the engine's own KV
-    pool (layer 0 as the requests left it), every sequence at a different
-    length."""
+    pool (layer 0's K and V leaves as the requests left them), every
+    sequence at a different length."""
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import pallas_kernels as pk
@@ -678,18 +678,19 @@ def _paged_kernel_vs_reference(engine):
                          .reshape(b, maxp).astype(np.int32))
     lengths = jnp.asarray(
         np.linspace(1, maxp * engine.page_size, b).astype(np.int32))
-    k_pages, v_pages = engine._kv[0, 0], engine._kv[0, 1]
+    k_pages, v_pages = engine._kv[0]
     scale = engine.head_dim ** -0.5
-    run = pk._paged_compiled((
-        b, engine.num_heads, engine.head_dim, engine.num_pages, maxp,
-        engine.page_size, str(q.dtype), scale, _use_interpret()))
-    got = run(q, k_pages, v_pages, tables, lengths)
+    with _env_override("MXTPU_PALLAS_DECODE", "1"):
+        got = pk.paged_attention(q, k_pages, v_pages, tables, lengths,
+                                 sm_scale=scale)
     ref = pk.paged_attention_reference(q, k_pages, v_pages, tables, lengths,
                                        scale)
     return float(jnp.abs(got - ref).max())
 
 
 def phase_serve_decode(cfg):
+    import jax
+
     import mxnet_tpu as mx
     from mxnet_tpu import compile as mxc
     from mxnet_tpu.gluon.model_zoo.transformer import TransformerLM
@@ -710,7 +711,6 @@ def phase_serve_decode(cfg):
                for lo, hi in [s["short"], s["long"]] * 4]
     heads = s["model"]["num_heads"]
     head_dim = s["model"]["units"] // heads
-    aligned = heads % 8 == 0 and head_dim % 128 == 0
 
     c0 = _compile_seconds()
     with tempfile.TemporaryDirectory(prefix="chip_smoke_lm_") as tmp:
@@ -746,6 +746,7 @@ def phase_serve_decode(cfg):
         program = mxc.compiled(key).as_text()
         reference = _reference_decode(ref_engine, prompts[1], s["max_new"])
         kernel_diff = _paged_kernel_vs_reference(engine)
+        pool = jax.tree_util.tree_leaves(engine._kv)
     finally:
         srv.shutdown()
         repo.unload("lm", timeout=5.0)
@@ -768,13 +769,13 @@ def phase_serve_decode(cfg):
         "mosaic_calls": program.count("tpu_custom_call"),
         "decode_attention": _kernel_path(
             program, "pallas paged_attention", "jnp dense gather"),
-        "paged_branch": "aligned" if aligned else "padded-copy",
+        "kv_pool": "%d leaves of %s %s" % (
+            len(pool), tuple(pool[0].shape), pool[0].dtype),
         "kernel_vs_reference_max_abs_diff": round(kernel_diff, 7),
         "tokens_equal_reference": served == reference,
         "compiles_while_serving": compiles_serving,
         "interpret": _use_interpret(),
-        "params_on": _platforms(
-            [engine._params["word"], engine._kv]),
+        "params_on": _platforms([engine._params["word"]] + pool),
     }
     checks = {
         "all_200": all(r and r[0] == 200 for r in replies) and not hung,

@@ -203,7 +203,8 @@ _var("MXTPU_PALLAS_DECODE", "str", "auto",
      "— flash-decode, q_len=1 against the block-allocated KV cache, page "
      "tables via scalar prefetch): `auto` = kernel on TPU, dense-gather "
      "jnp fallback elsewhere; `1` forces the kernel everywhere (interpret "
-     "mode on CPU — parity tests); `0` forces the jnp path. Read at trace "
+     "mode on CPU — parity tests); `0` forces the jnp path; shapes the "
+     "kernel cannot take go to the jnp path under any value. Read at trace "
      "time of each decode executable — flip it between processes, not "
      "mid-process.")
 _var("MXTPU_S2D_STEM", "bool", False,

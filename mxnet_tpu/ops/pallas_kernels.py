@@ -1135,26 +1135,39 @@ def conv_epilogue(x, gamma, beta, residual=None, eps=1e-3, fix_gamma=False,
 # kernel instead streams one PAGE per grid step straight from the paged
 # array — the page table rides scalar-prefetch (SMEM), so the BlockSpec
 # index_map picks each sequence's next page and nothing is ever copied
-# out of the pool. Online softmax carries (m, l, acc) in VMEM scratch
-# across the page axis, exactly the flash_attention recurrence with
-# page-sized k-blocks. Known bound: the grid is static (B, max_pages), so
-# a short sequence still DMAs its table's padding pages (masked to zero
-# contribution) — per-sequence early exit needs dynamic grid bounds;
-# until then the streamed bytes scale with max_pages, not actual length.
+# out of the pool.
+#
+# The pool is TOKEN-MAJOR: (pages, page_size, Cp), a page being page_size
+# rows of all heads' values side by side (head h owns lanes [h*D, (h+1)*D);
+# Cp is H*D rounded up to the 128-lane tile ONCE, by whoever allocates the
+# pool). A (page_size, Cp) block is whole (8, 128) tiles whatever H and D
+# are, so the kernel reads the pool where it lies: no slice, no pad. The
+# per-head dot product is a sum over D adjacent lanes; a butterfly of lane
+# rotations leaves every head's score replicated over its own D lanes, so
+# the online softmax (m, l, acc) lives as (., Cp) rows with no per-head
+# reshape anywhere, all on the VPU in float32. (The MXU form, (q*k) @ a 0/1
+# head-indicator matrix, compiles too, but a 16-row page leaves it bound by
+# loading that matrix once a page, at three to six bf16 passes for float32.)
+#
+# Known bound: the grid is static (B, max_pages), one page a step, so a
+# short sequence still DMAs its table's padding pages (their arithmetic is
+# skipped) — the streamed bytes scale with max_pages, not actual length.
 #
 # Gate: MXTPU_PALLAS_DECODE — `auto` = kernel on TPU, jnp gather fallback
 # elsewhere; `1` forces the kernel everywhere (interpret mode on CPU —
-# the parity tests); `0` forces the jnp path.
+# the parity tests); `0` forces the jnp path. Shapes the kernel cannot
+# take (`_paged_kernel_takes`) go to the jnp path whatever the gate says.
 # ---------------------------------------------------------------------------
 
 
 def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
                               sm_scale):
-    """Dense-gather oracle (and CPU fallback): q (B, H, D); k_pages /
-    v_pages (P, H, page_size, D); page_tables (B, max_pages) int32;
-    lengths (B,) int32 — tokens [0, lengths[b]) of sequence b are live,
-    laid out page_tables[b, t // page_size] slot t % page_size. A row
-    with length 0 returns zeros-ish garbage that callers mask out (its
+    """Dense-gather oracle (and fallback): q (B, H, D); k_pages / v_pages
+    (P, page_size, Cp) token-major with Cp >= H*D (lanes past H*D are the
+    allocation's padding and are ignored); page_tables (B, max_pages)
+    int32; lengths (B,) int32 — tokens [0, lengths[b]) of sequence b are
+    live, laid out page_tables[b, t // page_size] slot t % page_size. A
+    row with length 0 returns zeros-ish garbage that callers mask out (its
     scores are uniformly _NEG_INF, which is finite by design — no NaNs).
     Both contractions run at HIGHEST precision: an oracle whose f32
     scores the MXU rounded to bf16 could not tell a right kernel from a
@@ -1164,30 +1177,31 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
 
     hi = jax.lax.Precision.HIGHEST
     b, h, d = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[1]
     maxp = page_tables.shape[1]
-    k = k_pages[page_tables]            # (B, maxp, H, ps, D)
-    v = v_pages[page_tables]
-    k = jnp.moveaxis(k, 2, 1).reshape(b, h, maxp * ps, d)
-    v = jnp.moveaxis(v, 2, 1).reshape(b, h, maxp * ps, d)
-    s = jnp.einsum("bhd,bhld->bhl", q.astype(jnp.float32),
+    # (B, maxp, ps, Cp) -> (B, L, H, D)
+    k = k_pages[page_tables][..., :h * d].reshape(b, maxp * ps, h, d)
+    v = v_pages[page_tables][..., :h * d].reshape(b, maxp * ps, h, d)
+    s = jnp.einsum("bhd,blhd->bhl", q.astype(jnp.float32),
                    k.astype(jnp.float32), precision=hi) * sm_scale
     ids = jnp.arange(maxp * ps)[None, None, :]
     s = jnp.where(ids < lengths[:, None, None], s, _NEG_INF)
     p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    o = jnp.einsum("bhl,bhld->bhd", p, v.astype(jnp.float32), precision=hi)
+    o = jnp.einsum("bhl,blhd->bhd", p, v.astype(jnp.float32), precision=hi)
     return o.astype(q.dtype)
 
 
 def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, sm_scale, ps, n_pages):
+                  m_scr, l_scr, acc_scr, *, sm_scale, ps, d, n_pages):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
     j = pl.program_id(1)
+    cp = k_ref.shape[-1]
 
     @pl.when(j == 0)
     def _():
@@ -1195,92 +1209,94 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32) * sm_scale          # (Hp, Dp)
-    k = k_ref[0].astype(jnp.float32)                     # (Hp, ps, Dp)
-    v = v_ref[0].astype(jnp.float32)
-    # per-head scores against this page on the VPU: one query row per head
-    # leaves the MXU nothing to tile, and Mosaic refuses a batched dot whose
-    # lhs has no free dimension (interpret mode accepts it)
-    s = jnp.sum(q[:, None, :] * k, axis=-1)              # (Hp, ps)
-    col = j * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(col < len_ref[b], s, _NEG_INF)
-    m = m_scr[:, 0:1]
-    l = l_scr[:, 0:1]
-    new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m - new_m)
-    p = jnp.exp(s - new_m)
-    l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc_scr[...] * alpha + jnp.sum(p[:, :, None] * v, axis=1)
-    m_scr[...] = jnp.broadcast_to(new_m, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l, l_scr.shape)
-    acc_scr[...] = acc
+    @pl.when(j * ps < len_ref[b])      # a page past the length adds nothing
+    def _():
+        # a lane tile (or one head, if wider) at a time: its heads' scores,
+        # softmax state and output never meet another tile's, and a rotation
+        # inside one tile is a single-vreg operation
+        w = max(128, d)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (ps, w), 1)
+        row = j * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, w), 0)
+        live = row < len_ref[b]
+        for c in range(0, cp, w):
+            q = q_ref[0, :, c:c + w].astype(jnp.float32) * sm_scale  # (1, w)
+            k = k_ref[0, :, c:c + w].astype(jnp.float32)             # (ps, w)
+            v = v_ref[0, :, c:c + w].astype(jnp.float32)
+            # every head's q.k at once: multiply, then all-reduce inside
+            # each head's D lanes (D a power of two; lane i's partner at
+            # stride sh is i ^ sh, which never leaves the head's segment)
+            s = q * k
+            sh = 1
+            while sh < d:
+                s = s + jnp.where((lane & sh) == 0,
+                                  pltpu.roll(s, w - sh, 1),
+                                  pltpu.roll(s, sh, 1))
+                sh *= 2
+            s = jnp.where(live, s, _NEG_INF)
+            m = m_scr[0:1, c:c + w]
+            new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m - new_m)
+            p = jnp.exp(s - new_m)                                   # (ps, w)
+            l = l_scr[0:1, c:c + w] * alpha + jnp.sum(p, axis=0,
+                                                      keepdims=True)
+            acc = acc_scr[0:1, c:c + w] * alpha + jnp.sum(
+                p * v, axis=0, keepdims=True)
+            m_scr[:, c:c + w] = jnp.broadcast_to(new_m, (8, w))
+            l_scr[:, c:c + w] = jnp.broadcast_to(l, (8, w))
+            acc_scr[:, c:c + w] = jnp.broadcast_to(acc, (8, w))
 
     @pl.when(j == n_pages - 1)
     def _():
-        o_ref[0] = (acc_scr[...]
-                    / jnp.maximum(l_scr[:, 0:1], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[0:1, :]
+                    / jnp.maximum(l_scr[0:1, :], 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_kernel_takes(d, ps, cp, pool_dtype):
+    """Whether the Pallas kernel can read a pool of this form: the lane
+    butterfly needs a power-of-two head size, a page must be whole sublane
+    tiles of the pool's dtype (8 rows of 32 bits, 16 of 16, 32 of 8), and
+    its rows whole 128-lane tiles."""
+    sublanes = 32 // _np.dtype(pool_dtype).itemsize
+    return d & (d - 1) == 0 and ps % sublanes == 0 and cp % 128 == 0
 
 
 @functools.lru_cache(maxsize=128)
 def _paged_compiled(key):
-    (b, h, d, n_pages, maxp, ps, dtype, sm_scale, interpret) = key
+    (b, d, cp, maxp, ps, dtype, sm_scale, interpret) = key
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    hp = -(-h // 8) * 8
-    dp = -(-d // 128) * 128
+    def page(bb, j, tbl, lens):
+        # the paged gather: the page table names which KV page this grid
+        # step streams into VMEM
+        return (tbl[bb, j], 0, 0)
+
+    def row(bb, j, tbl, lens):
+        return (bb, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # page_tables, lengths (SMEM)
         grid=(b, maxp),
         in_specs=[
-            pl.BlockSpec((1, hp, dp), lambda bb, j, tbl, lens: (bb, 0, 0),
-                         memory_space=pltpu.VMEM),                   # q
-            # the paged gather: the page table names which KV page this
-            # grid step streams into VMEM
-            pl.BlockSpec((1, hp, ps, dp),
-                         lambda bb, j, tbl, lens: (tbl[bb, j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),                   # k
-            pl.BlockSpec((1, hp, ps, dp),
-                         lambda bb, j, tbl, lens: (tbl[bb, j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),                   # v
+            pl.BlockSpec((1, 1, cp), row, memory_space=pltpu.VMEM),   # q
+            pl.BlockSpec((1, ps, cp), page, memory_space=pltpu.VMEM),  # k
+            pl.BlockSpec((1, ps, cp), page, memory_space=pltpu.VMEM),  # v
         ],
-        out_specs=pl.BlockSpec((1, hp, dp),
-                               lambda bb, j, tbl, lens: (bb, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((hp, 128), jnp.float32),   # m
-                        pltpu.VMEM((hp, 128), jnp.float32),   # l
-                        pltpu.VMEM((hp, dp), jnp.float32)],   # acc
+        out_specs=pl.BlockSpec((1, 1, cp), row, memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((8, cp), jnp.float32),     # m
+                        pltpu.VMEM((8, cp), jnp.float32),     # l
+                        pltpu.VMEM((8, cp), jnp.float32)],    # acc
     )
-    call = pl.pallas_call(
-        functools.partial(_paged_kernel, sm_scale=sm_scale, ps=ps,
+    return pl.pallas_call(
+        functools.partial(_paged_kernel, sm_scale=sm_scale, ps=ps, d=d,
                           n_pages=maxp),
         name="paged_attention_decode",
-        out_shape=jax.ShapeDtypeStruct((b, hp, dp), _np.dtype(dtype)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, cp), _np.dtype(dtype)),
         grid_spec=grid_spec,
         interpret=interpret,
     )
-
-    def run(q, k_pages, v_pages, page_tables, lengths):
-        if hp == h and dp == d:
-            # aligned geometry (the production case: H >= 8, Dh a lane
-            # multiple): the page pool feeds the kernel directly and the
-            # only HBM traffic is the pages actually attended
-            return call(page_tables.astype(jnp.int32),
-                        lengths.astype(jnp.int32), q, k_pages, v_pages)
-        # unaligned geometry pays a padded COPY of the page pool per
-        # call — acceptable for tiny test models, wrong for production:
-        # pick H/Dh on the (8, 128) tile grid so this branch never runs
-        qp = jnp.pad(q, ((0, 0), (0, hp - h), (0, dp - d)))
-        kp = jnp.pad(k_pages, ((0, 0), (0, hp - h), (0, 0), (0, dp - d)))
-        vp = jnp.pad(v_pages, ((0, 0), (0, hp - h), (0, 0), (0, dp - d)))
-        out = call(page_tables.astype(jnp.int32),
-                   lengths.astype(jnp.int32), qp, kp, vp)
-        return out[:, :h, :d]
-
-    return run
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, lengths,
@@ -1289,26 +1305,37 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths,
     paged KV cache (docs/serving.md §Generation).
 
     q: (B, H, D) — the current token's per-head queries. k_pages /
-    v_pages: (P, H, page_size, D) block-allocated cache. page_tables:
-    (B, max_pages) int32 — sequence b's token t lives in page
-    ``page_tables[b, t // page_size]`` slot ``t % page_size``; entries
+    v_pages: (P, page_size, Cp) token-major block-allocated cache, head h
+    in lanes [h*D, (h+1)*D), Cp = H*D rounded up to a multiple of 128 by
+    the allocation (the kernel never pads or slices the pool).
+    page_tables: (B, max_pages) int32 — sequence b's token t lives in page
+    ``page_tables[b, t // page_size]`` row ``t % page_size``; entries
     past the sequence's used pages must still be VALID page indices
     (they are masked by ``lengths``, never dereferenced out of bounds).
     lengths: (B,) int32 live-token counts (0 disables a padding row).
+
+    A head size that is no power of two, a page that is not whole sublane
+    tiles of the pool's dtype or a Cp off the lane tile goes to
+    `paged_attention_reference`: decided from the shapes alone.
     """
+    import jax.numpy as jnp
+
     from .. import env as _env
 
     if sm_scale is None:
         sm_scale = 1.0 / float(_np.sqrt(q.shape[-1]))
     sm_scale = float(sm_scale)
+    b, h, d = q.shape
+    _, ps, cp = k_pages.shape
     gate = (_env.raw("MXTPU_PALLAS_DECODE") or "auto").strip().lower()
     interpret = _use_interpret()
-    if gate == "0" or (gate == "auto" and interpret):
+    if (gate == "0" or (gate == "auto" and interpret)
+            or not _paged_kernel_takes(d, ps, cp, k_pages.dtype)):
         return paged_attention_reference(q, k_pages, v_pages, page_tables,
                                          lengths, sm_scale)
-    b, h, d = q.shape
-    n_pages, _, ps, _ = k_pages.shape
-    maxp = page_tables.shape[1]
-    run = _paged_compiled((b, h, d, n_pages, maxp, ps, str(q.dtype),
-                           sm_scale, interpret))
-    return run(q, k_pages, v_pages, page_tables, lengths)
+    call = _paged_compiled((b, d, cp, page_tables.shape[1], ps,
+                            str(q.dtype), sm_scale, interpret))
+    rows = jnp.pad(q.reshape(b, h * d), ((0, 0), (0, cp - h * d)))
+    out = call(page_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+               rows[:, None, :], k_pages, v_pages)
+    return out[:, 0, :h * d].reshape(b, h, d)

@@ -734,6 +734,15 @@ class TransformerLMEngine:
     registry — parameters ride as arguments, so the executables are keyed
     purely by geometry. Single-threaded: only the scheduler worker may
     drive an engine.
+
+    The KV pool (`_kv`, donated whole to every executable) is a tuple of
+    one (K, V) pair per layer, each array ``(num_pages, page_size, Cp)``:
+    a page is page_size rows, a row one token's values of all heads side
+    by side (head h in columns [h*Dh, (h+1)*Dh)), Cp = num_heads*head_dim
+    rounded up to a multiple of 128 at allocation. A token's K/V is one
+    row scattered at ``[page, slot]`` and the kernel reads whole pages,
+    so no executable copies, slices or pads the pool
+    (docs/serving.md §Generation).
     """
 
     def __init__(self, lm=None, params=None, config=None, num_pages=None,
@@ -794,9 +803,11 @@ class TransformerLMEngine:
         self._param_bytes = int(sum(
             a.size * a.dtype.itemsize
             for a in jax.tree_util.tree_leaves(self._params)))
-        self._kv = jax.numpy.zeros(
-            (self.num_layers, 2, self.num_pages, self.num_heads,
-             self.page_size, self.head_dim), dtype=self.kv_dtype)
+        # rows padded to the 128-lane tile HERE, once — never per call
+        leaf = (self.num_pages, self.page_size, -(-self.units // 128) * 128)
+        self._kv = tuple(
+            tuple(jax.numpy.zeros(leaf, dtype=self.kv_dtype) for _ in "kv")
+            for _ in range(self.num_layers))
         # executable identity: architecture + geometry (params are args,
         # so two engines with one geometry share executables)
         self._fingerprint = hashlib.sha256(json.dumps(
@@ -808,7 +819,8 @@ class TransformerLMEngine:
     def kv_bytes(self):
         """Device bytes of the page pool (allocated in full at load —
         the figure `MXTPU_SERVE_MEMORY_BUDGET` admission prices)."""
-        return int(self._kv.size) * _np.dtype(self.kv_dtype).itemsize
+        return int(sum(a.size * a.dtype.itemsize
+                       for pair in self._kv for a in pair))
 
     def param_bytes(self):
         return self._param_bytes
@@ -849,7 +861,8 @@ class TransformerLMEngine:
         from ..ops.random_ops import sample_token_logits
 
         H, Dh, ps = self.num_heads, self.head_dim, self.page_size
-        nump, scale = self.num_pages, 1.0 / math.sqrt(self.head_dim)
+        C, nump = self.units, self.num_pages
+        scale = 1.0 / math.sqrt(self.head_dim)
 
         def fn(params, kv, tokens, length, page_row, temp, top_k, top_p,
                key):
@@ -860,14 +873,18 @@ class TransformerLMEngine:
             t_idx = jnp.arange(lp)
             tpage = jnp.where(t_idx < length, page_row[t_idx // ps], nump)
             tslot = t_idx % ps
-            for li, layer in enumerate(params["layers"]):
+            new_kv = []
+            for layer, (kp, vp) in zip(params["layers"], kv):
                 qh = _dense(x, layer["q"]).reshape(lp, H, Dh)
-                kh = _dense(x, layer["k"]).reshape(lp, H, Dh)
-                vh = _dense(x, layer["v"]).reshape(lp, H, Dh)
-                kv = kv.at[li, 0, tpage, :, tslot, :].set(
-                    kh.astype(kv.dtype), mode="drop")
-                kv = kv.at[li, 1, tpage, :, tslot, :].set(
-                    vh.astype(kv.dtype), mode="drop")
+                k = _dense(x, layer["k"])
+                v = _dense(x, layer["v"])
+                kh, vh = k.reshape(lp, H, Dh), v.reshape(lp, H, Dh)
+                # one row of C contiguous values a token, in place
+                new_kv.append(
+                    (kp.at[tpage, tslot, :C].set(k.astype(kp.dtype),
+                                                 mode="drop"),
+                     vp.at[tpage, tslot, :C].set(v.astype(vp.dtype),
+                                                 mode="drop")))
                 s = jnp.einsum("qhd,khd->hqk", qh, kh) * scale
                 s = jnp.where(causal[None], s, _NEG_INF)
                 p = jax.nn.softmax(s, axis=-1)
@@ -878,7 +895,7 @@ class TransformerLMEngine:
             logits = x[length - 1] @ params["word"].T            # (V,)
             tok = sample_token_logits(key, logits[None], temp, top_k,
                                       top_p)
-            return tok[0], kv
+            return tok[0], tuple(new_kv)
 
         # the kv pool is DONATED: without it every call materializes a
         # second full pool for the output (transient 2x kv_bytes — the
@@ -892,7 +909,7 @@ class TransformerLMEngine:
         from ..ops.pallas_kernels import paged_attention
         from ..ops.random_ops import sample_token_logits
 
-        H, Dh = self.num_heads, self.head_dim
+        H, Dh, C = self.num_heads, self.head_dim, self.units
         scale = 1.0 / math.sqrt(self.head_dim)
 
         def fn(params, kv, tokens, positions, dest_pages, dest_slots,
@@ -900,22 +917,23 @@ class TransformerLMEngine:
             b = tokens.shape[0]
             x = params["word"][tokens] + params["pos"][positions]  # (b, C)
             x = _ln(x, params["embed_norm"])
-            for li, layer in enumerate(params["layers"]):
+            new_kv = []
+            for layer, (kp, vp) in zip(params["layers"], kv):
                 qh = _dense(x, layer["q"]).reshape(b, H, Dh)
-                kh = _dense(x, layer["k"]).reshape(b, H, Dh)
-                vh = _dense(x, layer["v"]).reshape(b, H, Dh)
-                kv = kv.at[li, 0, dest_pages, :, dest_slots, :].set(
-                    kh.astype(kv.dtype), mode="drop")
-                kv = kv.at[li, 1, dest_pages, :, dest_slots, :].set(
-                    vh.astype(kv.dtype), mode="drop")
-                att = paged_attention(qh, kv[li, 0], kv[li, 1], tables,
-                                      lengths, sm_scale=scale)
+                kp = kp.at[dest_pages, dest_slots, :C].set(
+                    _dense(x, layer["k"]).astype(kp.dtype), mode="drop")
+                vp = vp.at[dest_pages, dest_slots, :C].set(
+                    _dense(x, layer["v"]).astype(vp.dtype), mode="drop")
+                new_kv.append((kp, vp))
+                att = paged_attention(qh, kp, vp, tables, lengths,
+                                      sm_scale=scale)
                 att = att.astype(x.dtype).reshape(b, -1)
                 x = _ln(x + _dense(att, layer["o"]), layer["attn_norm"])
                 h = jax.nn.gelu(_dense(x, layer["ffn1"]), approximate=False)
                 x = _ln(x + _dense(h, layer["ffn2"]), layer["ffn_norm"])
             logits = x @ params["word"].T                        # (b, V)
-            return sample_token_logits(key, logits, temp, top_k, top_p), kv
+            return (sample_token_logits(key, logits, temp, top_k, top_p),
+                    tuple(new_kv))
 
         # kv donated: the per-step update must alias, not copy, the pool
         return lambda: jax.jit(fn, donate_argnums=(1,))

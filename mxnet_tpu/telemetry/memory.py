@@ -305,6 +305,11 @@ def _leaf_nbytes(x):
     return 0
 
 
+# the decode engine's executables (serving/generate.py): what they donate
+# is the whole KV pool, which they must also update IN PLACE
+_KV_POOL_KINDS = ("lm_prefill", "lm_decode")
+
+
 def verify_donation(key, example_args, figures, threshold=0.5):
     """Check, from an executable's memory figures, that the buffers its
     key DECLARES donated (``key.donation`` argnums) were actually aliased
@@ -315,7 +320,14 @@ def verify_donation(key, example_args, figures, threshold=0.5):
     a fused trainer step that silently stopped donating is an extra
     whole-model allocation, exactly the regression ROADMAP item 1 cannot
     afford. Returns the report dict (also kept for
-    `last_donation_report`), or None when unverifiable."""
+    `last_donation_report`), or None when unverifiable.
+
+    For the decode engine's executables the donated bytes are the KV
+    pool, and aliasing alone does not say the pool stayed where it lies:
+    a program can alias its output and still copy, slice or pad the pool
+    on the way. Its temporaries do say it, so they are published as
+    ``mxtpu_serve_exe_temp_bytes{kind}``, and temporaries over a quarter
+    of the pool record a ``kv_pool_not_in_place`` event."""
     if not enabled() or not key.donation or figures is None \
             or figures.get("alias") is None:
         return None
@@ -339,13 +351,20 @@ def verify_donation(key, example_args, figures, threshold=0.5):
     labels = {"kind": key.kind}
     core.gauge("mxtpu_donation_declared_bytes", labels).set(declared)
     core.gauge("mxtpu_donation_alias_bytes", labels).set(alias)
-    if not report["ok"]:
-        from . import recorder
+    from . import recorder
 
+    if not report["ok"]:
         recorder.record_event(
             "donation_unaliased", key_kind=key.kind,
             declared_bytes=int(declared), alias_bytes=alias,
             aliased_fraction=round(report["aliased_fraction"], 4))
+    if key.kind in _KV_POOL_KINDS and figures.get("temp") is not None:
+        temp = report["temp_bytes"] = int(figures["temp"])
+        core.gauge("mxtpu_serve_exe_temp_bytes", labels).set(temp)
+        if temp > declared // 4:
+            recorder.record_event(
+                "kv_pool_not_in_place", key_kind=key.kind,
+                temp_bytes=temp, kv_bytes=int(declared))
     return report
 
 

@@ -100,33 +100,119 @@ def test_kv_allocator_gauges():
 
 
 # ---------------------------------------------------------------------------
-# paged decode attention: Pallas (interpret) vs dense-gather jnp oracle
+# paged decode attention: Pallas (interpret) vs dense-gather jnp oracle,
+# on the token-major pool (pages, page_size, Cp)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6), ("bfloat16", 4e-2)])
-def test_paged_attention_pallas_vs_jnp(monkeypatch, dtype, tol):
+def _paged_inputs(seed, b, h, d, pages, ps, maxp, dtype):
+    """q, the K and V pools (lanes past H*D zero, as the engine allocates
+    them) and a random page table."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    c = h * d
+    cp = -(-c // 128) * 128
+    pools = []
+    for _ in "kv":
+        pool = np.zeros((pages, ps, cp), np.float32)
+        pool[..., :c] = rng.randn(pages, ps, c)
+        pools.append(jnp.asarray(pool, dtype=dtype))
+    q = jnp.asarray(rng.randn(b, h, d), dtype=dtype)
+    tbl = jnp.asarray(rng.randint(0, pages, (b, maxp)), jnp.int32)
+    return q, pools[0], pools[1], tbl
+
+
+# ragged lengths: a FULL table, a page-straddling row, a 1-token row and an
+# INERT row (length 0 — the scheduler's batch padding)
+@pytest.mark.parametrize("h,d,ps,maxp,dtype,tol", [
+    (12, 64, 16, 6, "float32", 2e-6),     # GPT-2 small's heads: Cp = 768
+    (2, 32, 8, 5, "float32", 2e-6),       # tiny, unaligned: Cp padded to 128
+    (3, 16, 8, 4, "float32", 2e-6),       # odd head count, padded lanes
+    (16, 128, 8, 3, "float32", 2e-6),     # a head is a whole lane tile
+    (2, 256, 8, 3, "float32", 2e-6),      # a head is two lane tiles
+    (12, 64, 16, 6, "bfloat16", 4e-2),    # bf16 pool: sublane tile 16
+], ids=["gpt2s-f32", "tiny-f32", "odd-heads-f32", "wide-head-f32",
+        "two-tile-head-f32", "gpt2s-bf16"])
+def test_paged_attention_pallas_vs_jnp(monkeypatch, h, d, ps, maxp, dtype,
+                                       tol):
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import pallas_kernels as pk
 
-    rng = np.random.RandomState(7)
-    b, h, d, pages, ps, maxp = 4, 2, 32, 16, 4, 5
-    q = jnp.asarray(rng.randn(b, h, d), dtype=dtype)
-    kp = jnp.asarray(rng.randn(pages, h, ps, d), dtype=dtype)
-    vp = jnp.asarray(rng.randn(pages, h, ps, d), dtype=dtype)
-    tbl = jnp.asarray(rng.randint(0, pages, (b, maxp)), jnp.int32)
-    # ragged lengths incl. a full row, a page-straddling row, a 1-token
-    # row and an INERT row (length 0 — the scheduler's batch padding)
-    lens = jnp.asarray([maxp * ps, 7, 1, 0], jnp.int32)
+    q, kp, vp, tbl = _paged_inputs(7, 4, h, d, 16, ps, maxp, dtype)
+    lens = jnp.asarray([maxp * ps, ps + 1, 1, 0], jnp.int32)
+    assert pk._paged_kernel_takes(d, ps, kp.shape[-1], kp.dtype)
     ref = pk.paged_attention_reference(q, kp, vp, tbl, lens,
                                        1.0 / np.sqrt(d))
     monkeypatch.setenv("MXTPU_PALLAS_DECODE", "1")   # force the kernel
     out = pk.paged_attention(q, kp, vp, tbl, lens)
+    assert out.shape == q.shape and out.dtype == q.dtype
     # live rows match to dtype tolerance; the inert row is unused garbage
     err = np.max(np.abs(np.asarray(ref, np.float32)[:3]
                         - np.asarray(out, np.float32)[:3]))
     assert err < tol, err
     assert np.all(np.isfinite(np.asarray(out, np.float32)))
+
+
+def test_paged_attention_reference_is_dense_attention():
+    """The oracle itself, against attention written out per sequence and
+    head in numpy: the layout (token t of sequence b in page
+    tables[b, t // ps] row t % ps, head h in lanes [h*D, (h+1)*D)) is
+    pinned by something that does not share its code."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    b, h, d, ps, maxp = 3, 2, 32, 4, 3
+    q, kp, vp, tbl = _paged_inputs(11, b, h, d, 8, ps, maxp, "float32")
+    lens = [maxp * ps, 5, 1]
+    out = np.asarray(pk.paged_attention_reference(
+        q, kp, vp, tbl, np.asarray(lens, np.int32), 1.0 / np.sqrt(d)))
+    qn, kn, vn, tn = (np.asarray(a) for a in (q, kp, vp, tbl))
+    for bi in range(b):
+        for hi in range(h):
+            lanes = slice(hi * d, (hi + 1) * d)
+            k = np.stack([kn[tn[bi, t // ps], t % ps, lanes]
+                          for t in range(lens[bi])])
+            v = np.stack([vn[tn[bi, t // ps], t % ps, lanes]
+                          for t in range(lens[bi])])
+            sc = k @ qn[bi, hi] / np.sqrt(d)
+            w = np.exp(sc - sc.max())
+            want = (w / w.sum()) @ v
+            assert np.max(np.abs(out[bi, hi] - want)) < 1e-5
+
+
+@pytest.mark.parametrize("h,d,ps,cp,dtype,why", [
+    (2, 24, 8, 128, "float32", "head size no power of two"),
+    (2, 32, 4, 128, "float32", "page smaller than a float32 sublane tile"),
+    (2, 32, 8, 128, "bfloat16", "page smaller than a bf16 sublane tile"),
+    (2, 32, 8, 64, "float32", "rows off the 128-lane tile"),
+])
+def test_paged_attention_refused_shapes_fall_to_reference(monkeypatch, h, d,
+                                                          ps, cp, dtype, why):
+    """Shapes the kernel cannot take go to the jnp path by themselves,
+    even with the gate forcing the kernel: the choice is made from the
+    shapes, and the kernel builder is never reached."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.RandomState(5)
+    q = jnp.asarray(rng.randn(2, h, d), dtype=dtype)
+    kp = jnp.asarray(rng.randn(8, ps, cp), dtype=dtype)
+    vp = jnp.asarray(rng.randn(8, ps, cp), dtype=dtype)
+    tbl = jnp.asarray(rng.randint(0, 8, (2, 3)), jnp.int32)
+    lens = jnp.asarray([2 * ps + 1, 3], jnp.int32)
+    assert not pk._paged_kernel_takes(d, ps, cp, kp.dtype), why
+
+    def refuse(key):
+        raise AssertionError("kernel built for refused shapes: %r" % (key,))
+
+    monkeypatch.setattr(pk, "_paged_compiled", refuse)
+    monkeypatch.setenv("MXTPU_PALLAS_DECODE", "1")
+    out = pk.paged_attention(q, kp, vp, tbl, lens)
+    ref = pk.paged_attention_reference(q, kp, vp, tbl, lens,
+                                       1.0 / np.sqrt(d))
+    assert np.array_equal(np.asarray(out, np.float32),
+                          np.asarray(ref, np.float32))
 
 
 def test_paged_attention_gate_fallback(monkeypatch):
@@ -136,12 +222,8 @@ def test_paged_attention_gate_fallback(monkeypatch):
 
     from mxnet_tpu.ops import pallas_kernels as pk
 
-    rng = np.random.RandomState(3)
-    q = jnp.asarray(rng.randn(2, 2, 16), jnp.float32)
-    kp = jnp.asarray(rng.randn(8, 2, 4, 16), jnp.float32)
-    vp = jnp.asarray(rng.randn(8, 2, 4, 16), jnp.float32)
-    tbl = jnp.asarray(rng.randint(0, 8, (2, 3)), jnp.int32)
-    lens = jnp.asarray([5, 9], jnp.int32)
+    q, kp, vp, tbl = _paged_inputs(3, 2, 2, 16, 8, 8, 3, "float32")
+    lens = jnp.asarray([5, 17], jnp.int32)
     outs = {}
     for gate in ("0", "auto", "1"):
         monkeypatch.setenv("MXTPU_PALLAS_DECODE", gate)
@@ -421,6 +503,101 @@ def test_engine_greedy_matches_gluon_oracle(lm_scheduler, tiny_lm):
     # zero-compile steady state: every bucket was covered by warm
     assert misses.value - base == 0
     assert lm_scheduler.allocator.used_pages == 0
+
+
+def _pool_bytes(engine):
+    import jax
+
+    return [np.asarray(a).tobytes()
+            for a in jax.tree_util.tree_leaves(engine._kv)]
+
+
+@pytest.mark.parametrize("gate", ["1", "0"], ids=["kernel", "jnp"])
+def test_engine_pool_in_place_interleaved_pages(tiny_lm, monkeypatch, gate):
+    """The engine driven by hand on its per-layer token-major pool, pages
+    of two sequences interleaved: greedy tokens of a prefill and 32 decode
+    steps equal the dense forward's; a sequence retired mid-way hands its
+    pages to a new one while the other keeps decoding; padding rows' writes
+    drop and leave every page byte-identical. Once through the Pallas
+    kernel (interpret), once through the jnp path."""
+    import jax
+
+    monkeypatch.setenv("MXTPU_PALLAS_DECODE", gate)
+    ps, maxp, nump = 8, 6, 20 + int(gate)        # a geometry per gate: the
+    eng = TransformerLMEngine(                   # gate is read at trace time
+        lm=tiny_lm, num_pages=nump, page_size=ps, max_prompt=8,
+        max_new_tokens=40, decode_buckets=[2, 4], prefill_buckets=[8])
+    leaves = jax.tree_util.tree_leaves(eng._kv)
+    assert len(leaves) == 2 * eng.num_layers
+    assert all(a.shape == (nump, ps, 128) for a in leaves)   # 32 -> 128 once
+    assert eng.kv_bytes() == len(leaves) * nump * ps * 128 * 4
+    greedy, key = (0.0, 0, 1.0), mx.random.next_key()
+    rows = {"a": [1, 3, 5, 7, 9, 11], "b": [2, 4, 6, 8, 10, 12]}
+    prompts = {"a": [3, 5, 7, 2, 9], "b": [9, 4, 6, 1, 8, 2, 7],
+               "c": [1, 2, 3]}
+    def dense_greedy(prompt, n, pad_to=48):
+        # the block is causal, so one padded shape serves every length
+        toks = list(prompt)
+        for _ in range(n):
+            ids = mx.nd.array([toks + [0] * (pad_to - len(toks))],
+                              dtype="int32")
+            toks.append(int(np.argmax(
+                tiny_lm(ids).asnumpy()[0, len(toks) - 1])))
+        return toks[len(prompt):]
+
+    want = {n: dense_greedy(p, 33) for n, p in prompts.items()}
+
+    before = _pool_bytes(eng)
+    seqs = {}
+    for n in "ab":
+        tok = eng.prefill(prompts[n], rows[n], greedy, key)
+        seqs[n] = {"row": rows[n], "pos": len(prompts[n]), "out": [tok]}
+    after = _pool_bytes(eng)
+    # a 5- and a 7-token prompt in an 8-bucket: only the first page of each
+    # table was written, the bucket's padding rows dropped
+    for old, new in zip(before, after):
+        width = ps * 128 * 4
+        changed = {i for i in range(nump)
+                   if old[i * width:(i + 1) * width]
+                   != new[i * width:(i + 1) * width]}
+        assert changed == {1, 2}
+
+    def step(names, bucket):
+        b = bucket
+        tokens, positions = np.zeros(b, np.int32), np.zeros(b, np.int32)
+        dest_pages = np.full(b, nump, np.int32)       # dropped by default
+        dest_slots, lengths = np.zeros(b, np.int32), np.zeros(b, np.int32)
+        tables = np.zeros((b, maxp), np.int32)
+        for i, n in enumerate(names):
+            q = seqs[n]
+            tokens[i], positions[i] = q["out"][-1], q["pos"]
+            dest_pages[i] = q["row"][q["pos"] // ps]
+            dest_slots[i] = q["pos"] % ps
+            tables[i], lengths[i] = q["row"], q["pos"] + 1
+        out = eng.decode_step(tokens, positions, dest_pages, dest_slots,
+                              tables, lengths, np.zeros(b, np.float32),
+                              np.zeros(b, np.int32), np.ones(b, np.float32),
+                              key)
+        for i, n in enumerate(names):
+            seqs[n]["out"].append(int(out[i]))
+            seqs[n]["pos"] += 1
+
+    for _ in range(12):
+        step("ab", 2)
+    assert seqs["b"]["out"] == want["b"][:13]
+    # b retires; c takes over b's pages (stale K/V in them) in a wider
+    # bucket whose two spare rows are inert padding
+    tok = eng.prefill(prompts["c"], rows["b"], greedy, key)
+    seqs["c"] = {"row": rows["b"], "pos": len(prompts["c"]), "out": [tok]}
+    for _ in range(20):
+        step("ca", 4)
+    assert seqs["a"]["out"] == want["a"]                 # 1 + 32 tokens
+    assert seqs["c"]["out"] == want["c"][:21]
+
+    # a step of padding rows only: every write drops, nothing moves
+    before = _pool_bytes(eng)
+    step("", 2)
+    assert _pool_bytes(eng) == before
 
 
 def test_engine_sampled_tokens_stay_in_vocab(lm_scheduler):

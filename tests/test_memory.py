@@ -439,6 +439,71 @@ def test_donation_verifier_negative():
     assert events and events[-1]["fields"]["key_kind"] == "dist_step"
 
 
+@pytest.mark.parametrize("kind,temp,fires", [
+    ("lm_decode", 32768, True),         # a whole pool of temporaries
+    ("lm_prefill", 8193, True),         # just over a quarter of it
+    ("lm_decode", 8192, False),         # a quarter exactly: in place
+    ("dist_step", 32768, False),        # not an engine executable
+])
+def test_kv_pool_in_place_gauge_and_event(kind, temp, fires):
+    """The decode engine's executables publish their temporaries at fill
+    (`mxtpu_serve_exe_temp_bytes{kind}`), and temporaries over a quarter
+    of the donated KV pool record `kv_pool_not_in_place`: figures faked
+    large, since a sound engine program never shows them."""
+    import jax
+
+    from mxnet_tpu import telemetry
+
+    key = mxc.ExecutableKey(kind, "pool_%s_%d" % (kind, temp),
+                            donation=(1,), no_persist=True)
+    pool = ((jax.ShapeDtypeStruct((8, 8, 64), "float32"),
+             jax.ShapeDtypeStruct((8, 8, 64), "float32")),)   # 32 KiB
+    before = len([e for e in telemetry.events()
+                  if e["event"] == "kv_pool_not_in_place"])
+    rep = memory.verify_donation(key, (None, pool),
+                                 {"alias": 32768, "temp": temp})
+    assert rep["ok"] and rep["declared_bytes"] == 32768
+    gauge = 'mxtpu_serve_exe_temp_bytes{kind="%s"}' % kind
+    events = [e for e in telemetry.events()
+              if e["event"] == "kv_pool_not_in_place"]
+    if kind == "dist_step":
+        assert gauge not in telemetry.snapshot() and "temp_bytes" not in rep
+    else:
+        assert telemetry.snapshot()[gauge]["value"] == temp
+        assert rep["temp_bytes"] == temp
+    assert len(events) - before == (1 if fires else 0)
+    if fires:
+        f = events[-1]["fields"]
+        assert (f["key_kind"], f["temp_bytes"], f["kv_bytes"]) == (
+            kind, temp, 32768)
+
+
+def test_engine_fill_publishes_pool_temporaries():
+    """A real engine fill goes through the same hook: the tiny engine's
+    decode and prefill programs report temporaries far under a quarter of
+    its pool and no event."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.gluon.model_zoo.transformer import lm_mini
+    from mxnet_tpu.serving import TransformerLMEngine
+
+    lm = lm_mini(vocab_size=64)
+    lm.initialize()
+    eng = TransformerLMEngine(lm=lm, num_pages=64, page_size=8,
+                              max_prompt=8, max_new_tokens=8,
+                              decode_buckets=[2], prefill_buckets=[8])
+    before = len([e for e in telemetry.events()
+                  if e["event"] == "kv_pool_not_in_place"])
+    eng.warm()
+    snap = telemetry.snapshot()
+    for kind in ("lm_prefill", "lm_decode"):
+        temp = snap['mxtpu_serve_exe_temp_bytes{kind="%s"}' % kind]["value"]
+        assert 0 <= temp < eng.kv_bytes() // 4, (kind, temp)
+        assert snap['mxtpu_donation_alias_bytes{kind="%s"}' % kind][
+            "value"] >= eng.kv_bytes()
+    assert len([e for e in telemetry.events()
+                if e["event"] == "kv_pool_not_in_place"]) == before
+
+
 def test_distributed_trainer_step_verifies_donation():
     """The real fused-step fill runs the verifier: donated param +
     optimizer buffers are fully aliased (ROADMAP item 1's invariant)."""

@@ -92,14 +92,14 @@ def _epilogue(shape, dtype):
 
 
 def _paged(b, h, d, n_pages, maxp, ps, dtype):
-    def fwd(q, k_pages, v_pages, tables, lengths):
-        run = pk._paged_compiled((b, h, d, n_pages, maxp, ps,
-                                  str(jnp.dtype(dtype)), d ** -0.5, False))
-        return run(q, k_pages, v_pages, tables, lengths)
-
-    return fwd, [((b, h, d), dtype), ((n_pages, h, ps, d), dtype),
-                 ((n_pages, h, ps, d), dtype), ((b, maxp), jnp.int32),
-                 ((b,), jnp.int32)]
+    """The public entry point on a token-major pool (pages, page_size,
+    Cp): it must take the kernel for these shapes, not the jnp path."""
+    cp = -(-h * d // 128) * 128
+    assert pk._paged_kernel_takes(d, ps, cp, dtype)
+    return pk.paged_attention, [
+        ((b, h, d), dtype), ((n_pages, ps, cp), dtype),
+        ((n_pages, ps, cp), dtype), ((b, maxp), jnp.int32),
+        ((b,), jnp.int32)]
 
 
 bf16, f32 = jnp.bfloat16, jnp.float32
@@ -131,11 +131,15 @@ CASES = {
     "epilogue-gate-edge-f32":
         lambda: _epilogue((64, _largest_epilogue_c(4)), f32),
     # serve_decode geometry (GPT-2-small heads, f32 KV as the engine
-    # defaults): 12 x 64 is off the (8, 128) grid -> padded-copy branch
+    # defaults): a page is (16, 768), whole (8, 128) tiles
     "paged-gpt2small-f32": lambda: _paged(8, 12, 64, 512, 32, 16, f32),
     "paged-gpt2small-bf16": lambda: _paged(8, 12, 64, 512, 32, 16, bf16),
-    # aligned geometry: the page pool feeds the kernel with no copy
+    # a head is a whole lane tile
     "paged-aligned-bf16": lambda: _paged(32, 16, 128, 1024, 64, 16, bf16),
+    # a tiny model: 2 heads of 32 in one padded 128-lane row
+    "paged-tiny-f32": lambda: _paged(4, 2, 32, 64, 8, 8, f32),
+    # a head wider than a lane tile: the butterfly crosses vregs
+    "paged-head256-f32": lambda: _paged(8, 4, 256, 256, 16, 16, f32),
 }
 
 
@@ -163,3 +167,103 @@ def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
     # autodiff, `%transpose_jvp_conv_epilogue_bwd_dx__.1`)
     for name in KERNEL_NAMES[case.split("-")[0]]:
         assert re.search(r"%%\w*%s_*\.\d+ = " % name, text), name
+
+
+# ---------------------------------------------------------------------------
+# the decode engine's own programs at the benchmark's geometry: the KV pool
+# is donated, updated in place and read where it lies
+# ---------------------------------------------------------------------------
+
+def _gpt2s_engine(traffic):
+    """A `TransformerLMEngine` at chipbench/configs/gpt2_small.json's sizes
+    with a cell's buckets (weights of zeros: only shapes are lowered)."""
+    import json
+    import os
+
+    import numpy as np
+
+    from mxnet_tpu.serving import TransformerLMEngine
+
+    root = os.path.join(os.path.dirname(__file__), "..", "chipbench")
+    with open(os.path.join(root, "configs", "gpt2_small.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "traffic", traffic + ".json")) as f:
+        cell = json.load(f)["engine"]
+    sizes = config["sizes"]
+    u, hid = sizes["units"], sizes["hidden_size"]
+
+    def z(*shape):
+        return np.zeros(shape, np.float32)
+
+    def dense(o, i):
+        return {"w": z(o, i), "b": z(o)}
+
+    def norm():
+        return {"g": z(u), "b": z(u)}
+
+    params = {"word": z(sizes["vocab_size"], u),
+              "pos": z(sizes["max_length"], u), "embed_norm": norm(),
+              "layers": [{"q": dense(u, u), "k": dense(u, u),
+                          "v": dense(u, u), "o": dense(u, u),
+                          "attn_norm": norm(), "ffn1": dense(hid, u),
+                          "ffn2": dense(u, hid), "ffn_norm": norm()}
+                         for _ in range(sizes["num_layers"])]}
+    return TransformerLMEngine(params=params, config=sizes,
+                               **dict(config["engine"], **cell))
+
+
+def _engine_program(engine, kind, bucket):
+    """(jitted program, example arguments) of one engine executable."""
+    import numpy as np
+
+    from mxnet_tpu import random as mxrandom
+
+    def i32(*shape):
+        return np.zeros(shape, np.int32)
+
+    def f32s(*shape):
+        return np.zeros(shape, np.float32)
+
+    maxp, key = engine.max_pages_per_seq, mxrandom.next_key()
+    if kind == "lm_decode":
+        b = bucket
+        return engine._build_decode(b)(), (
+            engine._params, engine._kv, i32(b), i32(b), i32(b), i32(b),
+            i32(b, maxp), i32(b), f32s(b), i32(b), f32s(b), key)
+    return engine._build_prefill(bucket)(), (
+        engine._params, engine._kv, i32(bucket), np.int32(0), i32(maxp),
+        f32s(1), i32(1), f32s(1), key)
+
+
+@pytest.mark.parametrize("traffic,kind,bucket", [
+    ("gpt2s_chat_open", "lm_decode", 64),
+    ("gpt2s_docs_closed", "lm_prefill", 1024),
+], ids=["lm_decode-b64", "lm_prefill-l1024"])
+def test_engine_program_keeps_the_pool_in_place(traffic, kind, bucket, v5e,
+                                                monkeypatch):
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    engine = _gpt2s_engine(traffic)
+    pool = engine.kv_bytes()
+    assert pool == 2 * 12 * 3072 * 16 * 768 * 4
+    fn, args = _engine_program(engine, kind, bucket)
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.asarray(a).dtype
+                                       if not hasattr(a, "dtype")
+                                       else a.dtype, sharding=v5e), args)
+    compiled = fn.lower(*shapes).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.05 * pool, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= pool, mem.alias_size_in_bytes
+    text = compiled.as_text()
+    if kind == "lm_decode":
+        assert re.search(r"%\w*paged_attention_decode_*\.\d+ = ", text)
+    # no instruction moves an array that holds the page count: the 24
+    # leaves are scattered into and streamed from, nothing else
+    pages = str(engine.num_pages)
+    moved = [line.strip()[:120] for line in text.splitlines()
+             if re.search(r" (copy|pad|slice|transpose|dynamic-slice)\(",
+                          line)
+             and pages in re.findall(
+                 r"\d+", line.split(" = ", 1)[1].split("(", 1)[0])]
+    assert not moved, moved
+    assert len(re.findall(r" scatter\(", text)) == 2 * engine.num_layers

