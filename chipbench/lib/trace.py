@@ -16,7 +16,9 @@ What is read:
                   line of their own (``Async XLA Ops``), which is not read:
                   busy time is the time an operation occupies the core.
   host planes     ``/host:CPU``; the harness's own ``TraceAnnotation`` spans
-                  (``chipbench.*``) are events on the Python thread's line
+                  (``chipbench.*``) and the program's (``mxtpu.*``: the phases
+                  of the decode scheduler's lap and of a trainer's step) are
+                  events on their Python threads' lines
 """
 from __future__ import annotations
 
@@ -28,7 +30,8 @@ import tempfile
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OP_LINES = ("XLA Ops",)
-ANNOTATION_PREFIX = "chipbench."
+ANNOTATION_PREFIX = ("chipbench.", "mxtpu.")
+BETWEEN = "host_between_annotations"
 
 # An "XLA Ops" event is named by the operation's whole HLO text:
 #   %fusion.10 = bf16[256,112,112,64]{0,3,2,1:T(8,128)(2,1)} fusion(pred[...
@@ -53,7 +56,14 @@ class Session:
 
         self.dir = tempfile.mkdtemp(prefix="chipbench_trace_")
         self.stopped = False
-        jax.profiler.start_trace(self.dir)
+        # annotations and device operations only: the profiler's Python
+        # tracer hooks every call of every thread, which nothing here
+        # reads, which taxes the host thread that feeds the device, and
+        # which a process with thousands of waiting request threads does
+        # not survive (PERF.md, PR 29)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self.dir, profiler_options=options)
 
     def stop(self):
         import jax
@@ -83,7 +93,7 @@ def start():
 
 def load_events(path):
     """Plain events of an ``.xplane.pb``: device planes' op lines and the
-    host planes' ``chipbench.*`` annotations."""
+    host planes' ``chipbench.*`` and ``mxtpu.*`` annotations."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -137,10 +147,39 @@ def _union(intervals):
     return sum(e - s for s, e in merged), merged
 
 
+def composition(events, top=10):
+    """The ``top`` longest idle gaps of the first device: (seconds, {span:
+    seconds of the gap during which it was the innermost open span}), where
+    a span is an annotation of the host and ``BETWEEN`` stands for none."""
+    dev = [e for e in events if DEVICE_PLANE.match(e["plane"])]
+    first = min((e["plane"] for e in dev), default=None,
+                key=lambda p: int(DEVICE_PLANE.match(p).group(1)))
+    _, busy = _union([(e["start"], e["start"] + e["dur"])
+                      for e in dev if e["plane"] == first])
+    gaps = sorted(((busy[i + 1][0] - busy[i][1], busy[i][1], busy[i + 1][0])
+                   for i in range(len(busy) - 1)), reverse=True)[:top]
+    host = [(e["start"], e["start"] + e["dur"], e["name"]) for e in events
+            if not DEVICE_PLANE.match(e["plane"])]
+    out = []
+    for length, lo, hi in gaps:
+        over = [h for h in host if h[0] < hi and h[1] > lo]
+        cuts = sorted({lo, hi} | {t for h in over for t in h[:2]
+                                  if lo < t < hi})
+        spans = {}
+        for a, b in zip(cuts, cuts[1:]):
+            open_ = [h for h in over if h[0] <= a and h[1] >= b]
+            # innermost: the latest to start and, of those, the first to end
+            name = max(open_, key=lambda h: (h[0], -h[1]))[2] if open_ \
+                else BETWEEN
+            spans[name] = spans.get(name, 0.0) + (b - a)
+        out.append((length, spans))
+    return out
+
+
 def reduce(events, chips):
     """Busy and idle time, operation classes, Mosaic share, exposed
-    collectives, the ten largest operations and the ten longest idle gaps
-    (named by the harness annotation the gap began in)."""
+    collectives, the ten largest operations and the ten longest idle gaps,
+    each named by the innermost span that covers most of it."""
     by_dev = {}
     for e in events:
         m = DEVICE_PLANE.match(e["plane"])
@@ -149,8 +188,6 @@ def reduce(events, chips):
     devs = sorted(by_dev)[:chips]
     if not devs:
         return None
-    host = sorted((e for e in events if not DEVICE_PLANE.match(e["plane"])),
-                  key=lambda e: e["start"])
     t_lo = min(e["start"] for d in devs for e in by_dev[d])
     t_hi = max(e["start"] + e["dur"] for d in devs for e in by_dev[d])
     busy, class_s, op_s = [], {}, {}
@@ -186,22 +223,8 @@ def reduce(events, chips):
             k += 1
     exposed_s = coll_total - covered
 
-    # idle gaps of the first device, named by what the harness was doing
-    _, merged = _union([(e["start"], e["start"] + e["dur"]) for e in first])
-    gaps = [(merged[i + 1][0] - merged[i][1], merged[i][1])
-            for i in range(len(merged) - 1)]
-    gaps.sort(reverse=True)
-
-    def doing(t):
-        name = "host_between_annotations"
-        for h in host:
-            if h["start"] > t:
-                break
-            if h["start"] + h["dur"] >= t:
-                name = h["name"]
-        return name
-
-    top = [[doing(t), g] for g, t in gaps[:10]]
+    gap_spans = composition(events)
+    top = [[max(spans, key=spans.get), g] for g, spans in gap_spans]
     ops = sorted(([k, v] for k, v in op_s.items()), key=lambda kv: -kv[1])
     classes = sorted((["all_%s_ops" % k, v] for k, v in class_s.items()),
                      key=lambda kv: -kv[1])
@@ -209,7 +232,7 @@ def reduce(events, chips):
         "busy_s": busy_s, "window_s": window_s, "devices": len(devs),
         "class_s": class_s, "mosaic_s": class_s.get("mosaic", 0.0),
         "collective_s": coll_total, "collective_exposed_s": exposed_s,
-        "idle_gap_total_s": sum(g for g, _ in gaps), "gaps": len(gaps),
+        "idle_gap_spans": gap_spans,
         "breakdown": {"device_ops": (classes[:5] + ops[:5])[:10],
-                      "idle_gaps": top[:10]},
+                      "idle_gaps": top},
     }

@@ -24,6 +24,7 @@ import copy  # noqa: E402
 import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
@@ -120,7 +121,8 @@ class Options:
     """What the command line gives (seed, seconds, trace), and what only a
     builder's tool or a test sets: ``rehearse`` walks the cell at the tiny
     sizes its files give under "rehearse" on whatever JAX has and reports
-    counts only; ``series`` is called with the run's per-step series;
+    counts only; ``series`` is called with the run's per-step series and its
+    reduced trace;
     ``control`` names lower precisions whose numbers are printed beside the
     check's; ``break_step`` is handed the trainer or the model to break;
     ``overrides`` alters values of the traffic or the configuration."""
@@ -164,7 +166,23 @@ def run_cell(workload, opts, t_process=None, root=ROOT):
         if tr is not None and not opts.rehearse:
             dev["busy_s"], dev["window_s"] = tr["busy_s"], tr["window_s"]
             result["breakdown"] = tr["breakdown"]
+    # every number compared, beside its limit: the line's last key (a
+    # number that is not finite is null there: the line stays JSON)
+    result["checks"] = {
+        name: {"value": float(c["value"]) if math.isfinite(c["value"])
+               else None, "limit": c["limit"]}
+        for name, c in facts["checks"].items()}
     return result
+
+
+def emit(result):
+    """Each number compared beside its limit as the last lines of standard
+    error, and the result as the last line of standard output."""
+    sys.stdout.flush()
+    for name, c in result["checks"].items():
+        print("check %s %s limit %s" % (name, c["value"], c["limit"]),
+              file=sys.stderr, flush=True)
+    print(json.dumps(result), flush=True)
 
 
 def main(argv=None):
@@ -174,10 +192,7 @@ def main(argv=None):
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
-    result = run_cell(args.workload,
-                      Options(args.seed, args.seconds, args.trace))
-    sys.stdout.flush()
-    print(json.dumps(result), flush=True)
+    emit(run_cell(args.workload, Options(args.seed, args.seconds, args.trace)))
     return 0
 
 

@@ -90,6 +90,14 @@ class _Poller(threading.Thread):
         self.done.set()
 
 
+def horizon_s(traffic, seconds, trace):
+    """The seconds of arrivals a run offers: the warm-up, the window, two
+    slices of slack for edges that snap late, and a traced run's seconds
+    after the window."""
+    return float(traffic["warm_s"]) + seconds + 2 * float(traffic["slice_s"]) \
+        + (float(traffic["trace_s"]) + 10.0 if trace else 0.0)
+
+
 def _latency(records, t_open, t_close):
     lat = [1e3 * (r["t_done"] - r["t_from"]) for r in records
            if r["status"] == 200 and t_open <= r["t_done"] <= t_close]
@@ -152,9 +160,8 @@ def run(cell, config, traffic, opts, t_process):
         shutil.rmtree(workdir, ignore_errors=True)
     if opts.break_step:
         opts.break_step(model)
-    horizon = warm_s + opts.seconds + 2 * slice_s \
-        + (float(traffic["trace_s"]) + 10.0 if opts.trace else 0.0)
-    requests = loadgen.plan(traffic, opts.seed, sizes["vocab_size"], horizon)
+    requests = loadgen.plan(traffic, opts.seed, sizes["vocab_size"],
+                            horizon_s(traffic, opts.seconds, opts.trace))
     load = loadgen.Load(traffic, requests, server.port, PATH)
     stamp("requests")
     compile_s = goodput.totals()["phases"].get("compile", 0.0)
@@ -235,15 +242,20 @@ def run(cell, config, traffic, opts, t_process):
         "compiles_in_window": compiles_in_window,
         "trace": None if tracing is None else tracing.reduce(chips),
     }
+    served = facts["serve"]
     print("setup: " + "; ".join(stamps) + "; load before the window %.1f s"
           % (t_open - load.t0), flush=True)
     print("window: %.3f s, %.2f tokens/s; %d slices of about %.1f s: median "
           "%.2f, min %.2f, max %.2f; stall share %.3f%%; %d sequences "
-          "active at open; compiles inside the window %d"
+          "active at open, %d decode steps of %.2f on average; compiles "
+          "inside the window %d"
           % (window["window_s"], window["mean_rate"], window["slices"],
              slice_s, window["median_rate"], window["min_rate"],
              window["max_rate"], window["stall_share_pct"],
-             edges[0]["active"], compiles_in_window), flush=True)
+             edges[0]["active"], served["decode_steps"],
+             (served["tokens"] - served["prefills"])
+             / max(1, served["decode_steps"]), compiles_in_window),
+          flush=True)
     print("requests: sent %d, answered by the end of the run %d, good %d, "
           "failed %d, all client threads joined %s; generator late by max "
           "%.1f ms; counter saw %d tokens over the load, replies hold %d, "
@@ -268,13 +280,16 @@ def run(cell, config, traffic, opts, t_process):
     if opts.series:
         opts.series({"edges": edges, "records": [
             {k: v for k, v in r.items() if k != "tokens"} for r in records],
-            "setup_s": setup_s, "t0": load.t0})
+            "setup_s": setup_s, "t0": load.t0, "trace": facts["trace"]})
 
     # ---- correctness, once the window has closed and the model is freed ---
     t0 = time.perf_counter()
     del model, repo, server
     finished = [r for r in good if t_open <= r["t_done"] <= t_close]
     ok = bool(finished) and failed == 0 and compiles_in_window == 0
+    facts["checks"] = checks = {
+        "failed_requests": {"value": failed, "limit": 0},
+        "compiles_in_window": {"value": compiles_in_window, "limit": 0}}
     if finished:
         picked = _sample(finished, requests, int(traffic["check_requests"]),
                          opts.seed)
@@ -286,6 +301,8 @@ def run(cell, config, traffic, opts, t_process):
         limit = traffic["limits"]["served_gap_per_1k"]
         good_gap = got["served_gap_per_1k"] <= limit
         ok = ok and good_gap
+        checks["served_gap_per_1k"] = {"value": got["served_gap_per_1k"],
+                                       "limit": limit}
         print("check served_gap_per_1k %.6g  limit %.6g  %s  (%d served tokens "
               "of %d requests, %d of them not the reference's best, widest "
               "gap %.6g; reference took %.1f s)"

@@ -284,7 +284,7 @@ def run(cell, config, traffic, opts, t_process):
     if opts.series:
         opts.series({"done": done, "wait": waits, "dispatch": dispatches,
                      "warm_steps": warm_steps, "t_process": t_process,
-                     "setup_s": setup_s})
+                     "setup_s": setup_s, "trace": facts["trace"]})
 
     # ---- correctness, outside the window and outside set-up --------------
     del trainer, sharded, feed
@@ -309,6 +309,10 @@ def run(cell, config, traffic, opts, t_process):
                            precision)
             print("control %s %s" % (precision, {
                 k: v for k, v in compare(c, reference).items()}), flush=True)
+    facts["checks"] = dict(
+        {name: {"value": value, "limit": limit}
+         for name, value, limit, _ in rows},
+        compiles_in_window={"value": compiles_in_window, "limit": 0})
     facts["correct"] = ok
     facts["attempted"] = n_in
     facts["failed"] = 0

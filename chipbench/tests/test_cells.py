@@ -118,6 +118,43 @@ def test_a_cell_a_mix_and_a_metric_are_added_by_files_alone(
     assert set(result["metrics"]) == {metric}
 
 
+def _open_loops_judged_on_their_rate():
+    """(cell, its traffic) for every open-loop mix whose cell reports
+    `output_token_rate`."""
+    bench = _bench()
+    rated = next(m for m in bench["end_to_end"]
+                 if m["name"] == "output_token_rate")
+    out = []
+    for w in bench["workloads"]:
+        path = os.path.join(ROOT, "chipbench", "traffic",
+                            w["traffic"] + ".json")
+        with open(path) as f:
+            mix = json.load(f)
+        if mix.get("loop") == "open" and \
+                w["name"] in rated.get("workloads", [w["name"]]):
+            out.append((w["name"], mix))
+    return out
+
+
+@pytest.mark.parametrize("cell,mix", _open_loops_judged_on_their_rate(),
+                         ids=lambda v: v if isinstance(v, str) else "mix")
+def test_an_open_loop_judged_on_its_rate_offers_well_above_the_knee(cell,
+                                                                    mix):
+    """Such a cell reads the engine only while it offers more than the engine
+    completes; at or under the knee it reads its own Poisson draw (PERF.md,
+    PR 29).  And the backlog that builds by the end of a traced run must not
+    reach the queue's depth, or requests are refused; nor may its threads (a
+    client's and a handler's for each queued request) pass three quarters
+    of the 4096 at which the chip machine kills the process."""
+    from chipbench.runners import serve
+
+    assert mix["rate_rps"] >= 1.5 * mix["sustained_rps"] > 0, cell
+    horizon = serve.horizon_s(mix, _bench()["run_seconds"], trace=True)
+    backlog = mix["burst"] + (mix["rate_rps"] - mix["sustained_rps"]) * horizon
+    assert backlog < mix["queue_depth"], (cell, backlog)
+    assert 2 * backlog < 0.75 * 4096, (cell, backlog)
+
+
 def _zero_learning_rate(trainer):
     # a step that returns its state unchanged
     trainer.set_learning_rate(0.0)

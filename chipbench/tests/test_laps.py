@@ -1,6 +1,4 @@
-"""The readers of the program's own lap account on a hand-made ring, and the
-builder's tool that names idle gaps on synthetic events."""
-import importlib.util
+"""The readers of the program's own lap account on a hand-made ring."""
 import os
 
 import pytest
@@ -109,35 +107,3 @@ def test_too_few_traced_laps_or_no_ring_leave_the_metric_out(
     # a program without the ring (the parent of the PR that brought this)
     monkeypatch.delattr(goodput, "window")
     assert _read(metric, {"kind": kind}) is None
-
-
-def test_gaps_tool_says_what_the_host_did_over_a_gap():
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_tools_gaps", os.path.join(os.path.dirname(HERE), "tools",
-                                             "gaps.py"))
-    gaps = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(gaps)
-
-    def ev(plane, name, start, dur):
-        return {"plane": plane, "line": "x", "name": name, "start": start,
-                "dur": dur}
-
-    dev, host = "/device:TPU:0", "/host:CPU"
-    events = [
-        ev(dev, "fusion.1", 0.0, 1.0), ev(dev, "fusion.2", 1.010, 1.0),
-        ev(dev, "fusion.3", 2.012, 0.5),
-        ev(host, "mxtpu.serve.lap", 0.5, 0.508),
-        ev(host, "mxtpu.serve.decode_dispatch", 0.5, 0.502),
-        ev(host, "mxtpu.serve.decode_wait", 0.6, 0.402),   # ends at 1.002
-        ev(host, "mxtpu.serve.retire", 1.002, 0.004),
-        ev(host, "mxtpu.serve.lap", 1.0085, 0.9),
-        ev(host, "mxtpu.serve.admit", 1.0085, 0.0005),
-    ]
-    (first, spans), (second, other) = gaps.composition(events)
-    assert first == pytest.approx(0.010)
-    assert spans == pytest.approx({
-        "mxtpu.serve.decode_wait": 0.002, "mxtpu.serve.retire": 0.004,
-        "mxtpu.serve.lap": 0.003, "host_between_annotations": 0.0005,
-        "mxtpu.serve.admit": 0.0005})
-    assert second == pytest.approx(0.002) and set(other) == {
-        "host_between_annotations"}

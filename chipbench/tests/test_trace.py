@@ -93,3 +93,41 @@ def test_idle_gaps_exposed_collectives_and_two_chips():
     one = trace.reduce(events, 1)
     assert one["devices"] == 1 and one["busy_s"] == pytest.approx(2.5)
     assert trace.reduce([e for e in events if e["plane"] == host], 1) is None
+
+
+def test_a_gap_is_named_by_the_innermost_span_that_covers_most_of_it():
+    dev, host = "/device:TPU:0", "/host:CPU"
+    events = [
+        _ev(dev, "fusion.1", 0.0, 1.0), _ev(dev, "fusion.2", 1.010, 1.0),
+        _ev(dev, "fusion.3", 2.012, 0.5), _ev(dev, "fusion.4", 2.520, 0.5),
+        _ev(dev, "fusion.5", 3.021, 0.5),
+        _ev(host, "mxtpu.serve.lap", 0.5, 0.508),
+        _ev(host, "mxtpu.serve.decode_dispatch", 0.5, 0.502),
+        _ev(host, "mxtpu.serve.decode_wait", 0.6, 0.402),   # ends at 1.002
+        _ev(host, "mxtpu.serve.retire", 1.002, 0.004),
+        _ev(host, "mxtpu.serve.lap", 1.0085, 0.9),
+        _ev(host, "mxtpu.serve.admit", 1.0085, 0.0005),
+        # a gap wholly inside a dispatch nested in a lap; the lap's own span
+        # starts earlier and ends later
+        _ev(host, "mxtpu.serve.lap", 2.4, 0.7),
+        _ev(host, "mxtpu.serve.decode_dispatch", 2.5, 0.03),
+        # the training harness's wait, beside a step of the program's that
+        # ended before the gap
+        _ev(host, "mxtpu.dist.step", 2.9, 0.1),
+        _ev(host, "chipbench.wait_inflight", 3.01, 0.02),
+    ]
+    gaps = trace.composition(events)
+    assert [g for g, _ in gaps] == pytest.approx([0.010, 0.008, 0.002, 0.001])
+    assert gaps[0][1] == pytest.approx({
+        "mxtpu.serve.decode_wait": 0.002, "mxtpu.serve.retire": 0.004,
+        "mxtpu.serve.lap": 0.003, trace.BETWEEN: 0.0005,
+        "mxtpu.serve.admit": 0.0005})
+    assert trace.reduce(events, 1)["breakdown"]["idle_gaps"] == [
+        ["mxtpu.serve.retire", pytest.approx(0.010)],
+        ["mxtpu.serve.decode_dispatch", pytest.approx(0.008)],
+        [trace.BETWEEN, pytest.approx(0.002)],
+        ["chipbench.wait_inflight", pytest.approx(0.001)]]
+    assert trace.composition(events, top=1) == gaps[:1]
+    assert all(n.startswith(trace.ANNOTATION_PREFIX) for n in
+               ("mxtpu.serve.lap", "chipbench.next_feed"))
+    assert not "jax.block_until_ready".startswith(trace.ANNOTATION_PREFIX)

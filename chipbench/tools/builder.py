@@ -47,9 +47,7 @@ def main(argv=None):
         args.control.split(",") if args.control else None,
         overrides={k: json.loads(v) for k, v in
                    (kv.split("=", 1) for kv in args.set)})
-    result = bench_run.run_cell(args.workload, opts)
-    sys.stdout.flush()
-    print(json.dumps(result), flush=True)
+    bench_run.emit(bench_run.run_cell(args.workload, opts))
     return 0
 
 
