@@ -11,6 +11,7 @@ from . import register
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..base import np_dtype, device_int_dtype as _device_int_dtype
 
@@ -126,31 +127,39 @@ def shuffle(rng, data):
 # temperature<=0, top-k/top-p are nucleus filters on the logits — so a
 # mixed decode batch with per-row parameters stays ONE executable
 # (`sample_token_logits` takes arrays; the registered op takes the attr
-# spelling for nd/symbol callers).
+# spelling for nd/symbol callers). The executable branches on what its rows
+# ask for: a batch of greedy rows never sorts the vocabulary.
 # --------------------------------------------------------------------------
 
-def _top_k_logits(logits, k):
-    """Mask logits outside each row's top-k (k<=0 disables; k may be a
-    scalar or a per-row array)."""
-    v = logits.shape[-1]
-    kk = jnp.broadcast_to(jnp.asarray(k, jnp.int32), logits.shape[:-1])
+def _filter_logits(lf, top_k, top_p):
+    """Mask float32 logits outside each row's top-k (k<=0 disables) and
+    then outside its nucleus: the smallest prefix of descending-probability
+    tokens whose mass reaches p (always at least the argmax; p<=0 or p>=1
+    disables). Scalar or per-row k and p. One descending sort serves both
+    thresholds: the top-k-masked row in sorted order is the sorted row with
+    its tail masked (ties at the threshold are kept either way)."""
+    v = lf.shape[-1]
+    kk = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), lf.shape[:-1])
     kk = jnp.clip(jnp.where(kk <= 0, v, kk), 1, v)
-    desc = jnp.flip(jnp.sort(logits, axis=-1), axis=-1)
-    thr = jnp.take_along_axis(desc, (kk - 1)[..., None], axis=-1)
-    return jnp.where(logits >= thr, logits, -jnp.inf)
-
-
-def _top_p_logits(logits, p):
-    """Nucleus filter: keep the smallest prefix of descending-probability
-    tokens whose mass reaches p (always at least the argmax; p<=0 or
-    p>=1 disables). Scalar or per-row p."""
-    pp = jnp.broadcast_to(jnp.asarray(p, jnp.float32), logits.shape[:-1])
+    pp = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), lf.shape[:-1])
     pp = jnp.where((pp <= 0.0) | (pp >= 1.0), 1.0, pp)
-    desc = jnp.flip(jnp.sort(logits, axis=-1), axis=-1)
+    desc = jnp.flip(jnp.sort(lf, axis=-1), axis=-1)
+    thr_k = jnp.take_along_axis(desc, (kk - 1)[..., None], axis=-1)
+    desc = jnp.where(desc >= thr_k, desc, -jnp.inf)
     probs = jax.nn.softmax(desc, axis=-1)
     keep = (jnp.cumsum(probs, axis=-1) - probs) < pp[..., None]
-    thr = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1, keepdims=True)
-    return jnp.where(logits >= thr, logits, -jnp.inf)
+    thr_p = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1, keepdims=True)
+    masked = jnp.where(lf >= thr_k, lf, -jnp.inf)
+    return jnp.where(masked >= thr_p, masked, -jnp.inf)
+
+
+def _if_any(x, on_true, on_false):
+    """``on_true()`` if any element of ``x`` holds, else ``on_false()``:
+    chosen while tracing for Python and numpy values, by a `lax.cond` in the
+    program for jax arrays and tracers."""
+    if isinstance(x, jax.Array):
+        return jax.lax.cond(jnp.any(x), on_true, on_false)
+    return on_true() if np.any(x) else on_false()
 
 
 def sample_token_logits(rng, logits, temperature=1.0, top_k=0, top_p=1.0):
@@ -158,15 +167,35 @@ def sample_token_logits(rng, logits, temperature=1.0, top_k=0, top_p=1.0):
     where temperature<=0, else temperature-scaled categorical over the
     top-k/top-p-filtered distribution. Parameters may be scalars or
     per-row arrays (the decode scheduler batches requests with different
-    sampling knobs into one executable). Returns int32 (...)."""
+    sampling knobs into one executable). Returns int32 (...).
+
+    The work follows the rows. With array parameters the program holds two
+    `lax.cond`s on them: a batch whose rows are all greedy computes the
+    argmax and nothing else; a batch with sampled rows draws, and sorts the
+    vocabulary (once) only if a sampled row sets a top-k or a top-p. Every
+    row gets what it asked for whatever the rest of the batch asks. With
+    Python scalars the same choices are made while tracing and no `cond`
+    is in the graph. (Under `vmap` a `cond` becomes a select and both
+    branches run.)"""
     t = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32),
                          logits.shape[:-1])
     lf = logits.astype(jnp.float32)
-    masked = _top_p_logits(_top_k_logits(lf, top_k), top_p)
-    scaled = masked / jnp.maximum(t, 1e-6)[..., None]
-    drawn = jax.random.categorical(rng, scaled, axis=-1)
-    return jnp.where(t <= 0.0, jnp.argmax(lf, axis=-1),
-                     drawn).astype(jnp.int32)
+
+    def greedy():
+        return jnp.argmax(lf, axis=-1).astype(jnp.int32)
+
+    def draw(masked):
+        scaled = masked / jnp.maximum(t, 1e-6)[..., None]
+        drawn = jax.random.categorical(rng, scaled, axis=-1)
+        return jnp.where(t <= 0.0, greedy(), drawn).astype(jnp.int32)
+
+    def sampled():
+        filters = (top_k > 0) | ((top_p > 0.0) & (top_p < 1.0))
+        return _if_any((temperature > 0.0) & filters,
+                       lambda: draw(_filter_logits(lf, top_k, top_p)),
+                       lambda: draw(lf))
+
+    return _if_any(temperature > 0.0, sampled, greedy)
 
 
 @register("_sample_token", needs_rng=True, aliases=("sample_token",))
@@ -174,7 +203,9 @@ def sample_token(rng, data, temperature=1.0, top_k=0, top_p=1.0,
                  dtype="int32"):
     """data: (..., V) logits -> (...) sampled token ids (greedy /
     temperature / top-k / top-p per the attrs; one threefry subkey per
-    call, ops/random_ops.py convention)."""
+    call, ops/random_ops.py convention). The attrs are Python scalars, so
+    the graph holds only what they ask for: the argmax alone at
+    temperature<=0, no sort without a top-k or a top-p."""
     out = sample_token_logits(rng, data, temperature=float(temperature),
                               top_k=int(top_k), top_p=float(top_p))
     return out.astype(np_dtype(dtype))

@@ -35,8 +35,10 @@ repo already has:
   * **sampling** (greedy / temperature / top-k / top-p) is folded into
     the decode executable with PER-ROW parameter arrays
     (`ops/random_ops.sample_token_logits`), so a mixed batch of greedy
-    and stochastic requests stays one executable; every step consumes
-    one threefry subkey from the global chain.
+    and stochastic requests stays one executable, which branches on the
+    device on what its rows ask for (an all-greedy batch takes the argmax
+    and sorts nothing); every step consumes one threefry subkey from the
+    global chain.
 
 `TransformerLMEngine` runs a `gluon.model_zoo.transformer.TransformerLM`
 (decoder-only, tied embedding head) in incremental form: the pure-jax
@@ -306,6 +308,12 @@ class GenerateScheduler:
                                           labels)
         self._m_tokens = telemetry.counter(
             "mxtpu_serve_generated_tokens_total", labels)
+        # decode steps by what the sampler had to do: `greedy` steps take
+        # the argmax alone, `sampled` ones hold a row with a temperature
+        self._m_sampler = {
+            path: telemetry.counter("mxtpu_serve_sampler_steps_total",
+                                    {"model": self.name, "path": path})
+            for path in ("greedy", "sampled")}
         self._m_rej_full = telemetry.counter(
             "mxtpu_serve_rejected_total",
             {"model": self.name, "reason": "queue_full"})
@@ -476,8 +484,8 @@ class GenerateScheduler:
                 if self._stop:
                     return
             _goodput.step_start(kind="serve")
-            self._lap = {"n": 0, "bucket": 0, "prefills": 0, "admitted": 0,
-                         "queue_wait_s": 0.0}
+            self._lap = {"n": 0, "bucket": 0, "sampled": 0, "prefills": 0,
+                         "admitted": 0, "queue_wait_s": 0.0}
             try:
                 with _goodput.phase("admit"):
                     self._admit()
@@ -651,13 +659,15 @@ class GenerateScheduler:
                 temps[i] = seq.req.temperature
                 top_ks[i] = seq.req.top_k
                 top_ps[i] = seq.req.top_p
+            sampled = int((temps > 0).sum())
         # the engine claims `decode_wait` inside this phase
         with _goodput.phase("decode_dispatch") as step:
             nxt = self.engine.decode_step(tokens, positions, dest_pages,
                                           dest_slots, tables, lengths, temps,
                                           top_ks, top_ps, _random.next_key())
-        self._lap["n"], self._lap["bucket"] = n, bucket
+        self._lap.update(n=n, bucket=bucket, sampled=sampled)
         self._m_steps.inc()
+        self._m_sampler["sampled" if sampled else "greedy"].inc()
         self._m_decode.observe(step.elapsed)
         with _goodput.phase("retire"):
             now = time.perf_counter()

@@ -298,6 +298,166 @@ def test_sample_token_logits_per_row_params():
     assert out[3] in np.argsort(logits[3])[-4:]
 
 
+def _two_sort_sampler(rng, logits, temperature=1.0, top_k=0, top_p=1.0):
+    """The sampler as it stood before it branched on its rows, frozen here
+    as the reference: a sort of the vocabulary for top-k's threshold, a
+    second for top-p's and the draw, in every call, then the per-row
+    choice between argmax and drawn."""
+    import jax
+    import jax.numpy as jnp
+
+    def top_k_logits(logits, k):
+        v = logits.shape[-1]
+        kk = jnp.broadcast_to(jnp.asarray(k, jnp.int32), logits.shape[:-1])
+        kk = jnp.clip(jnp.where(kk <= 0, v, kk), 1, v)
+        desc = jnp.flip(jnp.sort(logits, axis=-1), axis=-1)
+        thr = jnp.take_along_axis(desc, (kk - 1)[..., None], axis=-1)
+        return jnp.where(logits >= thr, logits, -jnp.inf)
+
+    def top_p_logits(logits, p):
+        pp = jnp.broadcast_to(jnp.asarray(p, jnp.float32),
+                              logits.shape[:-1])
+        pp = jnp.where((pp <= 0.0) | (pp >= 1.0), 1.0, pp)
+        desc = jnp.flip(jnp.sort(logits, axis=-1), axis=-1)
+        probs = jax.nn.softmax(desc, axis=-1)
+        keep = (jnp.cumsum(probs, axis=-1) - probs) < pp[..., None]
+        thr = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1,
+                      keepdims=True)
+        return jnp.where(logits >= thr, logits, -jnp.inf)
+
+    t = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32),
+                         logits.shape[:-1])
+    lf = logits.astype(jnp.float32)
+    masked = top_p_logits(top_k_logits(lf, top_k), top_p)
+    scaled = masked / jnp.maximum(t, 1e-6)[..., None]
+    drawn = jax.random.categorical(rng, scaled, axis=-1)
+    return jnp.where(t <= 0.0, jnp.argmax(lf, axis=-1),
+                     drawn).astype(jnp.int32)
+
+
+def _sampler_rows(case):
+    """(logits, temperature, top_k, top_p) of eight rows over a vocabulary
+    of 300; all but `ties` and `bf16` share the logits."""
+    b, v = 8, 300
+    logits = (3.0 * np.random.RandomState(7).randn(b, v)).astype(np.float32)
+    t, k, p = (np.zeros(b, np.float32), np.zeros(b, np.int32),
+               np.ones(b, np.float32))
+    hot = np.asarray([0.3, 0.7, 1.0, 1.0, 1.3, 2.0, 5.0, 0.05], np.float32)
+    some_k = np.asarray([1, 2, 5, 40, 299, 7, 3, 100], np.int32)
+    some_p = np.asarray([1e-6, 0.1, 0.5, 0.9, 0.99, 0.3, 0.75, 0.999],
+                        np.float32)
+    if case == "all_greedy":
+        pass
+    elif case == "greedy_rows_that_set_filters":
+        k, p = some_k, some_p
+    elif case == "temperature_only":
+        t = hot
+    elif case == "top_k_only":
+        t, k = hot, some_k
+    elif case == "top_p_only":
+        t, p = hot, some_p
+    elif case == "top_k_and_top_p":
+        t, k, p = hot, some_k, some_p[::-1].copy()
+    elif case == "ties":
+        # a handful of distinct values: the top-k threshold falls inside a
+        # run of equal logits in every row, and both forms keep the run
+        logits = np.random.RandomState(8).randint(0, 6, (b, v)) \
+            .astype(np.float32)
+        t, k, p = hot, some_k, some_p
+    elif case == "top_k_at_and_over_the_vocabulary":
+        t = hot
+        k = np.asarray([v, v + 1, 10 * v, v - 1, v, 2 * v, v, v], np.int32)
+    elif case == "top_p_0_and_1":
+        t = hot
+        p = np.asarray([0.0, 1.0, 0.0, 1.0, -1.0, 2.0, 0.0, 1.0],
+                       np.float32)
+    elif case == "mixed":
+        t = np.asarray([0.0, 1.0, 0.0, 0.7, 0.0, 2.0, 1.0, 0.0], np.float32)
+        k = np.asarray([0, 0, 5, 5, 0, 0, 40, 0], np.int32)
+        p = np.asarray([1.0, 1.0, 0.5, 1.0, 1.0, 0.9, 0.6, 0.2], np.float32)
+    elif case == "bf16":
+        import jax.numpy as jnp
+
+        logits = jnp.asarray(logits, jnp.bfloat16)   # ties by rounding
+        t = np.asarray([0.0, 1.0, 0.0, 0.7, 0.0, 2.0, 1.0, 0.0], np.float32)
+        k, p = some_k, some_p
+    else:
+        raise AssertionError(case)
+    return logits, t, k, p
+
+
+@pytest.mark.parametrize("case", [
+    "all_greedy", "greedy_rows_that_set_filters", "temperature_only",
+    "top_k_only", "top_p_only", "top_k_and_top_p", "ties",
+    "top_k_at_and_over_the_vocabulary", "top_p_0_and_1", "mixed", "bf16"])
+def test_sampler_returns_what_the_two_sort_sampler_did(case):
+    """Same key, same logits, same per-row parameters: the same token in
+    every row, as one executable that branches on the device (the engine's
+    form) and with host values, where the branch is taken while tracing."""
+    import jax
+
+    from mxnet_tpu.ops.random_ops import sample_token_logits
+
+    logits, t, k, p = _sampler_rows(case)
+    old, new = jax.jit(_two_sort_sampler), jax.jit(sample_token_logits)
+    for seed in range(6):
+        key = jax.random.PRNGKey(seed)
+        want = np.asarray(old(key, logits, t, k, p))
+        assert want.dtype == np.int32
+        got = np.asarray(new(key, logits, t, k, p))
+        assert np.array_equal(got, want), (seed, got, want)
+        host = np.asarray(sample_token_logits(key, logits, t, k, p))
+        assert np.array_equal(host, want), (seed, host, want)
+    greedy = np.argmax(np.asarray(logits, np.float32), axis=-1)
+    assert np.array_equal(want[t <= 0], greedy[t <= 0])
+    if case in ("top_k_only", "ties"):
+        assert not np.array_equal(want, greedy)     # it did draw
+
+
+def _primitives(jaxpr):
+    """Every primitive name in a jaxpr, sub-jaxprs included."""
+    import jax
+
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names.extend(_primitives(sub))
+    return names
+
+
+@pytest.mark.parametrize("form,conds,sorts", [
+    ("arrays", 2, 1),               # the engine's: decided on the device
+    ("scalars_greedy", 0, 0),       # sample_token(temperature=0.0)
+    ("scalars_temperature", 0, 0),
+    ("scalars_top_k", 0, 1),
+    ("scalars_top_p", 0, 1),
+])
+def test_sampler_graph_holds_only_what_was_asked(form, conds, sorts):
+    import jax
+
+    from mxnet_tpu.ops import random_ops
+
+    key = jax.random.PRNGKey(0)
+    logits, t, k, p = _sampler_rows("mixed")
+    if form == "arrays":
+        jaxpr = jax.make_jaxpr(random_ops.sample_token_logits)(
+            key, logits, t, k, p)
+    else:
+        attrs = {"scalars_greedy": dict(temperature=0.0, top_k=5, top_p=0.5),
+                 "scalars_temperature": dict(temperature=0.8),
+                 "scalars_top_k": dict(temperature=0.8, top_k=5),
+                 "scalars_top_p": dict(temperature=0.8, top_p=0.5)}[form]
+        jaxpr = jax.make_jaxpr(
+            lambda key, x: random_ops.sample_token(key, x, **attrs))(
+                key, logits)
+    names = _primitives(jaxpr.jaxpr)
+    assert names.count("cond") == conds, names
+    assert names.count("sort") == sorts, names
+    if form == "scalars_greedy":
+        assert not {"random_bits", "div", "cumsum"} & set(names), names
+
+
 # ---------------------------------------------------------------------------
 # scheduler semantics on a stub engine (no jax compiles: fast, exact)
 # ---------------------------------------------------------------------------
@@ -452,6 +612,37 @@ def test_scheduler_abort_reclaims_pages():
         assert sched.allocator.used_pages == 0
     finally:
         sched.close(drain=False, timeout=0)
+
+
+@pytest.mark.parametrize("temperature,path,other", [
+    (0.0, "greedy", "sampled"), (0.8, "sampled", "greedy")])
+def test_scheduler_lap_records_what_the_sampler_was_asked(temperature, path,
+                                                          other):
+    """Every lap that stepped says in the accountant's ring how many of its
+    rows had a temperature, and the step counts under that path."""
+    from mxnet_tpu.telemetry import goodput
+
+    name = "stub/sampler-%s" % path
+
+    def steps(p):
+        series = telemetry.snapshot().get(
+            'mxtpu_serve_sampler_steps_total{model="%s",path="%s"}'
+            % (name, p))
+        return 0 if series is None else series["value"]
+
+    sched = GenerateScheduler(StubEngine(), name=name, queue_depth=8)
+    try:
+        for r in [sched.submit([1 + i], max_new_tokens=4,
+                               temperature=temperature) for i in range(3)]:
+            r.wait(10)
+    finally:
+        sched.close(drain=False, timeout=0)
+    laps = [r for r in goodput.window("serve")
+            if r["model"] == name and r["n"]]
+    assert laps
+    for lap in laps:
+        assert lap["sampled"] == (lap["n"] if temperature > 0 else 0)
+    assert steps(path) == len(laps) and steps(other) == 0
 
 
 # ---------------------------------------------------------------------------

@@ -235,22 +235,37 @@ def _engine_program(engine, kind, bucket):
         f32s(1), i32(1), f32s(1), key)
 
 
-@pytest.mark.parametrize("traffic,kind,bucket", [
+ENGINE_PROGRAMS = pytest.mark.parametrize("traffic,kind,bucket", [
     ("gpt2s_chat_open", "lm_decode", 64),
     ("gpt2s_docs_closed", "lm_prefill", 1024),
 ], ids=["lm_decode-b64", "lm_prefill-l1024"])
+_COMPILED = {}      # one compile (half a minute) a program, for every test
+
+
+def _compiled_engine_program(traffic, kind, bucket, v5e, monkeypatch):
+    """(the engine's pool bytes, pages and layers; the executable compiled
+    for the described v5e). The engine itself, 4 GB of zeros, is let go."""
+    if (traffic, kind, bucket) not in _COMPILED:
+        monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+        engine = _gpt2s_engine(traffic)
+        fn, args = _engine_program(engine, kind, bucket)
+        shapes = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                jnp.shape(a), jnp.asarray(a).dtype
+                if not hasattr(a, "dtype") else a.dtype, sharding=v5e),
+            args)
+        _COMPILED[traffic, kind, bucket] = (
+            (engine.kv_bytes(), engine.num_pages, engine.num_layers),
+            fn.lower(*shapes).compile())
+    return _COMPILED[traffic, kind, bucket]
+
+
+@ENGINE_PROGRAMS
 def test_engine_program_keeps_the_pool_in_place(traffic, kind, bucket, v5e,
                                                 monkeypatch):
-    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
-    engine = _gpt2s_engine(traffic)
-    pool = engine.kv_bytes()
+    (pool, num_pages, num_layers), compiled = _compiled_engine_program(
+        traffic, kind, bucket, v5e, monkeypatch)
     assert pool == 2 * 12 * 3072 * 16 * 768 * 4
-    fn, args = _engine_program(engine, kind, bucket)
-    shapes = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.asarray(a).dtype
-                                       if not hasattr(a, "dtype")
-                                       else a.dtype, sharding=v5e), args)
-    compiled = fn.lower(*shapes).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 0.05 * pool, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= pool, mem.alias_size_in_bytes
@@ -259,11 +274,40 @@ def test_engine_program_keeps_the_pool_in_place(traffic, kind, bucket, v5e,
         assert re.search(r"%\w*paged_attention_decode_*\.\d+ = ", text)
     # no instruction moves an array that holds the page count: the 24
     # leaves are scattered into and streamed from, nothing else
-    pages = str(engine.num_pages)
+    pages = str(num_pages)
     moved = [line.strip()[:120] for line in text.splitlines()
              if re.search(r" (copy|pad|slice|transpose|dynamic-slice)\(",
                           line)
              and pages in re.findall(
                  r"\d+", line.split(" = ", 1)[1].split("(", 1)[0])]
     assert not moved, moved
-    assert len(re.findall(r" scatter\(", text)) == 2 * engine.num_layers
+    assert len(re.findall(r" scatter\(", text)) == 2 * num_layers
+
+
+@ENGINE_PROGRAMS
+def test_engine_program_sorts_only_where_a_row_filters(traffic, kind, bucket,
+                                                       v5e, monkeypatch):
+    """The sampler branches on the device inside the bucket's one
+    executable: the entry computation holds a `conditional` and no `sort`;
+    the one sort of the vocabulary there is lies in a branch computation,
+    which a batch of greedy rows never enters."""
+    _, compiled = _compiled_engine_program(traffic, kind, bucket, v5e,
+                                           monkeypatch)
+    text = compiled.as_text()
+    bodies, entry = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.-]+) \(.*\{$", line)
+        if head:
+            name = head.group(2)
+            bodies[name] = []
+            entry = name if head.group(1) else entry
+        elif line.startswith(" "):
+            bodies[name].append(line)
+    sorts = {name: sum(" sort(" in line for line in body)
+             for name, body in bodies.items()}
+    assert sorts[entry] == 0
+    assert any(" conditional(" in line for line in bodies[entry])
+    assert sum(sorts.values()) <= 1, sorts
+    branches = set(re.findall(r"%([\w.-]+)", " ".join(
+        re.findall(r"branch_computations=\{([^}]*)\}", text))))
+    assert {name for name, n in sorts.items() if n} <= branches
