@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import importlib
 import shutil
+import sys
 import tempfile
 import threading
 import time
@@ -98,6 +99,19 @@ def horizon_s(traffic, seconds, trace):
         + (float(traffic["trace_s"]) + 10.0 if trace else 0.0)
 
 
+def _process_threads():
+    """Threads of this process as the kernel counts them (the chip machine
+    kills a process at 4096); None where /proc says nothing."""
+    try:
+        with open("/proc/self/status") as f:
+            for line in f:
+                if line.startswith("Threads:"):
+                    return int(line.split()[1])
+    except OSError:
+        pass
+    return None
+
+
 def _latency(records, t_open, t_close):
     lat = [1e3 * (r["t_done"] - r["t_from"]) for r in records
            if r["status"] == 200 and t_open <= r["t_done"] <= t_close]
@@ -106,6 +120,8 @@ def _latency(records, t_open, t_close):
     p50, _ = slices.percentile(lat, 50)
     p95, beyond = slices.percentile(lat, 95)
     return {"p50_ms": p50, "p95_ms": p95, "beyond_p95": beyond,
+            "p90_ms": slices.percentile(lat, 90)[0],
+            "p99_ms": slices.percentile(lat, 99)[0],
             "requests": len(lat), "max_ms": max(lat)}
 
 
@@ -170,11 +186,13 @@ def run(cell, config, traffic, opts, t_process):
     # ---- load, then the window -------------------------------------------
     tracing = None
     try:
+        poller = _Poller([])
+        # read before the load starts: while a closed loop's later clients
+        # start, its first are already being answered
+        at_load = poller.read(time.perf_counter())
         load.start()
         t_nominal = load.t0 + warm_s
-        poller = _Poller([t_nominal + k * slice_s
-                          for k in range(n_slices + 1)])
-        at_load = poller.read(load.t0)
+        poller.nominal = [t_nominal + k * slice_s for k in range(n_slices + 1)]
         poller.start()
         limit = t_nominal + opts.seconds + 60.0
         while not poller.done.wait(0.05):
@@ -187,6 +205,7 @@ def run(cell, config, traffic, opts, t_process):
         edges = poller.edges
         t_open, t_close = edges[0]["t"], edges[-1]["t"]
         setup_s = t_open - t_process
+        threads = [_process_threads()]      # at the window's close
         if opts.trace:
             # the profiler's own start and stop stall the host: it runs over
             # seconds of its own, once the window has closed and with the
@@ -194,6 +213,7 @@ def run(cell, config, traffic, opts, t_process):
             tracing = tracelib.start()
             time.sleep(float(traffic["trace_s"]))
             tracing.stop()
+        threads.append(_process_threads())  # as the load stops
         load.stop()
         peak = devlib.memory_peak_bytes(devs)
         if traffic["loop"] == "open":
@@ -259,11 +279,20 @@ def run(cell, config, traffic, opts, t_process):
     print("requests: sent %d, answered by the end of the run %d, good %d, "
           "failed %d, all client threads joined %s; generator late by max "
           "%.1f ms; counter saw %d tokens over the load, replies hold %d, "
-          "difference %d (tokens of requests cancelled or cut)"
-          % (len(load.late_s) if traffic["loop"] == "open" else load._next,
+          "difference %d (tokens of requests cancelled or cut); %d requests "
+          "planned; process threads %s at the window's close, %s as the load "
+          "stops"
+          % (len(load.late_s) if traffic["loop"] == "open"
+             else min(load._next, len(requests)),
              len(in_run), len(good), failed, joined,
              1e3 * max(load.late_s or [0.0]), counted, reply_tokens,
-             counted - reply_tokens), flush=True)
+             counted - reply_tokens, len(requests), threads[0], threads[1]),
+          flush=True)
+    if traffic["loop"] == "closed" and load._next >= len(requests):
+        # for the builder's eye: clients that found no request left went home
+        print("chipbench: %s: the plan of %d requests ran out: raise "
+              "max_requests" % (cell["name"], len(requests)),
+              file=sys.stderr, flush=True)
     sent_by_close = sum(1 for r in requests[:len(load.late_s)]
                         if load.t0 + r["due"] <= t_close) \
         if traffic["loop"] == "open" else None
@@ -274,9 +303,11 @@ def run(cell, config, traffic, opts, t_process):
               flush=True)
     if info:
         print("latency of the %d requests answered inside the window: p50 "
-              "%.1f ms, p95 %.1f ms (%d beyond it), max %.1f ms"
-              % (info["requests"], info["p50_ms"], info["p95_ms"],
-                 info["beyond_p95"], info["max_ms"]), flush=True)
+              "%.1f ms, p90 %.1f ms, p95 %.1f ms (%d beyond it), p99 %.1f ms, "
+              "max %.1f ms"
+              % (info["requests"], info["p50_ms"], info["p90_ms"],
+                 info["p95_ms"], info["beyond_p95"], info["p99_ms"],
+                 info["max_ms"]), flush=True)
     if opts.series:
         opts.series({"edges": edges, "records": [
             {k: v for k, v in r.items() if k != "tokens"} for r in records],

@@ -111,7 +111,7 @@ def test_every_seed_offers_the_same_multiset(name):
 
 
 def test_open_loop_due_times():
-    t = dict(_traffic("gpt2s_chat_open"), rate_rps=50.0, burst=10)
+    t = dict(_traffic("gpt2s_chat_open"), loop="open", rate_rps=50.0, burst=10)
     plan = loadgen.plan(t, 7, 100, 20.0)
     dues = [r["due"] for r in plan]
     assert dues[:10] == [0.0] * 10 and dues == sorted(dues)

@@ -68,6 +68,12 @@ def _serve_mix(mix):
     mix["rehearse"]["clients"] = 2
 
 
+def _open_loop_mix(mix):
+    # the same pairs offered at a rate, whatever the server does
+    mix["loop"] = "open"
+    mix["rehearse"].update(rate_rps=20.0, burst=8)
+
+
 def _four_chip_mix(mix):
     # a global batch of the mix's own, on the configuration's dp=4 mesh
     mix["batch"] = 1024
@@ -77,6 +83,8 @@ def _four_chip_mix(mix):
 @pytest.mark.parametrize("base,alter,config,chips,metric,source,reader", [
     ("gpt2s_docs_closed", _serve_mix, "gpt2_small", 1, "throwaway_requests",
      "program_counter", "    return float(facts['serve']['prefills'])\n"),
+    ("gpt2s_chat_open", _open_loop_mix, "gpt2_small", 1, "throwaway_steps",
+     "program_counter", "    return float(facts['serve']['decode_steps'])\n"),
     ("resnet50_train_bs256", _four_chip_mix, "resnet50_v1", 4,
      "throwaway_rows_a_chip", "program_counter",
      "    return facts['window']['slices'] and 8.0 / facts['chips']\n")])
@@ -84,7 +92,8 @@ def test_a_cell_a_mix_and_a_metric_are_added_by_files_alone(
         tmp_path, base, alter, config, chips, metric, source, reader):
     """A later PR adds a traffic mix, a cell and a per-layer metric as new
     files plus one entry each; no file that is there is edited.  The second
-    case is a cell on four chips, which the benchmark has none of yet."""
+    case is an open loop and the third a cell on four chips, of which the
+    benchmark has none."""
     root = str(tmp_path)
     shutil.copytree(os.path.join(ROOT, "chipbench"),
                     os.path.join(root, "chipbench"),
@@ -118,41 +127,54 @@ def test_a_cell_a_mix_and_a_metric_are_added_by_files_alone(
     assert set(result["metrics"]) == {metric}
 
 
-def _open_loops_judged_on_their_rate():
-    """(cell, its traffic) for every open-loop mix whose cell reports
-    `output_token_rate`."""
+def _mixes_judged_on_their_rate_alone():
+    """(cell, its traffic, its top decode bucket) for every serving cell
+    whose one end-to-end metric besides `setup_s` is `output_token_rate`."""
     bench = _bench()
-    rated = next(m for m in bench["end_to_end"]
-                 if m["name"] == "output_token_rate")
+    files = {c["name"]: c["file"] for c in bench["configs"]}
     out = []
     for w in bench["workloads"]:
-        path = os.path.join(ROOT, "chipbench", "traffic",
-                            w["traffic"] + ".json")
-        with open(path) as f:
+        judged = {m["name"] for m in bench["end_to_end"]
+                  if w["name"] in m.get("workloads", [w["name"]])}
+        if judged != {"output_token_rate", "setup_s"}:
+            continue
+        with open(os.path.join(ROOT, "chipbench", "traffic",
+                               w["traffic"] + ".json")) as f:
             mix = json.load(f)
-        if mix.get("loop") == "open" and \
-                w["name"] in rated.get("workloads", [w["name"]]):
-            out.append((w["name"], mix))
+        with open(os.path.join(ROOT, files[w["config"]])) as f:
+            engine = dict(json.load(f)["engine"], **mix["engine"])
+        out.append((w["name"], mix, max(engine["decode_buckets"])))
     return out
 
 
-@pytest.mark.parametrize("cell,mix", _open_loops_judged_on_their_rate(),
-                         ids=lambda v: v if isinstance(v, str) else "mix")
-def test_an_open_loop_judged_on_its_rate_offers_well_above_the_knee(cell,
-                                                                    mix):
-    """Such a cell reads the engine only while it offers more than the engine
-    completes; at or under the knee it reads its own Poisson draw (PERF.md,
-    PR 29).  And the backlog that builds by the end of a traced run must not
-    reach the queue's depth, or requests are refused; nor may its threads (a
-    client's and a handler's for each queued request) pass three quarters
-    of the 4096 at which the chip machine kills the process."""
+@pytest.mark.parametrize("cell,mix,top_bucket",
+                         _mixes_judged_on_their_rate_alone(),
+                         ids=lambda v: v if isinstance(v, str) else "")
+def test_a_mix_judged_on_its_rate_alone_keeps_the_decode_batch_full(
+        cell, mix, top_bucket):
+    """Such a cell reads the engine only while more requests wait than the
+    engine completes; otherwise it reads its own offer (PERF.md, PR 29 and
+    PR 36).  A client's thread and a handler's wait for every request
+    outstanding, and the chip machine kills a process at 4096 threads: they
+    stay under three quarters of that, and under the queue's depth, or
+    requests are refused.  A closed loop keeps at least four full decode
+    batches of clients, all of them started before the window opens; it
+    stays saturated at any engine speed.  An open loop offers at least 1.5
+    times the rate a sweep found sustained, and the backlog that builds by
+    the end of a traced run is held to the same two limits."""
     from chipbench.runners import serve
 
-    assert mix["rate_rps"] >= 1.5 * mix["sustained_rps"] > 0, cell
-    horizon = serve.horizon_s(mix, _bench()["run_seconds"], trace=True)
-    backlog = mix["burst"] + (mix["rate_rps"] - mix["sustained_rps"]) * horizon
-    assert backlog < mix["queue_depth"], (cell, backlog)
-    assert 2 * backlog < 0.75 * 4096, (cell, backlog)
+    if mix["loop"] == "closed":
+        waiting = mix["clients"]
+        assert waiting >= 4 * top_bucket, (cell, waiting)
+        assert mix["ramp_s"] <= mix["warm_s"], cell
+    else:
+        assert mix["rate_rps"] >= 1.5 * mix["sustained_rps"] > 0, cell
+        horizon = serve.horizon_s(mix, _bench()["run_seconds"], trace=True)
+        waiting = mix["burst"] + \
+            (mix["rate_rps"] - mix["sustained_rps"]) * horizon
+    assert waiting <= mix["queue_depth"], (cell, waiting)
+    assert 2 * waiting < 0.75 * 4096, (cell, waiting)
 
 
 def _zero_learning_rate(trainer):
