@@ -510,8 +510,8 @@ def phase_train_bert_base(cfg):
     b = cfg["bert"]
 
     class BERTPretrain(HybridBlock):
-        """The zoo encoder under a masked-LM vocabulary head (the
-        pretraining step bench.py's bert mode builds)."""
+        """The zoo encoder under a masked-LM vocabulary head: BERT's
+        pretraining step."""
 
         def __init__(self, **kwargs):
             super().__init__(**kwargs)

@@ -7,8 +7,8 @@ Three invariants, one choke point (``mxnet_tpu/env.py``):
      accessors (``env.get`` / ``env.raw`` / ``env.is_set``), so type,
      default and doc live in exactly one place;
   2. every name the code reads — via the accessors in the library, or via
-     ``os.environ`` literals in ``tools/`` and ``bench.py`` (which stay
-     import-free of the package) — is declared in the registry;
+     ``os.environ`` literals in ``tools/`` (which stay import-free of
+     the package) — is declared in the registry;
   3. the registry and the ``docs/env_vars.md`` Framework table agree
      exactly, both directions (the table is generated:
      ``python -m mxnet_tpu.env --markdown``).
@@ -128,8 +128,8 @@ class EnvRegistryChecker:
                         "`%s` is read via mxnet_tpu.env but not declared "
                         "in its registry (KeyError at runtime)" % name)
 
-        # 2: tools/bench read MXTPU_* names that must be registered
-        for rel in repo.py_files("tools", "bench.py"):
+        # 2: tools read MXTPU_* names that must be registered
+        for rel in repo.py_files("tools"):
             tree = repo.tree(rel)
             if tree is None:
                 continue

@@ -34,7 +34,7 @@ def enable_persistent_compile_cache():
     directory is set in code; otherwise the cache lives at the fixed
     ``<checkout>/.jax_cache`` — the path is part of the cache key, so it
     must never move between runs. Called by the entry points that run on
-    an accelerator (chip_smoke.py, bench.py), not at package import: the
+    an accelerator (chip_smoke.py), not at package import: the
     CPU test suite stays uncached (XLA:CPU AOT reloads warn about
     machine-feature mismatches and save little). This sits UNDER the
     framework's own executable-artifact tier (``MXTPU_COMPILE_CACHE`` →

@@ -53,8 +53,8 @@ _FALSY = ("0", "off", "none", "disable", "false", "no")
 def cache_dir(create=False):
     """The persistent tier's directory from ``MXTPU_COMPILE_CACHE``
     (``1``/``on`` -> the repo-local ``.mxtpu_compile_cache`` default), or
-    None when the tier is disabled. Read per call — arming the cache after
-    import (bench.py does, once it has found an accelerator) just works."""
+    None when the tier is disabled. Read per call, so arming the cache
+    after import just works."""
     choice = _env.raw("MXTPU_COMPILE_CACHE") or ""
     if not choice or choice.lower() in _FALSY:
         return None

@@ -152,8 +152,8 @@ _var("MXTPU_COMPILE_CACHE", "str", None,
      "steady state with zero recompiles. Not default-on: artifacts are "
      "machine-scoped (XLA:CPU AOT reloads across machine-feature "
      "mismatches risk SIGILL) and the directory must be trusted "
-     "(artifacts unpickle on load). `bench.py` arms it for accelerator "
-     "runs; manage with `python -m mxnet_tpu.compile`.")
+     "(artifacts unpickle on load). Manage with "
+     "`python -m mxnet_tpu.compile`.")
 _var("MXTPU_COMPILE_CACHE_ENTRIES", "int", 4096,
      "Capacity of the unified executable cache's in-memory LRU table "
      "(`mxnet_tpu.compile.registry`): oldest-touched executables are "
@@ -196,8 +196,8 @@ _var("MXTPU_PALLAS_CONV_EPILOGUE", "str", "auto",
      "channels-first always uses the jnp fallback. Any non-`0` value also "
      "makes the model-zoo ResNets BUILD the fused graph (BatchNormRelu/"
      "BatchNormAddRelu ops; parameter names unchanged). Read at first "
-     "compile of each op/attrs combination — flip it between processes (as "
-     "`tools/bench_capture.sh` A-B rows do), not mid-process.")
+     "compile of each op/attrs combination — flip it between processes, "
+     "not mid-process.")
 _var("MXTPU_PALLAS_DECODE", "str", "auto",
      "Paged decode-attention kernel (`ops/pallas_kernels.paged_attention` "
      "— flash-decode, q_len=1 against the block-allocated KV cache, page "
@@ -218,57 +218,6 @@ _var("MXTPU_PROFILE_SYNC", "bool", False,
      "Profiler records true device time by blocking per op, instead of "
      "(async) dispatch time. Equivalent of the reference engine's "
      "profiling stamps.")
-_var("MXTPU_STEP_TRACE_DIR", "str", "step_trace",
-     "Output directory for `tools/step_profile.py` XLA (xplane) step "
-     "traces.")
-
-# -- bench.py ---------------------------------------------------------------
-_var("MXTPU_BENCH_BATCH", "int", 32, "bench.py batch size.")
-_var("MXTPU_BENCH_WARMUP", "int", 3, "bench.py warmup iterations.")
-_var("MXTPU_BENCH_ITERS", "int", 10, "bench.py measured iterations.")
-_var("MXTPU_BENCH_MODE", "str", "train",
-     "bench.py mode: `train`, `score` (reference benchmark_score.py "
-     "analogue), `score_int8` (quantize_model int8 deployment path), "
-     "`bert` (BERT-base tokens/sec + MFU), `lstm` (word-LM), "
-     "`train_sharded` (ShardedTrainer fused-step vs op-by-op A/B, "
-     "docs/sharded_training.md), `goodput` (attribution self-check A/B), "
-     "`train_input` (sync vs prefetched input-pipeline A/B, "
-     "docs/data_pipeline.md).")
-_var("MXTPU_BENCH_SHARDED_IMPL", "str", "fused",
-     "train_sharded mode implementation under test: `fused` times BOTH "
-     "the op-by-op baseline and the promoted fused step (the A/B row); "
-     "`opbyop` times only the baseline (its own committed row).")
-_var("MXTPU_BENCH_NET", "str", "resnet50",
-     "model for train/score modes (`resnet152`, `inception_v3` for score; "
-     "`inception_v3`, `alexnet` for train — the BASELINE.md V100 rows).")
-_var("MXTPU_BENCH_LAYOUT", "str", "NCHW",
-     "`NHWC` builds the bench net channels-last (layout_scope) and feeds "
-     "NHWC batches.")
-_var("MXTPU_BENCH_DTYPE", "str", "bfloat16",
-     "bench compute precision (`float32` for the fp32 path).")
-_var("MXTPU_BENCH_SEQLEN", "int", 512,
-     "sequence length for the `bert` bench mode.")
-_var("MXTPU_BENCH_SEGMENTS", "str", "1",
-     "train-mode MFU segment decomposition (matmul ceiling / fwd / "
-     "fwd+dgrad fields). `0` disables.")
-_var("MXTPU_BENCH_SEG_MM_N", "int", 8192,
-     "matrix side for the segment matmul-ceiling measurement.")
-_var("MXTPU_BENCH_SWEEP_BATCH", "int", 256,
-     "large-batch sweep point 1 batch size (fields `sweep_*`; `0` "
-     "disables).")
-_var("MXTPU_BENCH_SWEEP_BATCH2", "int", 512,
-     "large-batch sweep point 2 batch size (fields `sweep2_*`; `0` "
-     "disables).")
-_var("MXTPU_BENCH_PROFILE", "bool", False,
-     "`1` captures an XLA (xplane) trace of a few steady-state bench steps "
-     "next to the JSON artifact (the docs/perf_notes.md MFU-gap evidence "
-     "path).")
-_var("MXTPU_BENCH_PROFILE_DIR", "str", None,
-     "Output directory for the `MXTPU_BENCH_PROFILE` trace (default "
-     "`bench_trace_<mode>`).")
-_var("MXTPU_BENCH_INPUT_STALL_MS", "int", 20,
-     "train_input mode: per-batch producer stall (ms) of the deliberately "
-     "input-bound workload the sync-vs-prefetched A/B runs against.")
 
 # -- data loading -----------------------------------------------------------
 _var("MXTPU_DATALOADER_CTX", "str", "fork",
@@ -322,14 +271,6 @@ _var("MXTPU_WALLTIME_FILE", "str", None,
      "if set, the pytest conftest appends a JSON record of suite wall time "
      "vs. the tier-1 budget to this file (always printed in the terminal "
      "summary).")
-
-# -- probe / diagnosis tools ------------------------------------------------
-_var("MXTPU_PROBE_BATCH", "int", 256,
-     "tools/mfu_probe.py, conv_probe.py, int8_probe.py, bn_bisect.py "
-     "measurement batch size.")
-_var("MXTPU_PROBE_ITERS", "int", None,
-     "probe-tool measured iterations (tool-specific defaults: mfu 10, "
-     "bn_bisect 20, int8 200, conv 400).")
 
 # -- distributed: rendezvous + launcher -------------------------------------
 _var("MXTPU_COORDINATOR", "str", None,
@@ -417,7 +358,7 @@ _var("MXTPU_SERVE_TIMEOUT_MS", "float", 2000.0,
      "may override it via its `timeout_ms` field.")
 _var("MXTPU_SERVE_PORT", "int", 8500,
      "serving: default HTTP port for `tools/serve.py` / `ServingServer` "
-     "(0 binds a free port — tests and serve_bench).")
+     "(0 binds a free port, as the tests and `chipbench/` do).")
 _var("MXTPU_SERVE_DRAIN_TIMEOUT_MS", "float", 30000.0,
      "serving: graceful-shutdown budget in ms — how long SIGTERM/`/drainz` "
      "waits for queued + in-flight requests to finish. A wedged executor "

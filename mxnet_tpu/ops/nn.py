@@ -552,8 +552,8 @@ def _bn_act(data, addend, gamma, beta, moving_mean, moving_var, eps, momentum,
     the whole epilogue runs as the two-pass fused kernel pair
     (pallas_kernels.conv_epilogue); otherwise the pure-jnp fallback keeps
     the existing custom-vjp BN with separate add/relu ops (XLA fuses the
-    elementwise tail, but offers no cross-pass guarantee — see
-    docs/perf_evidence/conv_epilogue.md)."""
+    elementwise tail, but offers no cross-pass guarantee; what the
+    kernel costs on the chip is in PERF.md §5)."""
     ax = axis % data.ndim
     eps = float(eps)
     fix_gamma = bool(fix_gamma)

@@ -383,8 +383,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
 # TPU-native replacement for the reference's fused cuDNN RNN kernel
 # (src/operator/rnn-inl.h:162, cudnn_rnn-inl.h). A lax.scan LSTM issues one
 # tiny h2h matmul per timestep; at word-LM shapes (B=32, H=650) each step
-# re-reads the 3.4 MB recurrent weight from HBM and leaves the MXU ~95%
-# idle (measured 5.3% MFU, BENCH_local_r04_lstm). Here the grid is the time
+# re-reads the 3.4 MB recurrent weight from HBM and leaves the MXU mostly
+# idle. Here the grid is the time
 # axis (sequential on TPU), w_hh stays in VMEM across all steps, and the
 # h/c carries live in f32 VMEM scratch — per-step HBM traffic drops to the
 # gx slice in + (y, c, gates) slices out.

@@ -95,8 +95,7 @@ def feature_list():
 
 
 # ---------------------------------------------------------------------------
-# chip peak FLOPs table (shared by telemetry MFU, bench.py, chip_smoke.py
-# and tools/mfu_probe*.py)
+# chip peak FLOPs table (telemetry MFU, chip_smoke.py)
 # ---------------------------------------------------------------------------
 
 # Peak dense-matmul TFLOP/s per chip in bf16, keyed by the `device_kind`

@@ -33,9 +33,8 @@ high-occupancy inference (docs/serving.md):
     docs/serving.md §Autoscaling).
 
 Launch with ``python tools/serve.py`` (``--replicas N`` for a pool,
-``--autoscale`` for the elastic loop); load-test with ``python
-tools/serve_bench.py`` (``--failover`` for the chaos row,
-``--autoscale`` for the surge row). All knobs are typed
+``--autoscale`` for the elastic loop); the generate path's rates are
+the serving cells of ``chipbench/`` (chipbench/README.md). All knobs are typed
 ``MXTPU_SERVE_*`` / ``MXTPU_AUTOSCALE_*`` variables in `mxnet_tpu.env`
 (docs/env_vars.md).
 """

@@ -314,8 +314,7 @@ class DynamicBatcher:
             "mxtpu_serve_rejected_total", {"model": name, "reason": "deadline"})
         self._m_rej_shed = telemetry.counter(
             "mxtpu_serve_rejected_total", {"model": name, "reason": "shed"})
-        # how full each dispatched bucket was (n / bucket): the occupancy
-        # evidence serve_bench reports
+        # how full each dispatched bucket was (n / bucket)
         self._m_occupancy = telemetry.histogram(
             "mxtpu_serve_batch_occupancy", labels,
             bounds=tuple(i / 10.0 for i in range(1, 11)))

@@ -327,7 +327,7 @@ class GenerateScheduler:
             "mxtpu_serve_rejected_total",
             {"model": self.name, "reason": "decode_expired"})
         # inter-token latency IS decode serving latency: its p99 is the
-        # serve_bench decode row's headline SLO figure
+        # built-in generation objective (MXTPU_SLO_INTERTOKEN_P99_MS)
         self._m_intertoken = telemetry.histogram(
             "mxtpu_serve_intertoken_seconds", labels,
             bounds=(.001, .0025, .005, .01, .025, .05, .1, .25, .5, 1., 2.5))

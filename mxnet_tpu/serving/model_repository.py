@@ -220,8 +220,7 @@ class ServedModel:
     # -- serving surface ---------------------------------------------------
     @property
     def pool(self):
-        """The model's `ReplicaPool` (None when served in-process).
-        serve_bench's failover row kills/observes replicas through it."""
+        """The model's `ReplicaPool` (None when served in-process)."""
         return self._pool
 
     @property

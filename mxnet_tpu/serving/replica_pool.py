@@ -524,7 +524,8 @@ class ReplicaPool:
 
     def replica_stats(self, replica_id, timeout=5.0):
         """One stats round trip to a replica worker (KV-page occupancy,
-        post-warm jit count — the serve_bench/test evidence hooks).
+        post-warm jit count: what tests/test_generate.py and
+        tests/test_autoscale.py read after a drain).
         Returns the worker's stats dict, or None on timeout/eject."""
         slot = self._slot_by_id(replica_id)
         if slot is None:
@@ -810,11 +811,6 @@ class ReplicaPool:
                 "generations": {s.id: s.proc.generation
                                 for s in self._slots},
             }
-
-    def replica_pid(self, replica_id):
-        """Pid of a replica's current process (serve_bench chaos hook)."""
-        slot = self._slot_by_id(replica_id)
-        return slot.proc.pid if slot is not None else None
 
     def replica_ids(self):
         """Live replica ids (sparse after resizes — ids never recycle)."""

@@ -125,7 +125,8 @@ class ServingServer:
         self._http.serve_forever(poll_interval=0.1)
 
     def start(self):
-        """Serve on a daemon thread (tests, serve_bench). Returns self."""
+        """Serve on a daemon thread (tests, chipbench/, chip_smoke.py).
+        Returns self."""
         self._serve_thread = threading.Thread(
             target=self.serve_forever, name="mxtpu-serve-http", daemon=True)
         self._serve_thread.start()

@@ -83,8 +83,7 @@ def take_step_delta():
 
 
 def last_step_flops():
-    """The most recent nonzero per-step FLOP attribution (bench.py reports
-    this next to its hand-computed number)."""
+    """The most recent nonzero per-step FLOP attribution."""
     return _STATE.last_step
 
 

@@ -132,7 +132,7 @@ def test_bf16_nn_family_fwd_bwd(case):
 
 
 # ---------------------------------------------------------------------------
-# DistributedTrainer amp_dtype=bfloat16 (the bench.py default path)
+# DistributedTrainer amp_dtype=bfloat16 (the benchmark's training path)
 # ---------------------------------------------------------------------------
 
 def _conv_net(prefix):

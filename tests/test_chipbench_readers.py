@@ -1,0 +1,12 @@
+"""The yardstick's own readers under tier-1: the FLOP table and the slice
+arithmetic (test_arithmetic), the lap readers (test_laps, test_sampler_share)
+and the trace reducer (test_trace) on which every verdict in
+PERF_LEDGER.jsonl rests. The cases live in chipbench/tests, which BENCHMARK.json
+keeps out of a PR's reach and `pytest tests/` does not collect; the star
+imports make each of them, parametrisation and all, a case of this file.
+chipbench/tests/{test_cells,test_control}.py walk cells for minutes and stay
+outside tier-1."""
+from chipbench.tests.test_arithmetic import *  # noqa: F401,F403
+from chipbench.tests.test_laps import *  # noqa: F401,F403
+from chipbench.tests.test_sampler_share import *  # noqa: F401,F403
+from chipbench.tests.test_trace import *  # noqa: F401,F403

@@ -1,8 +1,8 @@
 """Process-worker DataLoader tests (reference:
 python/mxnet/gluon/data/dataloader.py:98-120 shared-memory workers).
 
-Correctness only — scaling is benchmarked by tools/bench_dataloader.py on
-multi-core hosts (CI machines here expose a single core)."""
+Correctness only: scaling with workers is not measured (no cell of
+chipbench/ loads through worker processes)."""
 import numpy as np
 import pytest
 

@@ -175,13 +175,14 @@ def test_backward_preserves_float64_operand():
     silently shifts. Allocation-free: the magnitude lives in the VALUE, not
     the shape (ADVICE r3 medium)."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from mxnet_tpu import autograd
     from mxnet_tpu.ndarray import NDArray
+    from mxnet_tpu.ndarray.ndarray import _x64_arming
 
     hi = 2**31 + 6
-    with enable_x64(True):
+    # the framework's own arming (it knows where this jax keeps enable_x64)
+    with _x64_arming(dtypes=("float64",))[0]:
         vj = jnp.full((1,), float(hi), jnp.float64)
         ones = jnp.ones((1,), jnp.float64)
     v = NDArray(vj)

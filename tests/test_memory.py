@@ -13,8 +13,7 @@ Coverage map:
     MemoryBudgetError (507), warn: mode publishes, within-budget load
     publishes with a footprint in describe();
   * donation verifier — positive (aliasable donated buffer) and negative
-    (donation XLA cannot alias) cases through the registry fill hook;
-  * bench_history — trajectory aggregation over synthetic BENCH files.
+    (donation XLA cannot alias) cases through the registry fill hook.
 """
 import json
 import os
@@ -527,48 +526,3 @@ def test_distributed_trainer_step_verifies_donation():
     kinds = {e["kind"] for e in memory.executables_top(20)}
     assert "dist_step" in kinds
 
-
-# ---------------------------------------------------------------------------
-# bench_history
-# ---------------------------------------------------------------------------
-
-def test_bench_history_trajectory(tmp_path):
-    sys.path.insert(0, os.path.join(_ROOT, "tools"))
-    try:
-        import bench_history
-    finally:
-        sys.path.pop(0)
-    (tmp_path / "BENCH_local_r04_train.json").write_text(json.dumps({
-        "metric": "resnet50_train_bs32_imgs_per_sec", "value": 1197.8,
-        "unit": "imgs/sec", "mfu": 0.149, "vs_baseline": 4.01,
-        "baseline": {"hw": "V100"}, "device": "TPU v5 lite",
-        "utc": "2026-01-01T00:00:00Z"}))
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-        "n": 1, "rc": 1, "tail": "boom"}))
-    (tmp_path / "BENCH_local_r10_memory.json").write_text(json.dumps({
-        "mode": "serve_memory", "footprint_bytes": 13281920,
-        "over_budget_rejected": True, "within_budget_accepted": True,
-        "donation": {"aliased_fraction": 1.0}}))
-    (tmp_path / "BENCH_local_r09_broken.json").write_text("{not json")
-    # dial-failure relabel: the _stale suffix must land in the stale flag,
-    # not be swallowed into the row name
-    (tmp_path / "BENCH_local_r05_train_stale.json").write_text(json.dumps({
-        "metric": "resnet50_train_bs32_imgs_per_sec", "value": 900.0,
-        "unit": "imgs/sec", "stale": True}))
-    rc = bench_history.main(["--root", str(tmp_path), "--quiet"])
-    assert rc == 0
-    rows = json.load(open(tmp_path / "BENCH_TRAJECTORY.json"))["rows"]
-    by_file = {r["file"]: r for r in rows}
-    assert by_file["BENCH_local_r04_train.json"]["value"] == 1197.8
-    assert by_file["BENCH_r01.json"]["metric"] == "capture_failed"
-    assert by_file["BENCH_local_r10_memory.json"]["value"] == 13281920
-    assert by_file["BENCH_local_r09_broken.json"]["metric"] \
-        == "capture_failed"
-    stale_row = by_file["BENCH_local_r05_train_stale.json"]
-    assert stale_row["stale"] is True and stale_row["row"] == "train"
-    # rounds sort: r01 first, r10 last
-    assert rows[0]["file"] == "BENCH_r01.json"
-    assert rows[-1]["file"] == "BENCH_local_r10_memory.json"
-    md = (tmp_path / "docs" / "bench_trajectory.md").read_text()
-    assert "resnet50_train_bs32_imgs_per_sec" in md
-    assert "| r10 |" in md

@@ -341,7 +341,7 @@ def test_env_registry_all_directions(tmp_path):
         x = os.environ.get("MXTPU_TOOL_ONLY")         # line 2: unregistered
         y = os.environ.get("MXTPU_KNOWN", "d")        # registered: fine
         """,
-        "bench.py": "import os\nz = os.environ.get('MXTPU_KNOWN')\n",
+        "tools/serve.py": "import os\nz = os.environ.get('MXTPU_KNOWN')\n",
     })
     findings = _findings(EnvRegistryChecker(), repo)
     got = _lines(findings)
@@ -1192,7 +1192,7 @@ def test_env_module_typed_accessors(monkeypatch):
         env.get("MXTPU_NOT_REGISTERED")
     with pytest.raises(KeyError):
         env.raw("MXTPU_NOT_REGISTERED")
-    assert env.get("MXTPU_PROBE_ITERS", default=400) == 400  # per-site dflt
+    assert env.get("MXTPU_TEST_SEED", default=400) == 400  # per-site dflt
     table = env.markdown_table()
     assert table.splitlines()[0] == "| Variable | Default | Effect |"
     assert all("| `MXTPU_" in line for line in table.splitlines()[2:])

@@ -583,60 +583,6 @@ def test_slo_enabled_vs_disabled_step_overhead_under_2pct():
 
 
 # ---------------------------------------------------------------------------
-# bench_history --check regression gate
-# ---------------------------------------------------------------------------
-
-def _traj_row(rnd, metric, value, file=None, stale=False, mfu=None,
-              row="serve"):
-    return {"file": file or "BENCH_local_r%02d_%s.json" % (rnd, row),
-            "round": rnd, "row": row, "stale": stale, "metric": metric,
-            "value": value, "unit": "", "device": "cpu", "mfu": mfu,
-            "detail": "", "utc": ""}
-
-
-def test_bench_history_check_gate(tmp_path):
-    import tools.bench_history as bh
-
-    # >15% regression on the newest round vs the best prior row
-    rows = [_traj_row(6, "serve_batched_rps", 100.0),
-            _traj_row(12, "serve_batched_rps", 80.0)]
-    regs = bh.check(rows)
-    assert len(regs) == 1
-    assert regs[0]["metric"] == "serve_batched_rps"
-    assert regs[0]["regression_pct"] == pytest.approx(20.0)
-    # within tolerance passes; stale prior rows are never the baseline
-    assert bh.check([_traj_row(6, "serve_batched_rps", 100.0),
-                     _traj_row(12, "serve_batched_rps", 90.0)]) == []
-    assert bh.check([_traj_row(6, "serve_batched_rps", 1000.0, stale=True),
-                     _traj_row(12, "serve_batched_rps", 90.0)]) == []
-    # lower-is-better family: cold-start time-to-ready
-    regs = bh.check([_traj_row(8, "coldstart_resnet18_mb8", 5.0,
-                               row="coldstart"),
-                     _traj_row(12, "coldstart_resnet18_mb8", 9.0,
-                               row="coldstart")])
-    assert len(regs) == 1 and regs[0]["direction"] == "lower"
-    # coldstart gates per metric name: a NEW slower-to-load model's first
-    # row must not be compared against a different model's history
-    assert bh.check([_traj_row(8, "coldstart_resnet18_mb8", 5.0,
-                               row="coldstart"),
-                     _traj_row(12, "coldstart_bert_mb8", 20.0,
-                               row="coldstart_bert")]) == []
-    # MFU regression gates per (metric, row) family
-    regs = bh.check([_traj_row(3, "resnet50_train_bs32_imgs_per_sec",
-                               500.0, mfu=0.15, row="train"),
-                     _traj_row(12, "resnet50_train_bs32_imgs_per_sec",
-                               520.0, mfu=0.10, row="train")])
-    assert len(regs) == 1 and regs[0]["metric"].startswith("mfu:")
-    # run_check over a fabricated trajectory file: exit 2 on regression
-    (tmp_path / "BENCH_TRAJECTORY.json").write_text(json.dumps({
-        "rows": [_traj_row(6, "serve_batched_rps", 100.0),
-                 _traj_row(12, "serve_batched_rps", 50.0)]}))
-    assert bh.run_check(str(tmp_path), 0.15, quiet=True) == 2
-    # and the COMMITTED trajectory passes (the acceptance criterion)
-    assert bh.main(["--check", "--quiet"]) == 0
-
-
-# ---------------------------------------------------------------------------
 # THE acceptance e2e: slow_reply fault -> latency verdict flips ->
 # /statusz reports burn rate + exemplar trace -> recovery after clear
 # ---------------------------------------------------------------------------
