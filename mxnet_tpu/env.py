@@ -185,19 +185,6 @@ _var("MXTPU_PALLAS_LSTM", "str", "auto",
      "Fused Pallas LSTM layer (`ops/pallas_kernels.lstm_layer`): `auto` = "
      "on for TPU, `1` forces it everywhere (interpret mode on CPU — "
      "tests), `0` disables (lax.scan fallback).")
-_var("MXTPU_PALLAS_CONV_EPILOGUE", "str", "auto",
-     "Fused conv-epilogue kernels (BN batch-stats + normalize + ReLU + "
-     "residual add as one Pallas kernel pair, `ops/pallas_kernels."
-     "conv_epilogue`): `auto` = on for single-device TPU runs (pallas_call "
-     "has no SPMD partitioning rule, so sharded multi-device runs keep the "
-     "jnp psum sync-BN path), `1` forces it everywhere (interpret mode on "
-     "CPU — tests; any device count), `0` disables (pure-jnp custom-vjp BN "
-     "+ separate add/relu). Channels-last (NHWC) training path only; "
-     "channels-first always uses the jnp fallback. Any non-`0` value also "
-     "makes the model-zoo ResNets BUILD the fused graph (BatchNormRelu/"
-     "BatchNormAddRelu ops; parameter names unchanged). Read at first "
-     "compile of each op/attrs combination — flip it between processes, "
-     "not mid-process.")
 _var("MXTPU_PALLAS_DECODE", "str", "auto",
      "Paged decode-attention kernel (`ops/pallas_kernels.paged_attention` "
      "— flash-decode, q_len=1 against the block-allocated KV cache, page "

@@ -8,11 +8,12 @@ TPU notes: NCHW at the API (reference layout); build under
 Two zoo-level performance rewrites ride behind flags (both default to the
 reference graph; both are checkpoint-compatible — see each flag):
 
-- ``fuse_epilogue`` (env ``MXTPU_PALLAS_CONV_EPILOGUE``): every
-  BN→ReLU(→+residual) epilogue collapses into the fused BatchNormRelu /
-  BatchNormAddRelu ops (Pallas conv-epilogue kernels on TPU). Parameter
-  names are unchanged — the fused layers are the same ``nn.BatchNorm``
-  class, the paramless ``nn.Activation`` blocks simply disappear.
+- ``fuse_epilogue``: every BN→ReLU(→+residual) epilogue collapses into
+  the fused BatchNormRelu / BatchNormAddRelu ops (one op in the graph;
+  the same lowering as the separate ops, see ops/nn.py ``_bn_act``).
+  Parameter names are unchanged — the fused layers are the same
+  ``nn.BatchNorm`` class, the paramless ``nn.Activation`` blocks simply
+  disappear.
 - ``stem_s2d`` (env ``MXTPU_S2D_STEM``): the MXU-hostile 7×7/s2 3-channel
   stem becomes space-to-depth(2) + a 4×4/s1 conv over 12 channels —
   numerically equivalent under the weight-space transform
@@ -33,17 +34,6 @@ __all__ = ["ResNetV1", "ResNetV2", "BasicBlockV1", "BasicBlockV2",
            "resnet50_v1", "resnet101_v1", "resnet152_v1", "resnet18_v2",
            "resnet34_v2", "resnet50_v2", "resnet101_v2", "resnet152_v2",
            "get_resnet", "stem_weight_to_s2d", "convert_stem_params"]
-
-
-def _fuse_epilogue_default(flag):
-    """Zoo default for the fused-epilogue graph: explicit flag wins; else
-    opt in via MXTPU_PALLAS_CONV_EPILOGUE=1/auto (the op layer makes the
-    same env decide Pallas vs pure-jnp lowering — see ops/nn.py)."""
-    if flag is not None:
-        return bool(flag)
-    # NOT get(): the zoo gate is set-and-not-"0" (`auto` builds the fused
-    # graph too — the op layer then decides Pallas vs jnp lowering)
-    return (_env.raw("MXTPU_PALLAS_CONV_EPILOGUE") or "") not in ("", "0")
 
 
 def _stem_s2d_default(flag):
@@ -311,10 +301,9 @@ def _add_stem(features, channels0, stem_s2d, fuse_epilogue):
 
 class ResNetV1(HybridBlock):
     def __init__(self, block, layers, channels, classes=1000, thumbnail=False,
-                 fuse_epilogue=None, stem_s2d=None, **kwargs):
+                 fuse_epilogue=False, stem_s2d=None, **kwargs):
         super().__init__(**kwargs)
         assert len(layers) == len(channels) - 1
-        fuse_epilogue = _fuse_epilogue_default(fuse_epilogue)
         stem_s2d = _stem_s2d_default(stem_s2d)
         self._fuse_epilogue = fuse_epilogue
         with self.name_scope():
@@ -352,10 +341,9 @@ class ResNetV1(HybridBlock):
 
 class ResNetV2(HybridBlock):
     def __init__(self, block, layers, channels, classes=1000, thumbnail=False,
-                 fuse_epilogue=None, stem_s2d=None, **kwargs):
+                 fuse_epilogue=False, stem_s2d=None, **kwargs):
         super().__init__(**kwargs)
         assert len(layers) == len(channels) - 1
-        fuse_epilogue = _fuse_epilogue_default(fuse_epilogue)
         stem_s2d = _stem_s2d_default(stem_s2d)
         self._fuse_epilogue = fuse_epilogue
         with self.name_scope():
@@ -401,8 +389,8 @@ resnet_block_versions = [{"basic_block": BasicBlockV1, "bottle_neck": Bottleneck
 
 def get_resnet(version, num_layers, pretrained=False, ctx=None, root=None, **kwargs):
     """reference: resnet.py get_resnet. TPU extensions: fuse_epilogue=
-    and stem_s2d= (both default to their MXTPU_* env flags; see module
-    docstring)."""
+    (default off) and stem_s2d= (defaults to MXTPU_S2D_STEM); see module
+    docstring."""
     assert num_layers in resnet_spec, \
         "Invalid resnet depth %d; options: %s" % (num_layers, sorted(resnet_spec))
     block_type, layers, channels = resnet_spec[num_layers]

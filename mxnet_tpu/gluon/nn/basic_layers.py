@@ -200,9 +200,8 @@ class BatchNorm(HybridBlock):
     op (BatchNormRelu), and calling the layer with a second input —
     ``bn(x, residual)`` — folds a residual add in front of the activation
     (BatchNormAddRelu). Parameter names/shapes are identical to the plain
-    layer, so fused and unfused models share checkpoints; under
-    MXTPU_PALLAS_CONV_EPILOGUE the fused op lowers to the Pallas
-    conv-epilogue kernels (ops/pallas_kernels.conv_epilogue)."""
+    layer, so fused and unfused models share checkpoints, and the fused op
+    lowers exactly as the separate ones do (ops/nn.py `_bn_act`)."""
 
     def __init__(self, axis=None, momentum=0.9, epsilon=1e-5, center=True, scale=True,
                  use_global_stats=False, beta_initializer="zeros",

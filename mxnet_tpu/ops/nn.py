@@ -519,62 +519,28 @@ def _bn_frozen_bwd(ax, eps, fix_gamma, res, ct):
 _bn_frozen.defvjp(_bn_frozen_fwd, _bn_frozen_bwd)
 
 
-def _conv_epilogue_enabled():
-    """Fused Pallas conv-epilogue (BN stats+normalize+ReLU+add): default on
-    for SINGLE-device TPU; MXTPU_PALLAS_CONV_EPILOGUE=1 forces it
-    everywhere (interpret mode off-TPU, and regardless of device count),
-    =0 disables everywhere.
-
-    auto excludes multi-device runs: pallas_call has no SPMD partitioning
-    rule, so under pjit with a sharded batch axis it would force XLA to
-    gather each BN's full activation per layer — the jnp fallback keeps
-    the documented free-psum sync-BN behavior there."""
-    from .. import env as _env_mod
-
-    env = _env_mod.get("MXTPU_PALLAS_CONV_EPILOGUE")
-    if env == "0":
-        return False
-    if env == "1":
-        return True
-    import jax as _jax
-
-    return (_jax.default_backend() == "tpu"
-            and _jax.device_count() == 1)
-
-
 def _bn_act(data, addend, gamma, beta, moving_mean, moving_var, eps, momentum,
             fix_gamma, use_global_stats, axis, act, is_train):
     """Shared BatchNorm(+add)(+ReLU) core behind BatchNorm /
     BatchNormRelu / BatchNormAddRelu.
 
-    Training path: when the Pallas conv-epilogue is enabled and the channel
-    axis is last (the NHWC bench layout — flattening to (R, C) is free),
-    the whole epilogue runs as the two-pass fused kernel pair
-    (pallas_kernels.conv_epilogue); otherwise the pure-jnp fallback keeps
-    the existing custom-vjp BN with separate add/relu ops (XLA fuses the
-    elementwise tail, but offers no cross-pass guarantee; what the
-    kernel costs on the chip is in PERF.md §5)."""
+    One training lowering for every layout, backend and device count: the
+    custom-vjp `_bn_train`, then the add and the ReLU as plain ops, which
+    XLA fuses into their neighbours (the normalize, add and ReLU into the
+    consumer's input, the backward's reductions into the convolutions
+    beside them) and partitions under pjit. A Pallas kernel here is a
+    fusion barrier: measured on the chip with the convolution in front,
+    one loses to this path at every ResNet-50 shape (PERF.md §6, PR 38)."""
     ax = axis % data.ndim
     eps = float(eps)
     fix_gamma = bool(fix_gamma)
     relu = act == "relu"
     if is_train and not use_global_stats:
-        use_pallas = False
-        if _conv_epilogue_enabled() and ax == data.ndim - 1:
-            from . import pallas_kernels
-
-            use_pallas = pallas_kernels.conv_epilogue_fits(
-                data.shape[ax], jnp.dtype(data.dtype).itemsize)
-        if use_pallas:
-            out, mean, var = pallas_kernels.conv_epilogue(
-                data, gamma, beta, addend, eps=eps, fix_gamma=fix_gamma,
-                relu=relu)
-        else:
-            out, mean, var = _bn_train(data, gamma, beta, ax, eps, fix_gamma)
-            if addend is not None:
-                out = out + addend
-            if relu:
-                out = jax.nn.relu(out)
+        out, mean, var = _bn_train(data, gamma, beta, ax, eps, fix_gamma)
+        if addend is not None:
+            out = out + addend
+        if relu:
+            out = jax.nn.relu(out)
         new_mm = (moving_mean * momentum
                   + mean.astype(moving_mean.dtype) * (1 - momentum))
         new_mv = (moving_var * momentum
@@ -597,15 +563,12 @@ def batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3, momentum=0.
     writes outputs 1..2 back into the aux-state arrays (reference mutates aux
     in place, src/operator/nn/batch_norm.cc).
 
-    Both paths use a hand-written custom_vjp (see _bn_train/_bn_frozen; the
-    channels-last training path upgrades to the fused Pallas epilogue
-    kernels under MXTPU_PALLAS_CONV_EPILOGUE — see _bn_act): full-tensor
-    math runs in the input dtype end to end (bf16 under AMP), per-channel
-    vectors and reduction accumulators in f32. Under pjit with a sharded
-    batch axis the stats reductions psum across replicas automatically (the
-    reference's SyncBatchNorm, sync_batch_norm.cc, falls out of GSPMD) —
-    which is why the Pallas fused path is gated to single-device runs
-    (_conv_epilogue_enabled); multi-device always takes the jnp path."""
+    Both paths use a hand-written custom_vjp (see _bn_train/_bn_frozen):
+    full-tensor math runs in the input dtype end to end (bf16 under AMP),
+    per-channel vectors and reduction accumulators in f32. Under pjit with
+    a sharded batch axis the stats reductions psum across replicas
+    automatically (the reference's SyncBatchNorm, sync_batch_norm.cc, falls
+    out of GSPMD)."""
     return _bn_act(data, None, gamma, beta, moving_mean, moving_var, eps,
                    momentum, fix_gamma, use_global_stats, axis, None,
                    is_train)
@@ -617,10 +580,9 @@ def batch_norm_relu(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
                     momentum=0.9, fix_gamma=True, use_global_stats=False,
                     output_mean_var=False, axis=1, act_type="relu",
                     cudnn_off=False, is_train=False):
-    """BatchNorm + activation as ONE op (TPU fused conv-epilogue; the
-    reference's cuDNN-fused BNActivation analogue). Under
-    MXTPU_PALLAS_CONV_EPILOGUE the training path runs the two-pass Pallas
-    kernel pair instead of separate normalize and ReLU HBM passes."""
+    """BatchNorm + activation as ONE op (the reference's cuDNN-fused
+    BNActivation analogue). Lowered as `_bn_act`: XLA fuses the ReLU into
+    the normalize."""
     if act_type not in ("relu",):
         raise MXNetError("BatchNormRelu: unsupported act_type %r" % act_type)
     return _bn_act(data, None, gamma, beta, moving_mean, moving_var, eps,

@@ -24,8 +24,7 @@ import functools
 
 import numpy as _np
 
-__all__ = ["flash_attention", "lstm_layer", "conv_epilogue",
-           "conv_epilogue_fits", "paged_attention",
+__all__ = ["flash_attention", "lstm_layer", "paged_attention",
            "paged_attention_reference"]
 
 _NEG_INF = -1e30
@@ -722,404 +721,6 @@ def lstm_layer(gx, wh, h0, c0):
     ys_p, ct_p = scan_p(gx_p, wht, h0_p, c0_p)
     ys = ys_p[:, :b, :h]
     return ys, ys[-1], ct_p[:b, :h]
-
-
-# ---------------------------------------------------------------------------
-# Fused conv-epilogue: BN batch-stats + normalize + ReLU (+ residual add) as
-# TWO Pallas passes over the activation instead of the unfused graph's four+.
-#
-# The round-4 profile (docs/perf_notes.md) showed the bs256 ResNet-50 train
-# step is HBM-bound on the elementwise traffic AROUND the convolutions
-# (~67 GB/step after the BN custom-vjp): separate stats, normalize, ReLU and
-# residual-add each re-read/re-write the full activation. Here the epilogue
-# of a conv is exactly two activation-sized passes:
-#
-#   pass 1 (stats):   read x           -> per-channel Σd, Σd² (f32, on-chip)
-#   pass 2 (apply):   read x (+res)    -> write act(x·scale + shift (+res))
-#
-# and the backward is likewise two passes (channel reductions, then dx/dres).
-# The layout is channels-last (the MXU-preferred layout the NHWC bench path
-# uses): the activation flattens to (R=N·H·W, C) with NO data movement, the
-# grid walks row blocks, and the per-channel vectors ride (8, Cp) f32 blocks
-# exactly like the flash kernels' lse rows. Channels-first callers use the
-# pure-jnp fallback (ops/nn.py) — a transpose would cost the very HBM pass
-# this kernel exists to remove.
-#
-# Stats use the same proxy-shifted single-read moments as ops/nn.py
-# _bn_stats: d = x - proxy keeps E[d²]-E[d]² from cancelling for
-# large-mean/small-spread channels; all accumulation is f32.
-# ---------------------------------------------------------------------------
-
-
-def conv_epilogue_fits(c, itemsize):
-    """VMEM budget check for the fused conv-epilogue kernels. The row-block
-    size shrinks as C grows (see _epi_rows), so this only rejects channel
-    widths whose single-row tiles cannot fit; callers fall back to the
-    pure-jnp path when this returns False."""
-    cp = -(-c // 128) * 128
-    rb = _epi_rows(cp)
-    # worst kernel (backward dx with residual): ~3 input-dtype row blocks
-    # streamed (ct, x, out) + 2 written (dx, dres) + ~2 f32 temporaries in
-    # flight, plus the 8-row f32 channel vectors
-    blocks = rb * cp * (5 * itemsize + 2 * 4)
-    return (blocks + 6 * 8 * cp * 4) < 12 * 1024 * 1024
-
-
-def _epi_rows(cp):
-    """Row-block size: ~2 MB f32 per (rb, Cp) block, 32-row multiples (covers
-    the bf16 16-sublane tile), floor 32."""
-    rb = (2 * 1024 * 1024) // (cp * 4)
-    return max(32, min(512, (rb // 32) * 32))
-
-
-def _epi_stats_kernel(x_ref, proxy_ref, s1_ref, s2_ref, *, rb, r):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        s1_ref[...] = jnp.zeros_like(s1_ref)
-        s2_ref[...] = jnp.zeros_like(s2_ref)
-
-    x = x_ref[...].astype(jnp.float32)                 # (rb, Cp)
-    rows = i * rb + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    d = jnp.where(rows < r, x - proxy_ref[0:1, :], 0.0)
-    s1 = jnp.sum(d, axis=0, keepdims=True)             # (1, Cp)
-    s2 = jnp.sum(d * d, axis=0, keepdims=True)
-    s1_ref[...] = s1_ref[...] + jnp.broadcast_to(s1, s1_ref.shape)
-    s2_ref[...] = s2_ref[...] + jnp.broadcast_to(s2, s2_ref.shape)
-
-
-def _epi_apply_kernel(*refs, relu, has_res):
-    import jax.numpy as jnp
-
-    if has_res:
-        x_ref, res_ref, scale_ref, shift_ref, out_ref = refs
-    else:
-        x_ref, scale_ref, shift_ref, out_ref = refs
-        res_ref = None
-    y = (x_ref[...].astype(jnp.float32) * scale_ref[0:1, :]
-         + shift_ref[0:1, :])
-    if res_ref is not None:
-        y = y + res_ref[...].astype(jnp.float32)
-    if relu:
-        y = jnp.maximum(y, 0.0)
-    out_ref[...] = y.astype(out_ref.dtype)
-
-
-def _epi_bwd_reduce_kernel(*refs, rb, r, relu):
-    """Per-channel Σg and Σg·x̂ where g = ct·[out>0] (ReLU mask) — the two
-    reductions every BN backward needs, in ONE read of (ct, x[, out]).
-    Without relu the saved `out` is neither streamed nor read."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    if relu:
-        ct_ref, x_ref, out_ref, mean_ref, inv_ref, db_ref, dg_ref = refs
-    else:
-        ct_ref, x_ref, mean_ref, inv_ref, db_ref, dg_ref = refs
-        out_ref = None
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        db_ref[...] = jnp.zeros_like(db_ref)
-        dg_ref[...] = jnp.zeros_like(dg_ref)
-
-    g = ct_ref[...].astype(jnp.float32)
-    if relu:
-        g = jnp.where(out_ref[...].astype(jnp.float32) > 0.0, g, 0.0)
-    rows = i * rb + jax.lax.broadcasted_iota(jnp.int32, g.shape, 0)
-    g = jnp.where(rows < r, g, 0.0)
-    xhat = (x_ref[...].astype(jnp.float32) - mean_ref[0:1, :]) \
-        * inv_ref[0:1, :]
-    db = jnp.sum(g, axis=0, keepdims=True)
-    dg = jnp.sum(g * xhat, axis=0, keepdims=True)
-    db_ref[...] = db_ref[...] + jnp.broadcast_to(db, db_ref.shape)
-    dg_ref[...] = dg_ref[...] + jnp.broadcast_to(dg, dg_ref.shape)
-
-
-def _epi_bwd_dx_kernel(*refs, relu, has_res):
-    """dx = (γ·inv)·(g - Σg/R - x̂·Σ(g·x̂)/R), dres = g — one read of
-    (ct, x[, out]), one write of dx (+dres)."""
-    import jax.numpy as jnp
-
-    refs = list(refs)
-    ct_ref, x_ref = refs[0], refs[1]
-    out_ref = refs[2] if relu else None
-    k = 3 if relu else 2
-    mean_ref, inv_ref, coef_ref, cb_ref, cg_ref = refs[k:k + 5]
-    dx_ref = refs[k + 5]
-    dres_ref = refs[k + 6] if has_res else None
-    g = ct_ref[...].astype(jnp.float32)
-    if relu:
-        g = jnp.where(out_ref[...].astype(jnp.float32) > 0.0, g, 0.0)
-    xhat = (x_ref[...].astype(jnp.float32) - mean_ref[0:1, :]) \
-        * inv_ref[0:1, :]
-    dx = coef_ref[0:1, :] * (g - cb_ref[0:1, :] - xhat * cg_ref[0:1, :])
-    dx_ref[...] = dx.astype(dx_ref.dtype)
-    if dres_ref is not None:
-        dres_ref[...] = g.astype(dres_ref.dtype)
-
-
-def _vec8(v, cp):
-    """Per-channel f32 vector -> (8, Cp) block (TPU sublane-dim minimum)."""
-    import jax.numpy as jnp
-
-    v = jnp.pad(v.astype(jnp.float32), (0, cp - v.shape[0]))
-    return jnp.broadcast_to(v[None, :], (8, cp))
-
-
-def _epi_geom(r, c):
-    cp = -(-c // 128) * 128
-    rb = _epi_rows(cp)
-    n_blocks = -(-r // rb)
-    return rb, cp, n_blocks, n_blocks * rb
-
-
-def _epi_specs(r, c):
-    import jax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    rb, cp, n_blocks, rp = _epi_geom(r, c)
-    row_spec = pl.BlockSpec((rb, cp), lambda i: (i, 0),
-                            memory_space=pltpu.VMEM)
-    vec_spec = pl.BlockSpec((8, cp), lambda i: (0, 0),
-                            memory_space=pltpu.VMEM)
-    vec_shape = jax.ShapeDtypeStruct((8, cp), _np.float32)
-    return rb, cp, n_blocks, rp, row_spec, vec_spec, vec_shape
-
-
-# the four pallas_calls are cached SEPARATELY on exactly the parameters
-# each kernel depends on: the returned callables are stable objects, so
-# jax's trace cache reuses e.g. one stats executable across every
-# (relu, has_res) epilogue variant of the same shape
-
-@functools.lru_cache(maxsize=256)
-def _epi_stats_compiled(key):
-    (r, c, dtype, interpret) = key
-    from jax.experimental import pallas as pl
-
-    rb, cp, n_blocks, rp, row_spec, vec_spec, vec_shape = _epi_specs(r, c)
-    return pl.pallas_call(
-        functools.partial(_epi_stats_kernel, rb=rb, r=r),
-        name="conv_epilogue_stats",
-        grid=(n_blocks,),
-        in_specs=[row_spec, vec_spec],
-        out_shape=(vec_shape, vec_shape),
-        out_specs=(vec_spec, vec_spec),
-        interpret=interpret,
-    )
-
-
-@functools.lru_cache(maxsize=256)
-def _epi_apply_compiled(key):
-    (r, c, dtype, relu, has_res, interpret) = key
-    import jax
-    from jax.experimental import pallas as pl
-
-    rb, cp, n_blocks, rp, row_spec, vec_spec, _ = _epi_specs(r, c)
-    return pl.pallas_call(
-        functools.partial(_epi_apply_kernel, relu=relu, has_res=has_res),
-        name="conv_epilogue_apply",
-        grid=(n_blocks,),
-        in_specs=[row_spec] * (2 if has_res else 1) + [vec_spec, vec_spec],
-        out_shape=jax.ShapeDtypeStruct((rp, cp), _np.dtype(dtype)),
-        out_specs=row_spec,
-        interpret=interpret,
-    )
-
-
-@functools.lru_cache(maxsize=256)
-def _epi_reduce_compiled(key):
-    (r, c, dtype, relu, interpret) = key
-    from jax.experimental import pallas as pl
-
-    rb, cp, n_blocks, rp, row_spec, vec_spec, vec_shape = _epi_specs(r, c)
-    return pl.pallas_call(
-        functools.partial(_epi_bwd_reduce_kernel, rb=rb, r=r, relu=relu),
-        name="conv_epilogue_bwd_reduce",
-        grid=(n_blocks,),
-        in_specs=[row_spec] * (3 if relu else 2) + [vec_spec, vec_spec],
-        out_shape=(vec_shape, vec_shape),
-        out_specs=(vec_spec, vec_spec),
-        interpret=interpret,
-    )
-
-
-@functools.lru_cache(maxsize=256)
-def _epi_dx_compiled(key):
-    (r, c, dtype, relu, has_res, interpret) = key
-    import jax
-    from jax.experimental import pallas as pl
-
-    rb, cp, n_blocks, rp, row_spec, vec_spec, _ = _epi_specs(r, c)
-    dx_out = jax.ShapeDtypeStruct((rp, cp), _np.dtype(dtype))
-    return pl.pallas_call(
-        functools.partial(_epi_bwd_dx_kernel, relu=relu, has_res=has_res),
-        name="conv_epilogue_bwd_dx",
-        grid=(n_blocks,),
-        in_specs=[row_spec] * (3 if relu else 2) + [vec_spec] * 5,
-        out_shape=(dx_out, dx_out) if has_res else dx_out,
-        out_specs=(row_spec, row_spec) if has_res else row_spec,
-        interpret=interpret,
-    )
-
-
-def _epi_pad_rows(a, r, c):
-    import jax.numpy as jnp
-
-    rb, cp, n_blocks, rp = _epi_geom(r, c)
-    return jnp.pad(a, ((0, rp - r), (0, cp - c)))
-
-
-def _epi_forward(x2d, gamma, beta, res2d, eps, fix_gamma, relu, interpret):
-    import jax.numpy as jnp
-    from jax import lax
-
-    r, c = x2d.shape
-    has_res = res2d is not None
-    dtype = str(x2d.dtype)
-    rb, cp, _, _ = _epi_geom(r, c)
-    stats_call = _epi_stats_compiled((r, c, dtype, interpret))
-    apply_call = _epi_apply_compiled((r, c, dtype, relu, has_res, interpret))
-    xp = _epi_pad_rows(x2d, r, c)
-    # proxy: per-channel mean of the first row block (O(rb/R) read) — the
-    # cancellation guard _bn_stats uses, not part of the exact result
-    proxy = jnp.mean(x2d[:min(rb, r)].astype(jnp.float32), axis=0)
-    s1, s2 = stats_call(xp, _vec8(proxy, cp))
-    s1 = s1[0, :c] / r
-    s2 = s2[0, :c] / r
-    mean = proxy + s1
-    var = jnp.maximum(s2 - jnp.square(s1), 0.0)
-    inv = lax.rsqrt(var + eps)
-    g1 = jnp.ones_like(inv) if fix_gamma else gamma.astype(jnp.float32)
-    scale = g1 * inv
-    shift = beta.astype(jnp.float32) - mean * scale
-    args = (xp, _epi_pad_rows(res2d, r, c)) if has_res else (xp,)
-    out = apply_call(*args, _vec8(scale, cp), _vec8(shift, cp))[:r, :c]
-    return out, mean, var, inv
-
-def _epi_bwd_impl(eps, fix_gamma, relu, interpret, saved, cts, has_res):
-    """Shared Pallas backward for both custom_vjp arities below."""
-    import jax.numpy as jnp
-
-    x2d, gamma, beta, mean, inv, out = saved
-    r, c = x2d.shape
-    ct = cts[0]                       # mean/var cotangents ignored
-    dtype = str(x2d.dtype)
-    _, cp, _, _ = _epi_geom(r, c)
-    reduce_call = _epi_reduce_compiled((r, c, dtype, relu, interpret))
-    dx_call = _epi_dx_compiled((r, c, dtype, relu, has_res, interpret))
-    ctp = _epi_pad_rows(ct.astype(x2d.dtype), r, c)
-    xp = _epi_pad_rows(x2d, r, c)
-    rows = (ctp, xp, _epi_pad_rows(out, r, c)) if relu else (ctp, xp)
-    meanv, invv = _vec8(mean, cp), _vec8(inv, cp)
-    db, dg = reduce_call(*rows, meanv, invv)
-    db = db[0, :c]
-    dg = dg[0, :c]
-    g1 = jnp.ones_like(inv) if fix_gamma else gamma.astype(jnp.float32)
-    outs = dx_call(*rows, meanv, invv,
-                   _vec8(g1 * inv, cp), _vec8(db / r, cp),
-                   _vec8(dg / r, cp))
-    if has_res:
-        dx, dres = outs
-        dres = dres[:r, :c]
-    else:
-        dx, dres = outs, None
-    dx = dx[:r, :c]
-    dgamma = (jnp.zeros_like(gamma) if fix_gamma
-              else dg.astype(gamma.dtype))
-    dbeta = db.astype(beta.dtype)
-    return dx, dgamma, dbeta, dres
-
-
-def _epi_save(x2d, gamma, beta, mean, inv, out, relu):
-    # `out` is needed only for the ReLU mask; without relu the backward
-    # neither saves nor streams it (it would be two wasted activation
-    # reads per BN backward on the plain-BatchNorm path)
-    return (x2d, gamma, beta, mean, inv, out if relu else None)
-
-
-# module-level custom_vjp pair (one per arity), static config via
-# nondiff_argnums — built lazily so importing this module never imports jax
-
-
-@functools.lru_cache(maxsize=1)
-def _epi_vjp_fns():
-    import jax
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-    def epi3(x2d, gamma, beta, eps, fix_gamma, relu, interpret):
-        out, mean, var, _ = _epi_forward(
-            x2d, gamma, beta, None, eps, fix_gamma, relu, interpret)
-        return out, mean, var
-
-    def epi3_fwd(x2d, gamma, beta, eps, fix_gamma, relu, interpret):
-        out, mean, var, inv = _epi_forward(
-            x2d, gamma, beta, None, eps, fix_gamma, relu, interpret)
-        return (out, mean, var), _epi_save(x2d, gamma, beta, mean, inv,
-                                           out, relu)
-
-    def epi3_bwd(eps, fix_gamma, relu, interpret, saved, cts):
-        dx, dgamma, dbeta, _ = _epi_bwd_impl(eps, fix_gamma, relu,
-                                             interpret, saved, cts, False)
-        return dx, dgamma, dbeta
-
-    epi3.defvjp(epi3_fwd, epi3_bwd)
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-    def epi4(x2d, gamma, beta, res2d, eps, fix_gamma, relu, interpret):
-        out, mean, var, _ = _epi_forward(
-            x2d, gamma, beta, res2d, eps, fix_gamma, relu, interpret)
-        return out, mean, var
-
-    def epi4_fwd(x2d, gamma, beta, res2d, eps, fix_gamma, relu, interpret):
-        out, mean, var, inv = _epi_forward(
-            x2d, gamma, beta, res2d, eps, fix_gamma, relu, interpret)
-        return (out, mean, var), _epi_save(x2d, gamma, beta, mean, inv,
-                                           out, relu)
-
-    def epi4_bwd(eps, fix_gamma, relu, interpret, saved, cts):
-        return _epi_bwd_impl(eps, fix_gamma, relu, interpret, saved, cts,
-                             True)
-
-    epi4.defvjp(epi4_fwd, epi4_bwd)
-    return epi3, epi4
-
-
-def conv_epilogue(x, gamma, beta, residual=None, eps=1e-3, fix_gamma=False,
-                  relu=True):
-    """Fused BN(train-stats) + normalize + ReLU (+ residual add) over a
-    channels-last activation x (..., C).
-
-    Returns (out, batch_mean, batch_var); mean/var are f32 (C,) for the
-    moving-stat update. Differentiable (custom_vjp, Pallas backward) w.r.t.
-    x, gamma, beta and residual; the mean/var outputs' cotangents are
-    ignored (same documented divergence as ops/nn.py _bn_train — they feed
-    the never-differentiated moving-stat buffers). The custom_vjp pair is
-    module-level (static config via nondiff args), so repeated calls trace
-    the same function objects and jax's caches apply."""
-    shape = x.shape
-    c = shape[-1]
-    x2d = x.reshape((-1, c))
-    eps = float(eps)
-    relu = bool(relu)
-    fix_gamma = bool(fix_gamma)
-    interpret = _use_interpret()
-    epi3, epi4 = _epi_vjp_fns()
-    if residual is None:
-        out, mean, var = epi3(x2d, gamma, beta, eps, fix_gamma, relu,
-                              interpret)
-    else:
-        out, mean, var = epi4(x2d, gamma, beta, residual.reshape((-1, c)),
-                              eps, fix_gamma, relu, interpret)
-    return out.reshape(shape), mean, var
 
 
 # ---------------------------------------------------------------------------

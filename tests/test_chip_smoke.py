@@ -39,7 +39,7 @@ def test_smoke_without_chip_stops_at_device_phase():
 # what a CPU can already show is everything but "it ran on a TPU"
 TPU_ONLY = {"platform_is_tpu", "peak_known", "buffers_on_tpu", "batch_on_tpu",
             "not_interpret", "weights_and_kv_on_tpu",
-            "conv_epilogue_kernel_in_step", "flash_fwd_bwd_in_step",
+            "flash_fwd_bwd_in_step",
             "pallas_lstm_in_step", "paged_kernel_in_decode_step"}
 ALL_PHASES = ["device", "train_resnet50", "train_bert_base", "train_lstm_lm",
               "serve_decode", "compile_cache"]

@@ -52,13 +52,6 @@ def _largest_lstm_h(b, itemsize):
     return h
 
 
-def _largest_epilogue_c(itemsize):
-    c = 128
-    while pk.conv_epilogue_fits(c + 128, itemsize):
-        c += 128
-    return c
-
-
 def _flash(shape, dtype, causal):
     def loss(q, k, v):
         return jnp.sum(pk.flash_attention(q, k, v, causal=causal)
@@ -76,19 +69,6 @@ def _lstm(t, b, h, dtype):
     return (jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
             [((t, b, 4 * h), dtype), ((4 * h, h), dtype),
              ((b, h), dtype), ((b, h), dtype)])
-
-
-def _epilogue(shape, dtype):
-    c = shape[-1]
-
-    def loss(x, gamma, beta, res):
-        out, _, _ = pk.conv_epilogue(x, gamma, beta, residual=res,
-                                     relu=True)
-        return jnp.sum(out.astype(jnp.float32))
-
-    return (jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
-            [(shape, dtype), ((c,), jnp.float32), ((c,), jnp.float32),
-             (shape, dtype)])
 
 
 def _paged(b, h, d, n_pages, maxp, ps, dtype):
@@ -121,15 +101,6 @@ CASES = {
         lambda: _lstm(4, 32, _largest_lstm_h(32, 4), f32),
     "lstm-gate-edge-f32-b256":
         lambda: _lstm(4, 256, _largest_lstm_h(256, 4), f32),
-    # ResNet-50 NHWC stages at batch 32
-    "epilogue-56x56x256-bf16": lambda: _epilogue((32, 56, 56, 256), bf16),
-    "epilogue-112x112x64-bf16": lambda: _epilogue((32, 112, 112, 64), bf16),
-    "epilogue-7x7x2048-bf16": lambda: _epilogue((32, 7, 7, 2048), bf16),
-    "epilogue-56x56x64-f32": lambda: _epilogue((32, 56, 56, 64), f32),
-    "epilogue-gate-edge-bf16":
-        lambda: _epilogue((64, _largest_epilogue_c(2)), bf16),
-    "epilogue-gate-edge-f32":
-        lambda: _epilogue((64, _largest_epilogue_c(4)), f32),
     # serve_decode geometry (GPT-2-small heads, f32 KV as the engine
     # defaults): a page is (16, 768), whole (8, 128) tiles
     "paged-gpt2small-f32": lambda: _paged(8, 12, 64, 512, 32, 16, f32),
@@ -147,8 +118,6 @@ KERNEL_NAMES = {
     "flash": ("flash_attention_fwd", "flash_attention_bwd_dq",
               "flash_attention_bwd_dkv"),
     "lstm": ("lstm_layer_fwd", "lstm_layer_bwd"),
-    "epilogue": ("conv_epilogue_stats", "conv_epilogue_apply",
-                 "conv_epilogue_bwd_reduce", "conv_epilogue_bwd_dx"),
     "paged": ("paged_attention_decode",),
 }
 
@@ -164,9 +133,121 @@ def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
     # every kernel carries a fixed name into the program (and so into the
     # device trace), whatever Python function its body happens to be
     # (an instruction reads `%paged_attention_decode.3`, or, under
-    # autodiff, `%transpose_jvp_conv_epilogue_bwd_dx__.1`)
+    # autodiff, `%transpose_jvp_lstm_layer_bwd__.1`)
     for name in KERNEL_NAMES[case.split("-")[0]]:
         assert re.search(r"%%\w*%s_*\.\d+ = " % name, text), name
+
+
+# ---------------------------------------------------------------------------
+# a training batch norm has one lowering, XLA's own (ops/nn.py `_bn_act`):
+# no Mosaic kernel and no lane padding of the activation around one
+# ---------------------------------------------------------------------------
+
+def _conv_bn_grad(form):
+    """value_and_grad of loss(x, w, gamma, beta, res) of a 1x1 conv ->
+    training BN(+add)(+ReLU), channels last: the convolution in front, as
+    in a net, so that XLA has neighbours to fuse the batch norm into. A
+    new function a call: jax caches traces by the function."""
+    from mxnet_tpu.ops import nn as N
+
+    def loss(x, w, gamma, beta, res):
+        y = jax.lax.conv_general_dilated(
+            x, w, (1, 1), "VALID",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        c = y.shape[-1]
+        out, mm, mv = N._bn_act(
+            y, res if form == "bn_add_relu" else None, gamma, beta,
+            jnp.zeros((c,), f32), jnp.ones((c,), f32), 1e-5, 0.9, False,
+            False, -1, None if form == "bn" else "relu", True)
+        return jnp.sum(out.astype(f32)) + jnp.sum(mm) + jnp.sum(mv)
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+
+
+def _conv_bn_args(shape, cin, dtype, sharding=None):
+    c = shape[-1]
+    return [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in (
+        (shape[:-1] + (cin,), dtype), ((1, 1, cin, c), dtype),
+        ((c,), f32), ((c,), f32), (shape, dtype))]
+
+
+# the six activations the conv-epilogue kernel's compile cases used
+# (ResNet-50 NHWC stages at batch 32, and the widest channel counts the
+# kernel's VMEM rule admitted), with the width of the 1x1 conv in front
+BN_CASES = {
+    "56x56x256-bf16-bn_add_relu": ((32, 56, 56, 256), 64, bf16),
+    "112x112x64-bf16-bn_relu": ((32, 112, 112, 64), 16, bf16),
+    "7x7x2048-bf16-bn_add_relu": ((32, 7, 7, 2048), 512, bf16),
+    "56x56x64-f32-bn_relu": ((32, 56, 56, 64), 256, f32),
+    "1x1x16256-bf16-bn": ((64, 1, 1, 16256), 128, bf16),
+    "1x1x768-f32-bn_add_relu": ((64, 1, 1, 768), 128, f32),
+}
+
+
+def _channel_widening_pads(text, c):
+    """Instructions of a compiled program that pad a `c`-channel array
+    to more channels (HLO shapes and `padding=` are in logical order:
+    the channel is the last dimension)."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r" = \w+\[([0-9,]*)\][^ ]* pad\(.*padding=([0-9_x-]+)",
+                      line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        last = [int(v) for v in m.group(2).split("x")[-1].split("_")[:2]]
+        if dims and dims[-1] > c and dims[-1] - sum(last) == c:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("case", sorted(BN_CASES))
+def test_training_batch_norm_compiles_to_xla_alone(case, v5e):
+    shape, cin, dtype = BN_CASES[case]
+    form = case.rsplit("-", 1)[1]
+    args = _conv_bn_args(shape, cin, dtype, v5e)
+    text = jax.jit(_conv_bn_grad(form)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert "convolution" in text
+    assert not _channel_widening_pads(text, shape[-1])
+
+
+def _primitives(jaxpr, out=None):
+    """Multiset of primitive names of a jaxpr, sub-jaxprs included."""
+    out = {} if out is None else out
+    for eqn in jaxpr.eqns:
+        out[eqn.primitive.name] = out.get(eqn.primitive.name, 0) + 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("form", ["bn", "bn_relu", "bn_add_relu"])
+def test_batch_norm_lowering_reads_no_device_count_or_backend(
+        form, v5e, monkeypatch):
+    """One configuration, one program: the training batch norm traced
+    where jax reports one TPU device holds the primitives of the one
+    traced on the tests' 8-device CPU host, and lowered for the
+    described one-device v5e it holds no custom call."""
+    shape, cin, dtype = (8, 14, 14, 256), 64, bf16
+
+    def traced():
+        return _primitives(jax.make_jaxpr(_conv_bn_grad(form))(
+            *_conv_bn_args(shape, cin, dtype)).jaxpr)
+
+    here = traced()
+    assert jax.device_count() > 1 and jax.default_backend() == "cpu"
+    monkeypatch.setattr(jax, "device_count", lambda *a: 1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    assert traced() == here
+    assert "pallas_call" not in here
+    lowered = jax.jit(_conv_bn_grad(form)).lower(
+        *_conv_bn_args(shape, cin, dtype, v5e))
+    assert "custom_call" not in lowered.as_text()
 
 
 # ---------------------------------------------------------------------------
