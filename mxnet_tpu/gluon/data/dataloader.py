@@ -144,9 +144,18 @@ _WORKER_BATCHIFY = None
 def _worker_initializer(dataset_bytes, batchify_bytes):
     """Runs once in each worker process."""
     import os
+    import signal
 
     from ... import base as _base
 
+    # a forked worker inherits the trainer's SIGTERM-with-grace handler
+    # (parallel/resilience.py), which only records the request for the next
+    # step boundary; a data worker has no step, so the pool's terminate()
+    # would never end it and its join would hang. SIGTERM kills a worker,
+    # also one sent before this line: a worker is born with the signal
+    # blocked (`_make_pool`), so it waits here and is delivered now.
+    signal.signal(signal.SIGTERM, signal.SIG_DFL)
+    signal.pthread_sigmask(signal.SIG_UNBLOCK, {signal.SIGTERM})
     os.environ["JAX_PLATFORMS"] = "cpu"  # data workers never own a TPU
     _base.HOST_ARRAY_MODE = True        # decode/dataset stages stay numpy
     global _WORKER_DATASET, _WORKER_BATCHIFY
@@ -340,13 +349,21 @@ class DataLoader:
 
     def _make_pool(self):
         import multiprocessing as mp
+        import signal
 
         ctx = mp.get_context(self._mp_ctx)
-        return ctx.Pool(
-            self._num_workers, initializer=_worker_initializer,
-            initargs=(pickle.dumps(self._dataset),
-                      pickle.dumps(self._custom_batchify)
-                      if self._custom_batchify else b""))
+        # SIGTERM stays blocked in every worker until its initializer has
+        # put the default action back (the pool's own threads, which fork
+        # replacements, are made here and keep the mask)
+        mask = signal.pthread_sigmask(signal.SIG_BLOCK, {signal.SIGTERM})
+        try:
+            return ctx.Pool(
+                self._num_workers, initializer=_worker_initializer,
+                initargs=(pickle.dumps(self._dataset),
+                          pickle.dumps(self._custom_batchify)
+                          if self._custom_batchify else b""))
+        finally:
+            signal.pthread_sigmask(signal.SIG_SETMASK, mask)
 
     def _get_pool(self):
         if self._pool is None:

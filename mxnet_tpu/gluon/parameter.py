@@ -197,6 +197,25 @@ class Parameter:
         for c in self._data:
             self._data[c]._set_data(data.as_in_context(c)._data)
 
+    def adopt(self, array, ctx=None):
+        """Take a device array as this parameter's data as it is: no
+        initializer, no host copy, no gradient buffer (``grad_req`` becomes
+        ``null``). For models loaded to serve, whose parameters would not fit
+        the device twice (serving.generate.load_lm)."""
+        ctx = ctx if ctx is not None else current_context()
+        if self._shape is not None and len(self._shape) == len(array.shape) \
+                and any(s and s != a for s, a in zip(self._shape,
+                                                     array.shape)):
+            raise MXNetError("parameter %s has shape %s, the array %s"
+                             % (self.name, self._shape, tuple(array.shape)))
+        self._shape = tuple(int(d) for d in array.shape)
+        self.dtype = str(array.dtype)
+        self._grad_req = "null"
+        self._grad = None
+        self._ctx_list = [ctx]
+        self._data = OrderedDict([(ctx, nd.NDArray(array, ctx=ctx))])
+        self._deferred_init = ()
+
     def row_sparse_data(self, row_id):
         raise MXNetError("row_sparse parameters: use stype='row_sparse' (sparse "
                          "module) — dense fallback active in this build")

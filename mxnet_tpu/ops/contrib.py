@@ -755,3 +755,87 @@ def topk_moe(data, gate_weight, expert_w_in, expert_w_out, k=2,
     z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
     return (out.reshape(lead + (d,)), lb.astype(jnp.float32),
             z.astype(jnp.float32))
+
+
+def moe_tile_rows(pairs, experts_held):
+    """Rows of a tile of the grouped expert product: about twice the mean
+    pairs an expert, a power of two between 16 (a bfloat16 sublane tile)
+    and 128."""
+    tm = 16
+    while tm < 128 and tm * experts_held < 2 * pairs:
+        tm *= 2
+    return tm
+
+
+@register("_contrib_sigmoid_topk_moe", num_outputs=2, num_visible_outputs=2,
+          aliases=("sigmoid_topk_moe",))
+def sigmoid_topk_moe(data, gate_weight, expert_bias, w1, w3, w2, k=4,
+                     expert_offset=0, valid=None, routed_scaling_factor=1.0,
+                     norm_topk_prob=True):
+    """Drop-free top-k expert layer with a sigmoid router.
+
+    data (..., C); gate_weight (E, C) and expert_bias (E,) over ALL experts;
+    w1, w3, w2 (E_held, F, C): the experts ``expert_offset ..
+    expert_offset + E_held`` that this holder computes. Scores s =
+    sigmoid(x Wg) in float32; the k experts of a token are the top k of
+    s + expert_bias (the bias selects, it does not weigh); gates g = s / (sum
+    of the selected s + 1e-6) when ``norm_topk_prob``, times
+    ``routed_scaling_factor``. Every (token, expert) pair whose expert is
+    held is computed: rows are laid out expert by expert in tiles of
+    `moe_tile_rows` and go through one grouped product
+    (`pallas_kernels.moe_grouped_ffn`); there is no capacity and nobody is
+    dropped. Pairs of experts held elsewhere add nothing here: the parts
+    that all holders give add up to the whole layer. Rows with ``valid``
+    false (a bucket's padding) route nowhere and cost no expert.
+
+    Returns ``(output (..., C), stats (3,) int32)``: pairs computed here,
+    experts hit, and the busiest expert's pairs."""
+    from .pallas_kernels import moe_grouped_ffn
+
+    lead, c = data.shape[:-1], data.shape[-1]
+    x = data.reshape(-1, c)
+    n = x.shape[0]
+    held = w1.shape[0]
+    pairs = n * k
+    tm = moe_tile_rows(pairs, held)
+    tiles = pairs // tm + held          # covers any routing
+    with jax.named_scope("mxtpu.lm.moe.route"):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "nc,ec->ne", x, gate_weight, preferred_element_type=jnp.float32))
+        _, experts = lax.top_k(scores + expert_bias.astype(jnp.float32), k)
+        gates = jnp.take_along_axis(scores, experts, axis=1)      # (n, k)
+        if norm_topk_prob:
+            gates = gates / (jnp.sum(gates, axis=1, keepdims=True) + 1e-6)
+        gates = gates * routed_scaling_factor
+
+        local = experts - expert_offset
+        here = (local >= 0) & (local < held)
+        if valid is not None:
+            here = here & valid.reshape(-1)[:, None]
+        pair_expert = jnp.where(here, local, held).reshape(-1)    # (n*k,)
+        # a pair's place: its expert's first tile, then its rank in the
+        # expert
+        onehot = (pair_expert[:, None] == jnp.arange(held)[None, :]) \
+            .astype(jnp.int32)                                    # (P, E)
+        rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
+        counts = jnp.sum(onehot, axis=0)                          # (E,)
+        tile_end = jnp.cumsum(-(-counts // tm))                   # (E,)
+        tile_start = tile_end - (-(-counts // tm))
+        n_tiles = tile_end[-1]
+        start = jnp.sum(onehot * tile_start[None, :], axis=1)
+        dest = jnp.where(pair_expert < held, start * tm + rank, tiles * tm)
+        t_idx = jnp.minimum(jnp.arange(tiles), jnp.maximum(n_tiles - 1, 0))
+        tile_expert = jnp.sum(tile_end[None, :] <= t_idx[:, None], axis=1)
+        tile_expert = jnp.minimum(tile_expert, held - 1).astype(jnp.int32)
+        row_token = jnp.full((tiles * tm,), n, jnp.int32).at[dest].set(
+            jnp.arange(pairs, dtype=jnp.int32) // k, mode="drop")
+    with jax.named_scope("mxtpu.lm.moe.experts"):
+        xs = jnp.concatenate([x, jnp.zeros((1, c), x.dtype)])[row_token]
+        ys = moe_grouped_ffn(xs, tile_expert, n_tiles.reshape(1), w1, w3,
+                             w2, tm)
+        picked = ys.at[dest].get(mode="fill", fill_value=0.0) \
+            .reshape(n, k, c)
+        out = jnp.sum(picked * gates[:, :, None], axis=1).astype(data.dtype)
+    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
+                       jnp.max(counts)]).astype(jnp.int32)
+    return out.reshape(lead + (c,)), stats

@@ -1014,3 +1014,111 @@ def correlation(data1, data2, kernel_size=1, max_displacement=1, stride1=1,
 @register("IdentityAttachKLSparseReg")
 def identity_attach_kl_sparse_reg(data, sparseness_target=0.1, penalty=0.001, momentum=0.9):
     return data
+
+
+# ---------------------------------------------------------------------------
+# Decoder-LM blocks beyond the 2019 transformer: RMS norm, rotary positions,
+# a gated feed-forward, and the gated short convolution of the LFM2 family.
+# Pure functions of arrays: the zoo blocks (gluon/model_zoo/lfm2.py) and the
+# generation engine (serving/generate.py) call the same ones. Statistics are
+# float32 whatever the data's dtype; matrix products accumulate in float32.
+# ---------------------------------------------------------------------------
+
+@register("RMSNorm", aliases=("rms_norm",))
+def rms_norm(data, gamma, axis=-1, eps=1e-5):
+    """data * rsqrt(mean(data^2) + eps) * gamma over ``axis``, in float32,
+    returned in data's dtype."""
+    x = data.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x), axis=axis, keepdims=True)
+    return (x * lax.rsqrt(var + eps)
+            * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+@register("_contrib_rope", aliases=("rope",))
+def rope(data, positions, theta=10000.0):
+    """Rotary position embedding over the whole head, rotate-half form:
+    data (..., H, D); positions broadcastable to data.shape[:-2]. Lane i of
+    the first half pairs with lane i + D/2; the angle of pair i at position p
+    is p * theta^(-2i/D). Computed in float32."""
+    d = data.shape[-1]
+    half = d // 2
+    inv = jnp.asarray(float(theta), jnp.float32) ** (
+        -jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
+    ang = jnp.asarray(positions, jnp.float32)[..., None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x = data.astype(jnp.float32)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(data.dtype)
+
+
+def matmul_nt(x, w, dtype=None):
+    """x (..., in) times w (out, in) transposed, accumulated in float32 and
+    returned in ``dtype`` (x's when None): the one projection of the LM
+    blocks."""
+    return lax.dot_general(
+        x, w, (((x.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(dtype or x.dtype)
+
+
+@register("_contrib_swiglu_ffn", aliases=("swiglu_ffn",))
+def swiglu_ffn(data, w1, w3, w2):
+    """w2 (silu(w1 x) * w3 x): w1, w3 (F, C); w2 (C, F)."""
+    a = matmul_nt(data, w1, jnp.float32)
+    b = matmul_nt(data, w3, jnp.float32)
+    return matmul_nt((jax.nn.silu(a) * b).astype(data.dtype), w2)
+
+
+@register("_contrib_short_conv", num_outputs=2, num_visible_outputs=2,
+          aliases=("short_conv",))
+def short_conv(u, weight, state=None, length=None):
+    """Depthwise causal convolution of a few taps along time, with the
+    state a decode step needs.
+
+    u (..., T, C); weight (C, K); ``state`` (..., K-1, C) holds u of the K-1
+    positions before the first (zeros when None). Returns ``(c, new_state)``:
+    c_t = sum_j weight[:, j] * u_{t-(K-1)+j} and new_state = u of the last
+    K-1 positions, counted from ``length`` (a traced scalar: the live prefix
+    of a padded T) when given, else from T."""
+    k = weight.shape[1]
+    t = u.shape[-2]
+    if state is None:
+        state = jnp.zeros(u.shape[:-2] + (k - 1, u.shape[-1]), u.dtype)
+    padded = jnp.concatenate([state.astype(u.dtype), u], axis=-2)
+    w = weight.astype(jnp.float32)
+    c = sum(w[:, j] * lax.slice_in_dim(padded, j, j + t, axis=-2)
+            .astype(jnp.float32) for j in range(k))
+    if length is None:
+        new_state = lax.slice_in_dim(padded, t, t + k - 1, axis=-2)
+    else:
+        new_state = lax.dynamic_slice_in_dim(padded, length, k - 1, axis=-2)
+    return c.astype(u.dtype), new_state
+
+
+@register("_contrib_gated_short_conv", num_outputs=2, num_visible_outputs=2,
+          aliases=("gated_short_conv",))
+def gated_short_conv(r, w_in, w_conv, w_out, state=None, length=None):
+    """The LFM2 operator: B, C, X = split3(r W_in); c = conv(B * X);
+    o = (C * c) W_out. r (..., T, C); returns (o, new conv state)."""
+    b, c_gate, x = jnp.split(matmul_nt(r, w_in), 3, axis=-1)
+    c, new_state = short_conv(b * x, w_conv, state, length)
+    return matmul_nt(c_gate * c, w_out), new_state
+
+
+@register("_contrib_causal_attention", aliases=("causal_attention",))
+def causal_attention(q, k, v, sm_scale=None):
+    """Causal softmax attention of a whole sequence, grouped-query: q (...,
+    L, H, D); k, v (..., L, KV, D) with H a multiple of KV; query head i
+    reads KV head i // (H // KV). Scores and softmax in float32."""
+    l, h, d = q.shape[-3:]
+    kv = k.shape[-2]
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    qg = q.reshape(q.shape[:-2] + (kv, h // kv, d))
+    s = jnp.einsum("...qkgd,...lkd->...kgql", qg, k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    causal = jnp.arange(l)[None, :] <= jnp.arange(l)[:, None]
+    p = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
+    o = jnp.einsum("...kgql,...lkd->...qkgd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(q.shape).astype(q.dtype)

@@ -25,7 +25,8 @@ import functools
 import numpy as _np
 
 __all__ = ["flash_attention", "lstm_layer", "paged_attention",
-           "paged_attention_reference"]
+           "paged_attention_reference", "moe_grouped_ffn",
+           "moe_grouped_ffn_reference"]
 
 _NEG_INF = -1e30
 
@@ -750,6 +751,16 @@ def lstm_layer(gx, wh, h0, c0):
 # head-indicator matrix, compiles too, but a 16-row page leaves it bound by
 # loading that matrix once a page, at three to six bf16 passes for float32.)
 #
+# Grouped-query (fewer KV heads than query heads): the `group` query heads
+# that share a KV head arrive as `group` rows of Cp lanes and each keeps a
+# row of the softmax state; a page's tile is read from VMEM once for all of
+# them. Their q*k products, every lane tile's and every group row's stacked
+# by rows, are summed inside the heads' lane segments by one MXU product a
+# page with the segments' 0/1 matrix (float32 as two bfloat16 terms) in
+# place of a butterfly a query head: with group*tiles*page_size = 256 rows a
+# product the matrix's load is paid once a page, which the one-row-a-head
+# form above could not amortise.
+#
 # Known bound: the grid is static (B, max_pages), one page a step, so a
 # short sequence still DMAs its table's padding pages (their arithmetic is
 # skipped) — the streamed bytes scale with max_pages, not actual length.
@@ -762,17 +773,19 @@ def lstm_layer(gx, wh, h0, c0):
 
 
 def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
-                              sm_scale):
+                              sm_scale, kv_heads=None):
     """Dense-gather oracle (and fallback): q (B, H, D); k_pages / v_pages
-    (P, page_size, Cp) token-major with Cp >= H*D (lanes past H*D are the
-    allocation's padding and are ignored); page_tables (B, max_pages)
-    int32; lengths (B,) int32 — tokens [0, lengths[b]) of sequence b are
-    live, laid out page_tables[b, t // page_size] slot t % page_size. A
-    row with length 0 returns zeros-ish garbage that callers mask out (its
-    scores are uniformly _NEG_INF, which is finite by design — no NaNs).
-    Both contractions run at HIGHEST precision: an oracle whose f32
-    scores the MXU rounded to bf16 could not tell a right kernel from a
-    wrong one on the chip."""
+    (P, page_size, Cp) token-major with Cp >= KV*D (lanes past KV*D are the
+    allocation's padding and are ignored), KV = ``kv_heads`` heads of keys
+    and values (None: H, one K and V a query head); H a multiple of KV,
+    query head i reading KV head i // (H // KV) (grouped-query); page_tables (B,
+    max_pages) int32; lengths (B,) int32 — tokens [0, lengths[b]) of
+    sequence b are live, laid out page_tables[b, t // page_size] slot
+    t % page_size. A row with length 0 returns zeros-ish garbage that
+    callers mask out (its scores are uniformly _NEG_INF, which is finite by
+    design — no NaNs). Both contractions run at HIGHEST precision: an oracle
+    whose f32 scores the MXU rounded to bf16 could not tell a right kernel
+    from a wrong one on the chip."""
     import jax
     import jax.numpy as jnp
 
@@ -780,26 +793,31 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
     b, h, d = q.shape
     ps = k_pages.shape[1]
     maxp = page_tables.shape[1]
-    # (B, maxp, ps, Cp) -> (B, L, H, D)
-    k = k_pages[page_tables][..., :h * d].reshape(b, maxp * ps, h, d)
-    v = v_pages[page_tables][..., :h * d].reshape(b, maxp * ps, h, d)
-    s = jnp.einsum("bhd,blhd->bhl", q.astype(jnp.float32),
-                   k.astype(jnp.float32), precision=hi) * sm_scale
-    ids = jnp.arange(maxp * ps)[None, None, :]
-    s = jnp.where(ids < lengths[:, None, None], s, _NEG_INF)
+    kv = h if kv_heads is None else int(kv_heads)
+    g = h // kv
+    # (B, maxp, ps, Cp) -> (B, L, KV, D)
+    k = k_pages[page_tables][..., :kv * d].reshape(b, maxp * ps, kv, d)
+    v = v_pages[page_tables][..., :kv * d].reshape(b, maxp * ps, kv, d)
+    qg = q.astype(jnp.float32).reshape(b, kv, g, d)
+    s = jnp.einsum("bkgd,blkd->bkgl", qg, k.astype(jnp.float32),
+                   precision=hi) * sm_scale
+    ids = jnp.arange(maxp * ps)[None, None, None, :]
+    s = jnp.where(ids < lengths[:, None, None, None], s, _NEG_INF)
     p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    o = jnp.einsum("bhl,blhd->bhd", p, v.astype(jnp.float32), precision=hi)
-    return o.astype(q.dtype)
+    o = jnp.einsum("bkgl,blkd->bkgd", p, v.astype(jnp.float32), precision=hi)
+    return o.reshape(b, h, d).astype(q.dtype)
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, sm_scale, ps, d, n_pages):
+def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest, sm_scale, ps,
+                  d, n_pages, group, mxu=False):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    seg_ref = rest[0] if mxu else None
+    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
     b = pl.program_id(0)
     j = pl.program_id(1)
     cp = k_ref.shape[-1]
@@ -819,51 +837,85 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         lane = jax.lax.broadcasted_iota(jnp.int32, (ps, w), 1)
         row = j * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, w), 0)
         live = row < len_ref[b]
+        if mxu:
+            # every (lane tile, query head of the group)'s q*k stacked by
+            # rows, summed inside each head's D lanes by ONE product with
+            # the 0/1 matrix of the heads' segments (the same for every
+            # tile), which leaves each head's score on all its lanes as the
+            # butterfly does; the float32 products go through the MXU as two
+            # bfloat16 terms (16 bits of mantissa: scores good to 1e-5)
+            prod = jnp.concatenate(
+                [q_ref[0, g:g + 1, c:c + w].astype(jnp.float32) * sm_scale
+                 * k_ref[0, :, c:c + w].astype(jnp.float32)
+                 for c in range(0, cp, w) for g in range(group)], axis=0)
+            hi = prod.astype(jnp.bfloat16)
+            lo = (prod - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+            s_all = jnp.dot(jnp.concatenate([hi, lo], axis=0), seg_ref[...],
+                            preferred_element_type=jnp.float32)
+            s_all = s_all[:prod.shape[0]] + s_all[prod.shape[0]:]
         for c in range(0, cp, w):
-            q = q_ref[0, :, c:c + w].astype(jnp.float32) * sm_scale  # (1, w)
+            # the page's tile is read once; the `group` query heads that
+            # share each KV head (row g of q, of the state and of the output)
+            # take it in turn
             k = k_ref[0, :, c:c + w].astype(jnp.float32)             # (ps, w)
             v = v_ref[0, :, c:c + w].astype(jnp.float32)
-            # every head's q.k at once: multiply, then all-reduce inside
-            # each head's D lanes (D a power of two; lane i's partner at
-            # stride sh is i ^ sh, which never leaves the head's segment)
-            s = q * k
-            sh = 1
-            while sh < d:
-                s = s + jnp.where((lane & sh) == 0,
-                                  pltpu.roll(s, w - sh, 1),
-                                  pltpu.roll(s, sh, 1))
-                sh *= 2
-            s = jnp.where(live, s, _NEG_INF)
-            m = m_scr[0:1, c:c + w]
-            new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-            alpha = jnp.exp(m - new_m)
-            p = jnp.exp(s - new_m)                                   # (ps, w)
-            l = l_scr[0:1, c:c + w] * alpha + jnp.sum(p, axis=0,
-                                                      keepdims=True)
-            acc = acc_scr[0:1, c:c + w] * alpha + jnp.sum(
-                p * v, axis=0, keepdims=True)
-            m_scr[:, c:c + w] = jnp.broadcast_to(new_m, (8, w))
-            l_scr[:, c:c + w] = jnp.broadcast_to(l, (8, w))
-            acc_scr[:, c:c + w] = jnp.broadcast_to(acc, (8, w))
+            for g in range(group):
+                if mxu:
+                    at = ((c // w) * group + g) * ps
+                    s = s_all[at:at + ps]
+                else:
+                    q = q_ref[0, g:g + 1, c:c + w].astype(jnp.float32) \
+                        * sm_scale                                   # (1, w)
+                    # every head's q.k at once: multiply, then all-reduce
+                    # inside each head's D lanes (D a power of two; lane i's
+                    # partner at stride sh is i ^ sh, which never leaves the
+                    # head's segment)
+                    s = q * k
+                    sh = 1
+                    while sh < d:
+                        s = s + jnp.where((lane & sh) == 0,
+                                          pltpu.roll(s, w - sh, 1),
+                                          pltpu.roll(s, sh, 1))
+                        sh *= 2
+                s = jnp.where(live, s, _NEG_INF)
+                m = m_scr[g:g + 1, c:c + w]
+                new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+                alpha = jnp.exp(m - new_m)
+                p = jnp.exp(s - new_m)                               # (ps, w)
+                l = l_scr[g:g + 1, c:c + w] * alpha + jnp.sum(
+                    p, axis=0, keepdims=True)
+                acc = acc_scr[g:g + 1, c:c + w] * alpha + jnp.sum(
+                    p * v, axis=0, keepdims=True)
+                if group == 1:
+                    m_scr[:, c:c + w] = jnp.broadcast_to(new_m, (8, w))
+                    l_scr[:, c:c + w] = jnp.broadcast_to(l, (8, w))
+                    acc_scr[:, c:c + w] = jnp.broadcast_to(acc, (8, w))
+                else:
+                    m_scr[g:g + 1, c:c + w] = new_m
+                    l_scr[g:g + 1, c:c + w] = l
+                    acc_scr[g:g + 1, c:c + w] = acc
 
     @pl.when(j == n_pages - 1)
     def _():
-        o_ref[0] = (acc_scr[0:1, :]
-                    / jnp.maximum(l_scr[0:1, :], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[0:group, :]
+                    / jnp.maximum(l_scr[0:group, :], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
-def _paged_kernel_takes(d, ps, cp, pool_dtype):
+def _paged_kernel_takes(d, ps, cp, pool_dtype, group=1):
     """Whether the Pallas kernel can read a pool of this form: the lane
     butterfly needs a power-of-two head size, a page must be whole sublane
-    tiles of the pool's dtype (8 rows of 32 bits, 16 of 16, 32 of 8), and
-    its rows whole 128-lane tiles."""
+    tiles of the pool's dtype (8 rows of 32 bits, 16 of 16, 32 of 8), its
+    rows whole 128-lane tiles, and the query heads that share a KV head
+    one row each of the (8, Cp) softmax state."""
     sublanes = 32 // _np.dtype(pool_dtype).itemsize
-    return d & (d - 1) == 0 and ps % sublanes == 0 and cp % 128 == 0
+    return (d & (d - 1) == 0 and ps % sublanes == 0 and cp % 128 == 0
+            and 1 <= group <= 8)
 
 
 @functools.lru_cache(maxsize=128)
 def _paged_compiled(key):
-    (b, d, cp, maxp, ps, dtype, sm_scale, interpret) = key
+    (b, d, cp, maxp, ps, dtype, sm_scale, interpret, group, mxu) = key
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -881,34 +933,40 @@ def _paged_compiled(key):
         num_scalar_prefetch=2,          # page_tables, lengths (SMEM)
         grid=(b, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1, cp), row, memory_space=pltpu.VMEM),   # q
+            pl.BlockSpec((1, group, cp), row, memory_space=pltpu.VMEM),  # q
             pl.BlockSpec((1, ps, cp), page, memory_space=pltpu.VMEM),  # k
             pl.BlockSpec((1, ps, cp), page, memory_space=pltpu.VMEM),  # v
-        ],
-        out_specs=pl.BlockSpec((1, 1, cp), row, memory_space=pltpu.VMEM),
+        ] + ([pl.BlockSpec((128, 128), lambda bb, j, tbl, lens: (0, 0),
+                           memory_space=pltpu.VMEM)] if mxu else []),
+        out_specs=pl.BlockSpec((1, group, cp), row,
+                               memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((8, cp), jnp.float32),     # m
                         pltpu.VMEM((8, cp), jnp.float32),     # l
                         pltpu.VMEM((8, cp), jnp.float32)],    # acc
     )
     return pl.pallas_call(
         functools.partial(_paged_kernel, sm_scale=sm_scale, ps=ps, d=d,
-                          n_pages=maxp),
+                          n_pages=maxp, group=group, mxu=mxu),
         name="paged_attention_decode",
-        out_shape=jax.ShapeDtypeStruct((b, 1, cp), _np.dtype(dtype)),
+        out_shape=jax.ShapeDtypeStruct((b, group, cp), _np.dtype(dtype)),
         grid_spec=grid_spec,
         interpret=interpret,
     )
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, lengths,
-                    sm_scale=None):
+                    sm_scale=None, kv_heads=None):
     """Flash-decode attention: one query token per sequence against a
     paged KV cache (docs/serving.md §Generation).
 
     q: (B, H, D) — the current token's per-head queries. k_pages /
-    v_pages: (P, page_size, Cp) token-major block-allocated cache, head h
-    in lanes [h*D, (h+1)*D), Cp = H*D rounded up to a multiple of 128 by
-    the allocation (the kernel never pads or slices the pool).
+    v_pages: (P, page_size, Cp) token-major block-allocated cache, KV head h
+    in lanes [h*D, (h+1)*D), Cp = KV*D rounded up to a multiple of 128 by
+    the allocation. KV = ``kv_heads`` (None: H, one K and V a query head);
+    with fewer KV heads than query heads, query head i reads KV head
+    i // (H // KV): the page is streamed once and each of its tiles serves
+    the H // KV heads that share it (the kernel never pads, slices or
+    repeats the pool).
     page_tables: (B, max_pages) int32 — sequence b's token t lives in page
     ``page_tables[b, t // page_size]`` row ``t % page_size``; entries
     past the sequence's used pages must still be VALID page indices
@@ -916,8 +974,9 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths,
     lengths: (B,) int32 live-token counts (0 disables a padding row).
 
     A head size that is no power of two, a page that is not whole sublane
-    tiles of the pool's dtype or a Cp off the lane tile goes to
-    `paged_attention_reference`: decided from the shapes alone.
+    tiles of the pool's dtype, a Cp off the lane tile or more than 8 query
+    heads a KV head goes to `paged_attention_reference`: decided from the
+    shapes alone.
     """
     import jax.numpy as jnp
 
@@ -928,15 +987,195 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths,
     sm_scale = float(sm_scale)
     b, h, d = q.shape
     _, ps, cp = k_pages.shape
+    kv = h if kv_heads is None else int(kv_heads)
+    if h % kv or kv * d > cp:
+        raise ValueError("%d query heads cannot share %d KV heads of %d in "
+                         "a pool row of %d lanes" % (h, kv, d, cp))
+    group = h // kv
     gate = (_env.raw("MXTPU_PALLAS_DECODE") or "auto").strip().lower()
     interpret = _use_interpret()
     if (gate == "0" or (gate == "auto" and interpret)
-            or not _paged_kernel_takes(d, ps, cp, k_pages.dtype)):
+            or not _paged_kernel_takes(d, ps, cp, k_pages.dtype, group)):
         return paged_attention_reference(q, k_pages, v_pages, page_tables,
-                                         lengths, sm_scale)
+                                         lengths, sm_scale, kv)
+    # grouped-query heads inside one lane tile: the lanes' sums go through
+    # the MXU, one product a page (on the chip 2.2 ms a call at LFM2's
+    # shapes where the butterfly once a query head took 7.8: PERF.md)
+    mxu = group > 1 and d <= 128
     call = _paged_compiled((b, d, cp, page_tables.shape[1], ps,
-                            str(q.dtype), sm_scale, interpret))
-    rows = jnp.pad(q.reshape(b, h * d), ((0, 0), (0, cp - h * d)))
+                            str(q.dtype), sm_scale, interpret, group, mxu))
+    # row g holds, for every KV head, the g-th of the query heads that
+    # share it, in that KV head's lanes
+    rows = q.reshape(b, kv, group, d).transpose(0, 2, 1, 3) \
+        .reshape(b, group, kv * d)
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, cp - kv * d)))
+    extra = ()
+    if mxu:
+        lanes = jnp.arange(128) // d
+        extra = ((lanes[:, None] == lanes[None, :]).astype(jnp.bfloat16),)
     out = call(page_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-               rows[:, None, :], k_pages, v_pages)
-    return out[:, 0, :h * d].reshape(b, h, d)
+               rows, k_pages, v_pages, *extra)
+    return out[:, :, :kv * d].reshape(b, group, kv, d) \
+        .transpose(0, 2, 1, 3).reshape(b, h, d)
+
+
+# ---------------------------------------------------------------------------
+# Grouped expert feed-forward: rows sorted by expert into tiles of `tm`, one
+# expert a tile; tile t computes w2_e (silu(w1_e x) * w3_e x) for its rows
+# with the weights of expert `tile_expert[t]`, which ride scalar prefetch so
+# that the BlockSpec index maps stream exactly the experts that were hit, once
+# a tile, in blocks of `_moe_tf` of the expert's width. Tiles past `n_tiles` (the
+# static grid covers the worst routing) keep the last live tile's block
+# indices, so nothing is fetched for them, and write zeros.
+#
+# w1, w3, w2 are all (E, F, C): a block (1, tf, C) is contiguous in each.
+# Gate as for paged attention: MXTPU_PALLAS_DECODE `auto` = kernel on TPU, the
+# jnp path elsewhere; `1` forces the kernel (interpret mode off the chip).
+# ---------------------------------------------------------------------------
+
+_MOE_BLOCK_BYTES = 40 << 20     # the three weight blocks, double-buffered
+
+
+def moe_grouped_ffn_reference(xs, tile_expert, n_tiles, w1, w3, w2, tm):
+    """Oracle and fallback of `moe_grouped_ffn`: every tile's weights
+    gathered whole, float32 accumulation, zeros past ``n_tiles``."""
+    import jax
+    import jax.numpy as jnp
+
+    t = tile_expert.shape[0]
+    x = xs.reshape(t, tm, xs.shape[-1])
+    a = jnp.einsum("tmc,tfc->tmf", x, w1[tile_expert],
+                   preferred_element_type=jnp.float32)
+    b = jnp.einsum("tmc,tfc->tmf", x, w3[tile_expert],
+                   preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(a) * b).astype(xs.dtype)
+    y = jnp.einsum("tmf,tfc->tmc", h, w2[tile_expert],
+                   preferred_element_type=jnp.float32)
+    live = jnp.arange(t)[:, None, None] < n_tiles
+    return jnp.where(live, y, 0.0).reshape(t * tm, xs.shape[-1])
+
+
+def _moe_kernel(te_ref, nt_ref, x_ref, w1_ref, w3_ref, w2_ref, o_ref,
+                acc_ref, *, nf):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    t = pl.program_id(0)
+    j = pl.program_id(1)
+    live = t < nt_ref[0]
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _():
+        x = x_ref[...]
+        nt = (((1,), (1,)), ((), ()))
+        a = jax.lax.dot_general(x, w1_ref[0], nt,
+                                preferred_element_type=jnp.float32)
+        b = jax.lax.dot_general(x, w3_ref[0], nt,
+                                preferred_element_type=jnp.float32)
+        h = (a * jax.nn.sigmoid(a) * b).astype(x.dtype)
+        acc_ref[...] += jnp.dot(h, w2_ref[0],
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(j == nf - 1)
+    def _():
+        o_ref[...] = acc_ref[...]
+
+
+def _moe_tf(f, c, itemsize):
+    """The expert-width block of a grid step: the widest split of the
+    expert's width into whole lane tiles whose three weight blocks fit VMEM
+    twice over (1536 = the whole expert at LFM2's sizes in bfloat16, which
+    read 85% of the HBM's peak on the chip where blocks of 512 read 81%)."""
+    for parts in range(1, f // 128 + 1):
+        tf = f // parts
+        if f % parts == 0 and tf % 128 == 0 \
+                and 6 * tf * c * itemsize <= _MOE_BLOCK_BYTES:
+            return tf
+    return 128 if f % 128 == 0 else f
+
+
+def _moe_kernel_takes(tm, c, f, dtype):
+    """Whole tiles only: rows a sublane tile of the dtype, the model width
+    whole lanes, the expert width split into blocks of whole lanes."""
+    itemsize = _np.dtype(dtype).itemsize
+    return (tm % (32 // itemsize) == 0 and c % 128 == 0
+            and _moe_tf(f, c, itemsize) % 128 == 0 and f % 128 == 0)
+
+
+@functools.lru_cache(maxsize=64)
+def _moe_compiled(key):
+    (tiles, tm, c, f, dtype, interpret) = key
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    itemsize = _np.dtype(dtype).itemsize
+    tf = _moe_tf(f, c, itemsize)
+    nf = f // tf
+
+    def weights(t, j, te, nt):
+        live = t < nt[0]
+        return (te[t], jnp.where(live, j, nf - 1), 0)
+
+    def rows(t, j, te, nt):
+        return (jnp.where(t < nt[0], t, jnp.maximum(nt[0] - 1, 0)), 0)
+
+    def out(t, j, te, nt):
+        return (t, 0)
+
+    need = 2 * (3 * tf * c * itemsize + tm * c * (itemsize + 4)) \
+        + tm * c * 4 + 3 * tm * tf * 4
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,          # tile_expert, n_tiles (SMEM)
+        grid=(tiles, nf),
+        in_specs=[
+            pl.BlockSpec((tm, c), rows),
+            pl.BlockSpec((1, tf, c), weights),
+            pl.BlockSpec((1, tf, c), weights),
+            pl.BlockSpec((1, tf, c), weights),
+        ],
+        out_specs=pl.BlockSpec((tm, c), out),
+        scratch_shapes=[pltpu.VMEM((tm, c), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_moe_kernel, nf=nf),
+        name="moe_grouped_ffn",
+        out_shape=jax.ShapeDtypeStruct((tiles * tm, c), jnp.float32),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(max(32 << 20, need + (8 << 20)))),
+        interpret=interpret,
+    )
+
+
+def moe_grouped_ffn(xs, tile_expert, n_tiles, w1, w3, w2, tm):
+    """Grouped SwiGLU over the experts that were hit.
+
+    xs (T*tm, C): the routed rows laid out by `ops.contrib.sigmoid_topk_moe`,
+    tile t holding rows of expert ``tile_expert[t]`` only (rows past an
+    expert's count are zeros); ``n_tiles`` (1,) int32 live tiles; w1, w3, w2
+    (E, F, C). Returns (T*tm, C) float32, zeros in the tiles past
+    ``n_tiles``. Shapes the kernel cannot take go to
+    `moe_grouped_ffn_reference`, decided from the shapes alone."""
+    import jax.numpy as jnp
+
+    from .. import env as _env
+
+    tiles = tile_expert.shape[0]
+    c, f = xs.shape[-1], w1.shape[1]
+    gate = (_env.raw("MXTPU_PALLAS_DECODE") or "auto").strip().lower()
+    interpret = _use_interpret()
+    if (gate == "0" or (gate == "auto" and interpret)
+            or not _moe_kernel_takes(tm, c, f, xs.dtype)):
+        return moe_grouped_ffn_reference(xs, tile_expert, n_tiles, w1, w3,
+                                         w2, tm)
+    call = _moe_compiled((tiles, tm, c, f, str(xs.dtype), interpret))
+    return call(tile_expert.astype(jnp.int32), n_tiles.astype(jnp.int32),
+                xs, w1, w3, w2)

@@ -80,7 +80,7 @@ from .batcher import (DeadlineExceededError, DrainingError, QueueFullError,
                       ServingError, bucket_for, drain_timeout_s,
                       power_of_two_buckets)
 
-__all__ = ["KVPageAllocator", "GenRequest", "GenerateScheduler",
+__all__ = ["KVPageAllocator", "StateSlotPool", "GenRequest", "GenerateScheduler",
            "TransformerLMEngine", "ServedLM", "save_lm", "load_lm"]
 
 _LOG = logging.getLogger("mxnet_tpu.serving.generate")
@@ -164,6 +164,50 @@ class KVPageAllocator:
             self._m_occ.set(used / float(self.num_pages))
 
 
+class StateSlotPool:
+    """Free list of fixed per-sequence state slots: the second kind of
+    state a sequence can own beside its KV pages. A model with recurrent
+    layers (the engine's short convolutions) keeps one row a layer for every
+    sequence that can be active; a slot is taken at admission, written by
+    the prefill, read and rewritten by every decode step, and returned the
+    step the sequence leaves (retire, abort, deadline, prefill failure
+    alike). Slots do not grow, so unlike pages one slot is the whole grant.
+    Occupancy rides `mxtpu_serve_state_slots_{total,used}`."""
+
+    def __init__(self, num_slots, name="default"):
+        self.num_slots = int(num_slots)
+        self._lock = threading.Lock()
+        self._free = list(range(self.num_slots - 1, -1, -1))
+        labels = {"model": name}
+        telemetry.gauge("mxtpu_serve_state_slots_total",
+                        labels).set(self.num_slots)
+        self._m_used = telemetry.gauge("mxtpu_serve_state_slots_used",
+                                       labels)
+        self._m_used.set(0)
+
+    @property
+    def used_slots(self):
+        with self._lock:
+            return self.num_slots - len(self._free)
+
+    def alloc(self):
+        """One slot, or None when every slot is taken."""
+        with self._lock:
+            if not self._free:
+                return None
+            slot = self._free.pop()
+            self._m_used.set(self.num_slots - len(self._free))
+        return slot
+
+    def free(self, slot):
+        with self._lock:
+            if slot in self._free or not (0 <= slot < self.num_slots):
+                raise MXNetError("double-free/corrupt state slot %r"
+                                 % (slot,))
+            self._free.append(slot)
+            self._m_used.set(self.num_slots - len(self._free))
+
+
 # ---------------------------------------------------------------------------
 # requests
 # ---------------------------------------------------------------------------
@@ -236,13 +280,14 @@ class GenRequest:
 class _Sequence:
     """Scheduler-internal state of one RUNNING sequence."""
 
-    __slots__ = ("req", "pages", "page_row", "pos", "generated", "t_last",
-                 "n_steps")
+    __slots__ = ("req", "pages", "page_row", "slot", "pos", "generated",
+                 "t_last", "n_steps")
 
-    def __init__(self, req, pages, page_row, pos, first_token):
+    def __init__(self, req, pages, page_row, pos, first_token, slot=None):
         self.req = req
         self.pages = pages
         self.page_row = page_row
+        self.slot = slot          # state slot (models with recurrent layers)
         self.pos = pos            # position of the NEXT token to feed
         self.generated = [first_token]
         self.t_last = time.perf_counter()
@@ -292,6 +337,11 @@ class GenerateScheduler:
         self.queue_depth = max(1, int(queue_depth))
         self.allocator = KVPageAllocator(engine.num_pages, engine.page_size,
                                          name=self.name)
+        # the second kind of state: one fixed slot a sequence, only for an
+        # engine whose model has recurrent layers
+        n_slots = getattr(engine, "state_slots", 0)
+        self.slots = StateSlotPool(n_slots, name=self.name) \
+            if n_slots else None
 
         self._cv = threading.Condition()
         self._queue = collections.deque()
@@ -466,7 +516,7 @@ class GenerateScheduler:
             with self._cv:
                 leftovers, self._active = self._active, []
             for seq in leftovers:
-                self.allocator.free(seq.pages)
+                self._release(seq)
             self._m_active.set(0)
         # verdicts for a gone model are noise on /statusz
         _slo.unregister_model(self.name)
@@ -485,7 +535,8 @@ class GenerateScheduler:
                     return
             _goodput.step_start(kind="serve")
             self._lap = {"n": 0, "bucket": 0, "sampled": 0, "prefills": 0,
-                         "admitted": 0, "queue_wait_s": 0.0}
+                         "admitted": 0, "queue_wait_s": 0.0,
+                         "prefill_tokens": 0}
             try:
                 with _goodput.phase("admit"):
                     self._admit()
@@ -501,7 +552,7 @@ class GenerateScheduler:
                 with self._cv:
                     dead, self._active = self._active, []
                 for seq in dead:
-                    self.allocator.free(seq.pages)
+                    self._release(seq)
                     seq.req._resolve(error=err)
                 self._m_active.set(0)
             lap = _goodput.step_end(model=self.name, **self._lap)
@@ -564,6 +615,12 @@ class GenerateScheduler:
                 pages = self.allocator.alloc(need)
                 if pages is None:
                     break            # pool pressure: stays queued
+                slot = None
+                if self.slots is not None:
+                    slot = self.slots.alloc()
+                    if slot is None:     # never while active < max_active
+                        self.allocator.free(pages)
+                        break
                 self._queue.popleft()
                 self._m_queue.set(len(self._queue))
             req.queue_seconds = time.perf_counter() - req._t_submit
@@ -580,9 +637,12 @@ class GenerateScheduler:
                     first = self.engine.prefill(
                         req.tokens, page_row,
                         (req.temperature, req.top_k, req.top_p),
-                        _random.next_key())
-            except Exception as e:  # bad prompt/model: answer, free pages
+                        _random.next_key(),
+                        **({} if slot is None else {"slot": slot}))
+            except Exception as e:  # bad prompt/model: answer, free state
                 self.allocator.free(pages)
+                if slot is not None:
+                    self.slots.free(slot)
                 err = ServingError("prefill on %r failed: %r"
                                    % (self.name, e))
                 err.__cause__ = e
@@ -591,6 +651,7 @@ class GenerateScheduler:
                 req._resolve(error=err)
                 continue
             lap["prefills"] += 1
+            lap["prefill_tokens"] += len(req.tokens)
             self._m_prefill.observe(prefill.elapsed, exemplar=exemplar)
             if trace is not None and trace.recorded:
                 t0_wall = time.time() - (time.perf_counter() - prefill.t0)
@@ -602,7 +663,8 @@ class GenerateScheduler:
                     component="decode",
                     attrs={"prompt": len(req.tokens), "pages": len(pages)})
             self._m_tokens.inc()
-            seq = _Sequence(req, pages, page_row, len(req.tokens), first)
+            seq = _Sequence(req, pages, page_row, len(req.tokens), first,
+                            slot)
             with _goodput.phase("retire"):
                 done = self._finish_if_done(seq)
             if not done:
@@ -620,7 +682,7 @@ class GenerateScheduler:
             live = []
             for seq in self._active:
                 if seq.req.done():
-                    self.allocator.free(seq.pages)
+                    self._release(seq)
                 elif seq.req.deadline is not None \
                         and now >= seq.req.deadline:
                     self._retire(seq, None, error=DeadlineExceededError(
@@ -649,6 +711,13 @@ class GenerateScheduler:
             temps = _np.zeros(bucket, _np.float32)
             top_ks = _np.zeros(bucket, _np.int32)
             top_ps = _np.ones(bucket, _np.float32)
+            extra = {}
+            if self.slots is not None:
+                # a padding row names the row past the inert one: it reads
+                # the inert row and its write drops
+                seq_slots = extra["seq_slots"] = _np.full(
+                    bucket, self.slots.num_slots + 1, _np.int32)
+                seq_slots[:n] = [seq.slot for seq in live]
             for i, seq in enumerate(live):
                 tokens[i] = seq.generated[-1]
                 positions[i] = seq.pos
@@ -664,8 +733,16 @@ class GenerateScheduler:
         with _goodput.phase("decode_dispatch") as step:
             nxt = self.engine.decode_step(tokens, positions, dest_pages,
                                           dest_slots, tables, lengths, temps,
-                                          top_ks, top_ps, _random.next_key())
-        self._lap.update(n=n, bucket=bucket, sampled=sampled)
+                                          top_ks, top_ps, _random.next_key(),
+                                          **extra)
+        self._lap.update(n=n, bucket=bucket, sampled=sampled,
+                         context_tokens=int(lengths.sum()))
+        if self.slots is not None:
+            self._lap["state_slots"] = self.slots.used_slots
+        moe = getattr(self.engine, "last_moe", None)
+        if moe is not None:
+            self._lap.update(moe_pairs=moe[0], moe_experts_hit=moe[1],
+                             moe_load_max=moe[2])
         self._m_steps.inc()
         self._m_sampler["sampled" if sampled else "greedy"].inc()
         self._m_decode.observe(step.elapsed)
@@ -708,8 +785,14 @@ class GenerateScheduler:
             return True
         return False
 
-    def _retire(self, seq, finish_reason, error=None):
+    def _release(self, seq):
+        """Return everything a sequence holds: its pages and its slot."""
         self.allocator.free(seq.pages)
+        if seq.slot is not None:
+            self.slots.free(seq.slot)
+
+    def _retire(self, seq, finish_reason, error=None):
+        self._release(seq)
         if error is not None:
             self._m_expired.inc()
             seq.req._resolve(error=error)
@@ -719,60 +802,197 @@ class GenerateScheduler:
 
 
 # ---------------------------------------------------------------------------
-# the Transformer-LM decode engine
+# the decoder-LM engine: one per-layer description, two programs
 # ---------------------------------------------------------------------------
 
-def _ln(x, p):
+def transformer_lm_description(config):
+    """The per-layer description of `model_zoo.transformer.TransformerLM`:
+    learned positions, a LayerNorm after the embeddings and after each
+    residual add (post-LN), biased projections, one K and V a query head, an
+    erf-GELU feed-forward, the head tied to the embedding, float32."""
+    heads = int(config["num_heads"])
+    return {"arch": "transformer_lm", "dtype": "float32",
+            "units": int(config["units"]),
+            "vocab_size": int(config["vocab_size"]),
+            "max_length": int(config["max_length"]),
+            "norm": "layer", "norm_at": "post", "norm_eps": 1e-5,
+            "positions": "learned", "embed_norm": True, "final_norm": False,
+            "head": "tied", "heads": heads, "kv_heads": heads,
+            "head_dim": int(config["units"]) // heads, "qk_norm": False,
+            "ffn": "gelu",
+            "layers": [{"operator": "attention", "ffn": "dense"}
+                       for _ in range(int(config["num_layers"]))]}
+
+
+def _lm_norm(desc, x, p):
     from ..ops import nn as _opsnn
 
+    if desc["norm"] == "rms":
+        return _opsnn.rms_norm(x, p["g"], eps=desc["norm_eps"])
     return _opsnn.layer_norm(x, p["g"], p["b"])
 
 
-def _dense(x, p):
-    return x @ p["w"].T + p["b"]
+def _lm_dense(x, p):
+    from ..ops import nn as _opsnn
+
+    y = _opsnn.matmul_nt(x, p["w"])
+    return y + p["b"] if "b" in p else y
+
+
+def _lm_layers(desc, params, x, positions, valid, kv, slots, attention, conv):
+    """The layers of a decoder LM over rows ``x`` (n, C), one row a token at
+    ``positions`` (n,): the block both of the engine's programs run. What
+    differs between them is where an operator keeps its state, so the two
+    stateful steps are handed in: ``attention((K, V) pages of the layer, q,
+    k, v) -> (attended, new pages)`` (store k and v, attend) and
+    ``conv(slots of the layer, r, layer) -> (o, new slots)`` (the gated
+    short convolution over its slot); ``kv`` and ``slots`` hold one entry an
+    attention / convolution layer. Rows with ``valid`` false are a bucket's
+    padding: they are computed like any row and route to no expert. Returns
+    (rows, per-expert-layer stats, new kv, new slots)."""
+    import jax
+
+    from ..ops import nn as _opsnn
+    from ..ops.contrib import sigmoid_topk_moe
+
+    n = x.shape[0]
+    pre = desc["norm_at"] == "pre"
+    h, kvh, dh = desc["heads"], desc["kv_heads"], desc["head_dim"]
+    stats, new_kv, new_slots = [], [], []
+    for layer, spec in zip(params["layers"], desc["layers"]):
+        r = _lm_norm(desc, x, layer["attn_norm"]) if pre else x
+        if spec["operator"] == "attention":
+            with jax.named_scope("mxtpu.lm.attn"):
+                q = _lm_dense(r, layer["q"]).reshape(n, h, dh)
+                k = _lm_dense(r, layer["k"]).reshape(n, kvh, dh)
+                v = _lm_dense(r, layer["v"]).reshape(n, kvh, dh)
+                if desc["qk_norm"]:
+                    q = _opsnn.rms_norm(q, layer["q_norm"]["g"],
+                                        eps=desc["norm_eps"])
+                    k = _opsnn.rms_norm(k, layer["k_norm"]["g"],
+                                        eps=desc["norm_eps"])
+                if desc["positions"] == "rotary":
+                    q = _opsnn.rope(q, positions, desc["rope_theta"])
+                    k = _opsnn.rope(k, positions, desc["rope_theta"])
+                att, pages = attention(kv[len(new_kv)], q, k, v)
+                new_kv.append(pages)
+                o = _lm_dense(att.astype(x.dtype).reshape(n, h * dh),
+                              layer["o"])
+        else:
+            with jax.named_scope("mxtpu.lm.conv"):
+                o, slot = conv(slots[len(new_slots)], r, layer)
+                new_slots.append(slot)
+        x = x + o
+        if not pre:
+            x = _lm_norm(desc, x, layer["attn_norm"])
+        r = _lm_norm(desc, x, layer["ffn_norm"]) if pre else x
+        if spec["ffn"] == "experts":
+            ex = desc["experts"]
+            f, st = sigmoid_topk_moe(
+                r, layer["gate"], layer["expert_bias"], layer["ew1"],
+                layer["ew3"], layer["ew2"], k=ex["per_token"],
+                expert_offset=ex["offset"], valid=valid,
+                routed_scaling_factor=ex["scaling"],
+                norm_topk_prob=ex["norm_topk"])
+            stats.append(st)
+        elif desc["ffn"] == "swiglu":
+            f = _opsnn.swiglu_ffn(r, layer["w1"], layer["w3"], layer["w2"])
+        else:
+            f = _lm_dense(jax.nn.gelu(_lm_dense(r, layer["ffn1"]),
+                                      approximate=False), layer["ffn2"])
+        x = x + f
+        if not pre:
+            x = _lm_norm(desc, x, layer["ffn_norm"])
+    return x, stats, tuple(new_kv), tuple(new_slots)
+
+
+def _lm_embed(desc, params, tokens, positions):
+    x = params["word"][tokens]
+    if desc["positions"] == "learned":
+        x = x + params["pos"][positions]
+    if desc["embed_norm"]:
+        x = _lm_norm(desc, x, params["embed_norm"])
+    return x
+
+
+def _lm_logits(desc, params, x):
+    """Rows -> float32 logits over the vocabulary."""
+    from ..ops import nn as _opsnn
+
+    if desc["final_norm"]:
+        x = _lm_norm(desc, x, params["final_norm"])
+    head = params["word"] if desc["head"] == "tied" else params["head"]
+    return _opsnn.matmul_nt(x, head, "float32")
 
 
 class TransformerLMEngine:
-    """Incremental (paged-KV) execution of a `TransformerLM`.
+    """Incremental execution of a decoder LM through paged KV and state
+    slots.
 
-    Prefill computes the full causal forward of a padded prompt bucket,
-    writes every token's K/V into the sequence's pages and samples the
-    first token; decode_step feeds one token per active sequence, appends
-    its K/V and attends over the page table
-    (`ops/pallas_kernels.paged_attention`). Both are pure functions of
-    (params, kv, inputs) resolved through the `mxnet_tpu.compile`
-    registry — parameters ride as arguments, so the executables are keyed
-    purely by geometry. Single-threaded: only the scheduler worker may
-    drive an engine.
+    The model is a per-layer description (`transformer_lm_description`, or a
+    zoo block's ``description()``, carried by a `save_lm` artifact's header):
+    each layer an operator (attention | short convolution) and a
+    feed-forward (dense | experts), with the norm (layer | RMS, post | pre),
+    the positions (learned | rotary) and the head (tied | own) of the whole
+    model. Prefill and decode are both built from it over the same layer
+    function (`_lm_layers`); they differ only in where an operator keeps
+    its state:
 
-    The KV pool (`_kv`, donated whole to every executable) is a tuple of
-    one (K, V) pair per layer, each array ``(num_pages, page_size, Cp)``:
-    a page is page_size rows, a row one token's values of all heads side
-    by side (head h in columns [h*Dh, (h+1)*Dh)), Cp = num_heads*head_dim
-    rounded up to a multiple of 128 at allocation. A token's K/V is one
-    row scattered at ``[page, slot]`` and the kernel reads whole pages,
-    so no executable copies, slices or pads the pool
-    (docs/serving.md §Generation).
+    * an **attention** layer owns one (K, V) pair of the page pool (`_kv`),
+      each array ``(num_pages, page_size, Cp)``: a page is page_size rows, a
+      row one token's values of all KV heads side by side (KV head h in
+      columns [h*Dh, (h+1)*Dh)), Cp = kv_heads*head_dim rounded up to a
+      multiple of 128 at allocation. Grouped-query models keep kv_heads <
+      heads rows wide; nothing is repeated per query head. Prefill computes
+      the causal forward of a padded prompt bucket and scatters every live
+      token's K/V to ``[page, slot]``; a decode step appends one row a
+      sequence and attends over the page table
+      (`ops/pallas_kernels.paged_attention`).
+    * a **short-convolution** layer owns one array of state slots
+      (`_slots`), ``(state_slots + 1, taps - 1, C)``: the gated input of a
+      sequence's last taps-1 positions. Prefill writes the slot at the
+      prompt's own length (not the padded bucket's), every decode step reads
+      and rewrites it; rows of a bucket's padding read the last, inert row
+      and their writes drop.
+
+    Parameters, pages and slots are kept in the description's dtype; matrix
+    products accumulate in float32; norm statistics, router scores, softmax
+    and logits are float32. Both programs are pure functions of (params,
+    state, inputs) resolved through the `mxnet_tpu.compile` registry —
+    parameters ride as arguments, so the executables are keyed purely by
+    description and geometry — and the state (pool and slots) is donated
+    whole to every call. Single-threaded: only the scheduler worker may
+    drive an engine (docs/serving.md §Generation).
     """
 
     def __init__(self, lm=None, params=None, config=None, num_pages=None,
                  page_size=None, max_prompt=None, max_new_tokens=None,
                  max_batch=None, decode_buckets=None, prefill_buckets=None,
-                 eos_id=None, kv_dtype="float32"):
+                 eos_id=None, kv_dtype=None, description=None):
         import jax
 
         if lm is not None:
             config = lm.config
             params = lm.decode_params()
+            if description is None and hasattr(lm, "description"):
+                description = lm.description()
         if config is None or params is None:
             raise MXNetError("TransformerLMEngine needs an lm= block or "
                              "params= + config=")
+        if description is None:
+            description = transformer_lm_description(config)
         self.config = dict(config)
-        self.vocab_size = int(config["vocab_size"])
-        self.units = int(config["units"])
-        self.num_heads = int(config["num_heads"])
-        self.head_dim = self.units // self.num_heads
-        self.num_layers = int(config["num_layers"])
+        self.description = desc = dict(description)
+        self.vocab_size = int(desc["vocab_size"])
+        self.units = int(desc["units"])
+        self.num_heads = int(desc["heads"])
+        self.kv_heads = int(desc["kv_heads"])
+        self.head_dim = int(desc["head_dim"])
+        self.num_layers = len(desc["layers"])
+        self.attn_layers = sum(1 for l in desc["layers"]
+                               if l["operator"] == "attention")
+        self.conv_layers = self.num_layers - self.attn_layers
+        self.has_experts = any(l["ffn"] == "experts" for l in desc["layers"])
         self.eos_id = None if eos_id is None else int(eos_id)
         self.page_size = int(page_size if page_size is not None
                              else _env.get("MXTPU_SERVE_KV_PAGE_SIZE"))
@@ -784,11 +1004,11 @@ class TransformerLMEngine:
             max_new_tokens if max_new_tokens is not None
             else _env.get("MXTPU_SERVE_MAX_NEW_TOKENS"))
         max_total = self.max_prompt + self.max_new_tokens
-        if max_total > int(config["max_length"]):
+        if max_total > int(desc["max_length"]):
             raise MXNetError(
                 "max_prompt + max_new_tokens = %d exceeds the model's "
                 "position table (max_length=%d)"
-                % (max_total, config["max_length"]))
+                % (max_total, desc["max_length"]))
         self.max_pages_per_seq = -(-max_total // self.page_size)
         if self.max_pages_per_seq > self.num_pages:
             raise MXNetError(
@@ -806,31 +1026,52 @@ class TransformerLMEngine:
                                power_of_two_buckets(self.max_prompt)
                                if b >= lo]
         self.prefill_buckets = sorted(int(b) for b in prefill_buckets)
-        self.kv_dtype = str(kv_dtype)
+        self.dtype = str(desc["dtype"])
+        self.kv_dtype = str(kv_dtype if kv_dtype is not None else self.dtype)
+        # one state slot a sequence that can be active, for every
+        # short-convolution layer; 0 where the model has none
+        self.state_slots = self.buckets[-1] if self.conv_layers else 0
 
+        dtype = jax.numpy.dtype(self.dtype)
         self._params = jax.tree_util.tree_map(
-            lambda a: jax.numpy.asarray(a, jax.numpy.float32), params)
+            lambda a: jax.numpy.asarray(a, dtype), params)
         self._param_bytes = int(sum(
             a.size * a.dtype.itemsize
             for a in jax.tree_util.tree_leaves(self._params)))
         # rows padded to the 128-lane tile HERE, once — never per call
-        leaf = (self.num_pages, self.page_size, -(-self.units // 128) * 128)
+        self._kv_lanes = self.kv_heads * self.head_dim
+        leaf = (self.num_pages, self.page_size,
+                -(-self._kv_lanes // 128) * 128)
         self._kv = tuple(
             tuple(jax.numpy.zeros(leaf, dtype=self.kv_dtype) for _ in "kv")
-            for _ in range(self.num_layers))
-        # executable identity: architecture + geometry (params are args,
+            for _ in range(self.attn_layers))
+        # row `state_slots` is the inert one that padding rows read
+        self._slots = tuple(
+            jax.numpy.zeros((self.state_slots + 1,
+                             int(desc["conv_taps"]) - 1, self.units),
+                            dtype=self.kv_dtype)
+            for _ in range(self.conv_layers))
+        self.last_moe = None    # (pairs, experts hit, busiest) of a step
+        # executable identity: description + geometry (params are args,
         # so two engines with one geometry share executables)
+        ident = {"config": self.config, "pages": self.num_pages,
+                 "page_size": self.page_size, "maxp": self.max_pages_per_seq,
+                 "kv": self.kv_dtype}
+        if desc["arch"] != "transformer_lm":
+            ident["description"] = desc
+            ident["slots"] = self.state_slots
         self._fingerprint = hashlib.sha256(json.dumps(
-            {"config": self.config, "pages": self.num_pages,
-             "page_size": self.page_size, "maxp": self.max_pages_per_seq,
-             "kv": self.kv_dtype}, sort_keys=True).encode()).hexdigest()[:32]
+            ident, sort_keys=True).encode()).hexdigest()[:32]
 
     # -- sizing ------------------------------------------------------------
     def kv_bytes(self):
-        """Device bytes of the page pool (allocated in full at load —
-        the figure `MXTPU_SERVE_MEMORY_BUDGET` admission prices)."""
-        return int(sum(a.size * a.dtype.itemsize
-                       for pair in self._kv for a in pair))
+        """Device bytes of the page pool and the state slots (allocated in
+        full at load — the figure `MXTPU_SERVE_MEMORY_BUDGET` admission
+        prices)."""
+        import jax
+
+        return int(sum(a.size * a.dtype.itemsize for a in
+                       jax.tree_util.tree_leaves((self._kv, self._slots))))
 
     def param_bytes(self):
         return self._param_bytes
@@ -843,6 +1084,7 @@ class TransformerLMEngine:
                 "decode_buckets": list(self.buckets),
                 "prefill_buckets": list(self.prefill_buckets),
                 "kv_dtype": self.kv_dtype,
+                "state_slots": self.state_slots,
                 "kv_bytes": self.kv_bytes(),
                 "param_bytes": self.param_bytes()}
 
@@ -852,9 +1094,9 @@ class TransformerLMEngine:
         # path is a dict get; serializing pallas/jnp decode graphs buys
         # little and the artifact trust story nothing)
         # donation=(1,): every executable minted through this key (prefill
-        # AND per-bucket decode) donates the KV pool at argnum 1, and the
-        # fill-hook donation verifier (telemetry.memory.verify_donation)
-        # only audits keys that declare it
+        # AND per-bucket decode) donates the state (KV pool, slots) at
+        # argnum 1, and the fill-hook donation verifier
+        # (telemetry.memory.verify_donation) only audits keys that declare it
         return _compile.ExecutableKey(
             kind, self._fingerprint, shapes=shape_sig, donation=(1,),
             static=(("pages", self.num_pages),
@@ -863,111 +1105,151 @@ class TransformerLMEngine:
                     ("kv", self.kv_dtype)),
             no_persist=True)
 
-    def _build_prefill(self, lp):
+    def _state(self):
+        """What every program is handed at argnum 1, donated: the page pool,
+        with the state slots beside it where the model has any."""
+        return (self._kv, self._slots) if self.conv_layers else self._kv
+
+    def _set_state(self, state):
+        if self.conv_layers:
+            self._kv, self._slots = state
+        else:
+            self._kv = state
+
+    def _build_prefill(self, lp, logits_out=False):
         import jax
         import jax.numpy as jnp
 
-        from ..ops.pallas_kernels import _NEG_INF
+        from ..ops import nn as _opsnn
         from ..ops.random_ops import sample_token_logits
 
-        H, Dh, ps = self.num_heads, self.head_dim, self.page_size
-        C, nump = self.units, self.num_pages
+        desc, ps, nump = self.description, self.page_size, self.num_pages
+        lanes, stateful = self._kv_lanes, bool(self.conv_layers)
         scale = 1.0 / math.sqrt(self.head_dim)
 
-        def fn(params, kv, tokens, length, page_row, temp, top_k, top_p,
-               key):
-            # tokens (lp,) int32 padded; length () int32; page_row (maxp,)
-            x = params["word"][tokens] + params["pos"][jnp.arange(lp)]
-            x = _ln(x, params["embed_norm"])                     # (lp, C)
-            causal = jnp.arange(lp)[None, :] <= jnp.arange(lp)[:, None]
+        def fn(params, state, tokens, length, page_row, temp, top_k, top_p,
+               key, slot=None):
+            # tokens (lp,) int32 padded; length () int32; page_row (maxp,);
+            # slot () int32, the sequence's state slot (stateful models)
+            kv, slots = state if stateful else (state, ())
             t_idx = jnp.arange(lp)
+            x = _lm_embed(desc, params, tokens, t_idx)           # (lp, C)
             tpage = jnp.where(t_idx < length, page_row[t_idx // ps], nump)
             tslot = t_idx % ps
-            new_kv = []
-            for layer, (kp, vp) in zip(params["layers"], kv):
-                qh = _dense(x, layer["q"]).reshape(lp, H, Dh)
-                k = _dense(x, layer["k"])
-                v = _dense(x, layer["v"])
-                kh, vh = k.reshape(lp, H, Dh), v.reshape(lp, H, Dh)
-                # one row of C contiguous values a token, in place
-                new_kv.append(
-                    (kp.at[tpage, tslot, :C].set(k.astype(kp.dtype),
-                                                 mode="drop"),
-                     vp.at[tpage, tslot, :C].set(v.astype(vp.dtype),
-                                                 mode="drop")))
-                s = jnp.einsum("qhd,khd->hqk", qh, kh) * scale
-                s = jnp.where(causal[None], s, _NEG_INF)
-                p = jax.nn.softmax(s, axis=-1)
-                att = jnp.einsum("hqk,khd->qhd", p, vh).reshape(lp, -1)
-                x = _ln(x + _dense(att, layer["o"]), layer["attn_norm"])
-                h = jax.nn.gelu(_dense(x, layer["ffn1"]), approximate=False)
-                x = _ln(x + _dense(h, layer["ffn2"]), layer["ffn_norm"])
-            logits = x[length - 1] @ params["word"].T            # (V,)
+
+            def attention(pages, q, k, v):
+                kp, vp = pages
+                # one row of contiguous values a token, in place
+                pages = (kp.at[tpage, tslot, :lanes].set(
+                             k.reshape(lp, lanes).astype(kp.dtype),
+                             mode="drop"),
+                         vp.at[tpage, tslot, :lanes].set(
+                             v.reshape(lp, lanes).astype(vp.dtype),
+                             mode="drop"))
+                return _opsnn.causal_attention(q, k, v, scale), pages
+
+            def conv(held, r, layer):
+                # the slot holds the gated input of the prompt's own last
+                # positions, whatever the bucket's padding computes after
+                o, st = _opsnn.gated_short_conv(
+                    r, layer["in"]["w"], layer["conv"], layer["out"]["w"],
+                    None, length)
+                return o, held.at[slot].set(st.astype(held.dtype),
+                                            mode="drop")
+
+            x, _, kv, slots = _lm_layers(desc, params, x, t_idx,
+                                         t_idx < length, kv, slots,
+                                         attention, conv)
+            new = (kv, slots) if stateful else kv
+            if logits_out:
+                return _lm_logits(desc, params, x), new          # (lp, V)
+            logits = _lm_logits(desc, params, x[length - 1])     # (V,)
             tok = sample_token_logits(key, logits[None], temp, top_k,
                                       top_p)
-            return tok[0], tuple(new_kv)
+            return tok[0], new
 
-        # the kv pool is DONATED: without it every call materializes a
+        # the state is DONATED: without it every call materializes a
         # second full pool for the output (transient 2x kv_bytes — the
         # exact OOM the load-time budget admission promises to preclude)
         return lambda: jax.jit(fn, donate_argnums=(1,))
 
-    def _build_decode(self, bucket):
+    def _build_decode(self, bucket, logits_out=False):
         import jax
         import jax.numpy as jnp
 
+        from ..ops import nn as _opsnn
         from ..ops.pallas_kernels import paged_attention
         from ..ops.random_ops import sample_token_logits
 
-        H, Dh, C = self.num_heads, self.head_dim, self.units
+        desc, lanes, kvh = self.description, self._kv_lanes, self.kv_heads
+        stateful = bool(self.conv_layers)
         scale = 1.0 / math.sqrt(self.head_dim)
 
-        def fn(params, kv, tokens, positions, dest_pages, dest_slots,
-               tables, lengths, temp, top_k, top_p, key):
+        def fn(params, state, tokens, positions, dest_pages, dest_slots,
+               tables, lengths, temp, top_k, top_p, key, seq_slots=None):
+            # seq_slots (b,) int32: each row's state slot; a padding row
+            # names the row past the inert one, so it reads the inert row
+            # and its write drops (stateful models)
             b = tokens.shape[0]
-            x = params["word"][tokens] + params["pos"][positions]  # (b, C)
-            x = _ln(x, params["embed_norm"])
-            new_kv = []
-            for layer, (kp, vp) in zip(params["layers"], kv):
-                qh = _dense(x, layer["q"]).reshape(b, H, Dh)
-                kp = kp.at[dest_pages, dest_slots, :C].set(
-                    _dense(x, layer["k"]).astype(kp.dtype), mode="drop")
-                vp = vp.at[dest_pages, dest_slots, :C].set(
-                    _dense(x, layer["v"]).astype(vp.dtype), mode="drop")
-                new_kv.append((kp, vp))
-                att = paged_attention(qh, kp, vp, tables, lengths,
-                                      sm_scale=scale)
-                att = att.astype(x.dtype).reshape(b, -1)
-                x = _ln(x + _dense(att, layer["o"]), layer["attn_norm"])
-                h = jax.nn.gelu(_dense(x, layer["ffn1"]), approximate=False)
-                x = _ln(x + _dense(h, layer["ffn2"]), layer["ffn_norm"])
-            logits = x @ params["word"].T                        # (b, V)
-            return (sample_token_logits(key, logits, temp, top_k, top_p),
-                    tuple(new_kv))
+            kv, slots = state if stateful else (state, ())
+            x = _lm_embed(desc, params, tokens, positions)       # (b, C)
 
-        # kv donated: the per-step update must alias, not copy, the pool
+            def attention(pages, q, k, v):
+                kp, vp = pages
+                kp = kp.at[dest_pages, dest_slots, :lanes].set(
+                    k.reshape(b, lanes).astype(kp.dtype), mode="drop")
+                vp = vp.at[dest_pages, dest_slots, :lanes].set(
+                    v.reshape(b, lanes).astype(vp.dtype), mode="drop")
+                return paged_attention(q, kp, vp, tables, lengths,
+                                       sm_scale=scale, kv_heads=kvh), (kp, vp)
+
+            def conv(held, r, layer):
+                o, st = _opsnn.gated_short_conv(
+                    r[:, None], layer["in"]["w"], layer["conv"],
+                    layer["out"]["w"], held.at[seq_slots].get(mode="clip"))
+                return o[:, 0], held.at[seq_slots].set(
+                    st.astype(held.dtype), mode="drop")
+
+            x, stats, kv, slots = _lm_layers(desc, params, x, positions,
+                                             lengths > 0, kv, slots,
+                                             attention, conv)
+            new = (kv, slots) if stateful else kv
+            logits = _lm_logits(desc, params, x)                 # (b, V)
+            if logits_out:
+                return logits, new
+            tok = sample_token_logits(key, logits, temp, top_k, top_p)
+            if stats:
+                # the step's expert counts ride out with its tokens: pairs
+                # computed and experts hit, summed over the expert layers,
+                # and the busiest expert's pairs
+                st = jnp.stack(stats)
+                tok = jnp.concatenate([tok, jnp.stack(
+                    [jnp.sum(st[:, 0]), jnp.sum(st[:, 1]),
+                     jnp.max(st[:, 2])]).astype(tok.dtype)])
+            return tok, new
+
+        # state donated: the per-step update must alias, not copy, the pool
         return lambda: jax.jit(fn, donate_argnums=(1,))
 
-    def _prefill_exe(self, lp, example_args=None):
+    def _prefill_exe(self, lp, example_args=None, logits_out=False):
         # example_args routes a miss through the registry's AOT fill, so
         # the donation verifier actually audits the declared KV-pool
         # donation at fill time (misses only; hits never evaluate it)
+        kind = "lm_prefill_logits" if logits_out else "lm_prefill"
         return _compile.get_or_build(
-            self._key("lm_prefill", ("prompt", lp)),
-            self._build_prefill(lp), label="lm_prefill:l%d" % lp,
+            self._key(kind, ("prompt", lp)),
+            self._build_prefill(lp, logits_out), label="%s:l%d" % (kind, lp),
             example_args=example_args)
 
-    def _decode_exe(self, bucket, example_args=None):
+    def _decode_exe(self, bucket, example_args=None, logits_out=False):
+        kind = "lm_decode_logits" if logits_out else "lm_decode"
         return _compile.get_or_build(
-            self._key("lm_decode", ("batch", bucket)),
-            self._build_decode(bucket), label="lm_decode:b%d" % bucket,
-            example_args=example_args)
+            self._key(kind, ("batch", bucket)),
+            self._build_decode(bucket, logits_out),
+            label="%s:b%d" % (kind, bucket), example_args=example_args)
 
     # -- driving -----------------------------------------------------------
-    def prefill(self, tokens, page_row, sampling, key):
-        """Run one prompt through its padded prefill bucket; writes the
-        prompt's K/V into `page_row`'s pages and returns the sampled
-        first token (int)."""
+    def _prefill_args(self, tokens, page_row, sampling, key, slot):
         lp = bucket_for(len(tokens), self.prefill_buckets)
         if lp is None:
             raise MXNetError("prompt of %d tokens overflows the prefill "
@@ -976,24 +1258,81 @@ class TransformerLMEngine:
         padded = _np.zeros(lp, _np.int32)
         padded[:len(tokens)] = tokens
         temp, top_k, top_p = sampling
-        args = (self._params, self._kv, padded,
+        args = (self._params, self._state(), padded,
                 _np.int32(len(tokens)), _np.asarray(page_row, _np.int32),
                 _np.float32([temp]), _np.int32([top_k]),
                 _np.float32([top_p]), key)
-        tok, self._kv = self._prefill_exe(lp, lambda: args)(*args)
+        if self.conv_layers:
+            # no slot named (warm-up): the row past the inert one, dropped
+            args += (_np.int32(self.state_slots + 1 if slot is None
+                               else slot),)
+        return lp, args
+
+    def prefill(self, tokens, page_row, sampling, key, slot=None):
+        """Run one prompt through its padded prefill bucket; writes the
+        prompt's K/V into `page_row`'s pages and its convolution state into
+        state slot `slot`, and returns the sampled first token (int)."""
+        lp, args = self._prefill_args(tokens, page_row, sampling, key, slot)
+        tok, state = self._prefill_exe(lp, lambda: args)(*args)
+        self._set_state(state)
         with _goodput.phase("prefill_wait"):    # blocked on the device
             return int(tok)
 
-    def decode_step(self, tokens, positions, dest_pages, dest_slots,
-                    tables, lengths, temps, top_ks, top_ps, key):
-        """One token for every row (rows with length 0 are inert padding:
-        their K/V writes drop and their sampled token is discarded).
-        Returns an int32 numpy array of next tokens."""
-        args = (self._params, self._kv, tokens, positions, dest_pages,
+    def _decode_args(self, tokens, positions, dest_pages, dest_slots, tables,
+                     lengths, temps, top_ks, top_ps, key, seq_slots):
+        args = (self._params, self._state(), tokens, positions, dest_pages,
                 dest_slots, tables, lengths, temps, top_ks, top_ps, key)
-        out, self._kv = self._decode_exe(len(tokens), lambda: args)(*args)
+        if self.conv_layers:
+            # no slots named (warm-up): the row past the inert one, dropped
+            if seq_slots is None:
+                seq_slots = _np.full(len(tokens), self.state_slots + 1,
+                                     _np.int32)
+            args += (_np.asarray(seq_slots, _np.int32),)
+        return args
+
+    def decode_step(self, tokens, positions, dest_pages, dest_slots,
+                    tables, lengths, temps, top_ks, top_ps, key,
+                    seq_slots=None):
+        """One token for every row (rows with length 0 are inert padding:
+        their K/V and state writes drop and their sampled token is
+        discarded). Returns an int32 numpy array of next tokens; a model
+        with expert layers leaves the step's (pairs, experts hit, busiest
+        expert's pairs) in ``last_moe``."""
+        args = self._decode_args(tokens, positions, dest_pages, dest_slots,
+                                 tables, lengths, temps, top_ks, top_ps, key,
+                                 seq_slots)
+        out, state = self._decode_exe(len(tokens), lambda: args)(*args)
+        self._set_state(state)
         with _goodput.phase("decode_wait"):     # blocked on the device
-            return _np.asarray(out)
+            out = _np.asarray(out)
+        if self.has_experts:
+            self.last_moe = tuple(int(v) for v in out[len(tokens):])
+            out = out[:len(tokens)]
+        return out
+
+    # test-only: the logits both programs sample from, through the same
+    # pages and slots (tests/test_lfm2.py compares them with a reference)
+    def prefill_logits(self, tokens, page_row, slot=None):
+        """(len(tokens), V) float32 logits of a prompt's every position;
+        writes pages and slot as `prefill` does."""
+        lp, args = self._prefill_args(tokens, page_row, (0.0, 0, 1.0),
+                                      _random.next_key(), slot)
+        logits, state = self._prefill_exe(lp, lambda: args, True)(*args)
+        self._set_state(state)
+        return _np.asarray(logits)[:len(tokens)]
+
+    def decode_logits(self, tokens, positions, dest_pages, dest_slots,
+                      tables, lengths, seq_slots=None):
+        """(b, V) float32 logits of one decode step; writes as
+        `decode_step` does."""
+        b = len(tokens)
+        args = self._decode_args(
+            tokens, positions, dest_pages, dest_slots, tables, lengths,
+            _np.zeros(b, _np.float32), _np.zeros(b, _np.int32),
+            _np.ones(b, _np.float32), _random.next_key(), seq_slots)
+        logits, state = self._decode_exe(b, lambda: args, True)(*args)
+        self._set_state(state)
+        return _np.asarray(logits)
 
     def warm(self):
         """Compile every prefill + decode bucket (dummy data, dropped
@@ -1023,10 +1362,67 @@ class TransformerLMEngine:
 # artifact IO — <prefix>-lmconfig.json + <prefix>-lm.params
 # ---------------------------------------------------------------------------
 
+# zoo blocks a generation artifact can hold, by the header's "arch"
+_LM_ARCHS = {
+    "transformer_lm": ("mxnet_tpu.gluon.model_zoo.transformer",
+                       "TransformerLM"),
+    "lfm2": ("mxnet_tpu.gluon.model_zoo.lfm2", "Lfm2LM"),
+}
+# dtypes numpy's .npy cannot name are written as these views of their bits
+_STORED_AS = {"bfloat16": "uint16"}
+
+
+def _lm_arch(lm):
+    for arch, (_, cls) in _LM_ARCHS.items():
+        if type(lm).__name__ == cls:
+            return arch
+    raise MXNetError("%s is no zoo block a generation artifact can hold "
+                     "(have %s)" % (type(lm).__name__, sorted(_LM_ARCHS)))
+
+
+def _write_params(lm, path, dtype):
+    """The parameters as an npz in ``dtype``, one array at a time (the host
+    never holds more than the largest), bfloat16 as its bits."""
+    import zipfile
+
+    import jax
+
+    from ..base import atomic_writer
+
+    view = _STORED_AS.get(dtype)
+    with atomic_writer(path, "wb") as f:
+        with zipfile.ZipFile(f, "w", zipfile.ZIP_STORED,
+                             allowZip64=True) as z:
+            for name, p in lm._collect_params_with_prefix().items():
+                a = p.data().asnumpy().astype(jax.numpy.dtype(dtype),
+                                              copy=False)
+                with z.open(name + ".npy", "w", force_zip64=True) as h:
+                    _np.lib.format.write_array(
+                        h, a.view(view) if view else a, allow_pickle=False)
+
+
+def _adopt_params(lm, path, dtype):
+    """Read an npz written by `_write_params` onto the device one array at
+    a time; each parameter adopts its array (no initializer, no second
+    copy: a model that fills most of the device loads)."""
+    import jax
+
+    view = _STORED_AS.get(dtype)
+    with _np.load(path, allow_pickle=False) as f:
+        for name, p in lm._collect_params_with_prefix().items():
+            if name not in f.files:
+                raise MXNetError("Parameter %s missing in %s" % (name, path))
+            a = f[name]
+            if view:
+                a = a.view(jax.numpy.dtype(dtype))
+            p.adopt(jax.device_put(a))
+
+
 def save_lm(lm, prefix):
-    """Write a generation-serving artifact: the architecture header and
-    the parameters. This is what `tools/serve.py --model name=PREFIX@
-    generate` and replica workers load."""
+    """Write a generation-serving artifact: the header (which zoo block, its
+    constructor arguments, and the per-layer description the engine builds
+    its programs from) and the parameters. This is what `tools/serve.py
+    --model name=PREFIX@generate` and replica workers load."""
     from .. import nd
     from ..base import atomic_writer
 
@@ -1034,15 +1430,23 @@ def save_lm(lm, prefix):
         # deferred Dense/LayerNorm shapes materialize on first forward
         lm(nd.array([[0]], dtype="int32"))
     prefix = os.fspath(prefix)
+    arch = _lm_arch(lm)
+    description = lm.description() if hasattr(lm, "description") \
+        else transformer_lm_description(lm.config)
     with atomic_writer(prefix + "-lmconfig.json", "w") as f:
-        json.dump({"format": _LM_FORMAT, "config": lm.config}, f, indent=1)
-    lm.save_parameters(prefix + "-lm.params")
+        json.dump({"format": _LM_FORMAT, "arch": arch, "config": lm.config,
+                   "description": description}, f, indent=1)
+    if arch == "transformer_lm":
+        lm.save_parameters(prefix + "-lm.params")
+    else:
+        _write_params(lm, prefix + "-lm.params", description["dtype"])
     return prefix
 
 
 def load_lm(prefix):
-    """Rebuild a `TransformerLM` from a `save_lm` artifact."""
-    from ..gluon.model_zoo.transformer import TransformerLM
+    """Rebuild the zoo block of a `save_lm` artifact: the header's "arch"
+    names it (a header without one is a `TransformerLM`'s)."""
+    import importlib
 
     prefix = os.fspath(prefix)
     cfg_path = prefix + "-lmconfig.json"
@@ -1054,8 +1458,17 @@ def load_lm(prefix):
     if header.get("format") != _LM_FORMAT:
         raise MXNetError("%s: unknown LM artifact format %r"
                          % (cfg_path, header.get("format")))
-    lm = TransformerLM(**header["config"])
-    lm.load_parameters(prefix + "-lm.params")
+    arch = header.get("arch", "transformer_lm")
+    if arch not in _LM_ARCHS:
+        raise MXNetError("%s: unknown LM architecture %r (have %s)"
+                         % (cfg_path, arch, sorted(_LM_ARCHS)))
+    module, cls = _LM_ARCHS[arch]
+    lm = getattr(importlib.import_module(module), cls)(**header["config"])
+    if arch == "transformer_lm":
+        lm.load_parameters(prefix + "-lm.params")
+    else:
+        _adopt_params(lm, prefix + "-lm.params",
+                      header["description"]["dtype"])
     return lm
 
 

@@ -10,3 +10,6 @@ from chipbench.tests.test_arithmetic import *  # noqa: F401,F403
 from chipbench.tests.test_laps import *  # noqa: F401,F403
 from chipbench.tests.test_sampler_share import *  # noqa: F401,F403
 from chipbench.tests.test_trace import *  # noqa: F401,F403
+# the LFM2 cell at its tiny sizes (four rehearsals, about a minute), its work
+# functions and its readers
+from chipbench.tests.test_cell_lfm2 import *  # noqa: F401,F403,E402

@@ -138,3 +138,38 @@ def test_mp_loader_abandoned_iteration_no_shm_leak():
     gc.collect()
     after = set(glob.glob("/dev/shm/psm_*"))
     assert after <= before, "leaked shm segments: %s" % (after - before)
+
+
+@pytest.mark.parametrize("used", [False, True],
+                         ids=["workers-still-starting", "workers-idle"])
+def test_mp_loader_pool_ends_under_the_preemption_handler(used):
+    """A process that armed the SIGTERM-with-grace handler (a trainer under
+    the resilience layer) forks its data workers with that handler in
+    place; it only records the request, so the pool's terminate() could
+    never end them and the loader's teardown hung. A worker puts the
+    default action back, and is born with the signal blocked so that a
+    terminate() that comes while it is still starting ends it too."""
+    import signal
+    import threading
+
+    from mxnet_tpu.parallel import resilience
+
+    before = signal.getsignal(signal.SIGTERM)
+    assert resilience.install_preemption_handler()
+    dl = DataLoader(_NpDataset(12), batch_size=4, num_workers=2)
+    pool = dl._get_pool()
+    try:
+        if used:
+            assert sum(1 for _ in dl) == 3
+        ender = threading.Thread(target=pool.terminate, daemon=True)
+        ender.start()
+        ender.join(60)                   # hung here before the fix
+        assert not ender.is_alive(), "pool.terminate() did not return"
+        assert all(p.exitcode is not None for p in pool._pool)
+    finally:
+        for p in pool._pool:             # a failure must not hang the exit
+            if p.exitcode is None:
+                p.kill()
+        if before is not resilience._on_sigterm:
+            signal.signal(signal.SIGTERM, before)
+            resilience._PREEMPT["installed"] = False

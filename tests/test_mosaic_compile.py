@@ -82,6 +82,35 @@ def _paged(b, h, d, n_pages, maxp, ps, dtype):
         ((b,), jnp.int32)]
 
 
+def _paged_gqa(b, h, kv, d, n_pages, maxp, ps, dtype):
+    """Grouped-query: the pool's row is kv*d lanes, h // kv query heads
+    share each KV head."""
+    cp = -(-kv * d // 128) * 128
+    assert pk._paged_kernel_takes(d, ps, cp, dtype, h // kv)
+
+    def fn(q, kp, vp, tables, lengths):
+        return pk.paged_attention(q, kp, vp, tables, lengths, kv_heads=kv)
+
+    return fn, [((b, h, d), dtype), ((n_pages, ps, cp), dtype),
+                ((n_pages, ps, cp), dtype), ((b, maxp), jnp.int32),
+                ((b,), jnp.int32)]
+
+
+def _moe(n, c, f, e, k, dtype):
+    """The drop-free expert layer (routing in XLA, the grouped product a
+    Mosaic kernel) over n rows."""
+    from mxnet_tpu.ops.contrib import moe_tile_rows, sigmoid_topk_moe
+
+    assert pk._moe_kernel_takes(moe_tile_rows(n * k, e), c, f, dtype)
+
+    def fn(x, wg, bias, w1, w3, w2, valid):
+        return sigmoid_topk_moe(x, wg, bias, w1, w3, w2, k=k, valid=valid)
+
+    return fn, [((n, c), dtype), ((e, c), dtype), ((e,), dtype),
+                ((e, f, c), dtype), ((e, f, c), dtype), ((e, f, c), dtype),
+                ((n,), jnp.bool_)]
+
+
 bf16, f32 = jnp.bfloat16, jnp.float32
 
 CASES = {
@@ -111,6 +140,17 @@ CASES = {
     "paged-tiny-f32": lambda: _paged(4, 2, 32, 64, 8, 8, f32),
     # a head wider than a lane tile: the butterfly crosses vregs
     "paged-head256-f32": lambda: _paged(8, 4, 256, 256, 16, 16, f32),
+    # chipbench/configs/lfm2_24b_a2b.json: 32 query heads on 8 KV heads of
+    # 64, a bfloat16 pool row of 512 lanes, the cell's top decode bucket
+    "paged-gqa-lfm2-bf16":
+        lambda: _paged_gqa(128, 32, 8, 64, 8192, 64, 16, bf16),
+    "paged-gqa-tiny-f32": lambda: _paged_gqa(4, 4, 2, 32, 64, 8, 8, f32),
+    # the same configuration's expert layer: 64 experts of 1536, 4 a token;
+    # a decode batch of 128 (tiles of 16 rows) and a prompt bucket of 512
+    # (tiles of 64)
+    "moe-lfm2-decode128-bf16": lambda: _moe(128, 2048, 1536, 64, 4, bf16),
+    "moe-lfm2-prefill512-bf16": lambda: _moe(512, 2048, 1536, 64, 4, bf16),
+    "moe-small-f32": lambda: _moe(32, 256, 256, 8, 2, f32),
 }
 
 
@@ -119,6 +159,7 @@ KERNEL_NAMES = {
               "flash_attention_bwd_dkv"),
     "lstm": ("lstm_layer_fwd", "lstm_layer_bwd"),
     "paged": ("paged_attention_decode",),
+    "moe": ("moe_grouped_ffn",),
 }
 
 
