@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import builtins
 import functools
+import itertools
 import math
 
 import numpy as _np
@@ -238,61 +239,107 @@ def _patches_max(x, kernel, stride, pads):
     return _extract_patches(x, kernel, stride, pads, neg).max(axis=2)
 
 
-def _max_pool_taps_bwd(x, y, g, kernel, stride, pads):
-    """Channels-first maxpool input-grad as a pure elementwise expression.
+def _max_pool_taps_bwd(x, y, g, kernel, stride, pads, ch_last):
+    """Maxpool input-grad in the caller's layout, at the OUTPUT's size.
 
     dx[p] = sum over windows w containing p of [x[p] == y[w]] * g[w].
-    For tap offset a in prod(kernel), the window touching padded position
-    q = w*s + a is read by zero-stuffing y/g onto the padded input grid
-    (lax.pad with interior dilation s-1, offset a). All prod(k) terms are
-    compare/select/adds that XLA fuses into ONE kernel — ~1 read of x and
-    1 write of dx vs the old patches-based vjp, which rebuilt
-    conv_general_dilated_patches in backward (a k^2*C-channel one-hot conv:
-    0.5 TFLOP and ~12 ms/step of the round-4 bs256 ResNet-50 profile for
-    the single stem maxpool).
+    Padded position q = w*s + a (tap offset a) belongs to stride phase
+    q mod s, and within a phase tap a reads y and g unstretched, shifted by
+    a // s windows. So x is viewed as (.., m, s, ..) an axis (a reshape, no
+    copy), each of the prod(stride) phases sums its taps against static
+    slices of y / g (padded once, at the output's size, so that a shift
+    past either edge reads a zero cotangent), and the phases are stacked
+    back and reshaped into dx once. 3x3/s2 pad 1: the phases take 4, 2, 2
+    and 1 taps, nine compare/selects on output-sized arrays. Stride 1 is one
+    phase; stride > kernel leaves whole phases zero. Accumulates in the
+    cotangent's dtype, taps in row-major order, as before.
+
+    Measured on the chip at PR 40 (TPU v5e, forward + backward of the
+    ResNet-50 stem pool alone, bf16[256,112,112,64], device ms a call;
+    PERF.md section 6): this form 6.56; the form it replaced, which
+    zero-stuffed y and g onto the padded input grid once a tap (18
+    `lax.pad`s with interior 1, each written and read back at the input's
+    size, where its docstring promised one fused kernel), 36.71
+    channels-first with transposes around it and 36.65 channels-last (XLA
+    lays 64 channels out batch-minor either way, so the detour itself was
+    free); `select_and_scatter` 1.74. As bf16[256,64,112,112]: 9.62
+    against 41.83 and 4.18. ResNet-50's step fell from 138.3 to 107.1 ms.
 
     Tie semantics: every in-window position equal to the max receives the
     full window cotangent (reference CPU pooling backward behavior,
-    src/operator/nn/pool.h max path), vs the even split jnp.max's vjp gave
-    the old formulation. Ties are measure-zero for float activations."""
+    src/operator/nn/pool.h max path). After a ReLU, and in bfloat16, ties
+    are common, which is why reduce_window's own gradient (one position a
+    window) is a yardstick here and not a candidate."""
     nsp = len(kernel)
-    xshape = x.shape[2:]
-    oshape = y.shape[2:]
-    padded = tuple(xshape[i] + pads[i][0] + pads[i][1] for i in range(nsp))
-    ninf = jnp.asarray(-jnp.inf, x.dtype)
-    xp = jnp.pad(x, ((0, 0), (0, 0)) + tuple(pads))
-    dxp = jnp.zeros_like(xp)
-    import itertools
-    for taps in itertools.product(*[range(k) for k in kernel]):
-        cfg = []
-        ok = True
+    sp0 = 1 if ch_last else 2  # first spatial axis
+    xs = x.shape[sp0:sp0 + nsp]
+    osz = y.shape[sp0:sp0 + nsp]
+    # phases an axis, each m[i] positions long (x zero-extended to m*s)
+    m = [-(-xs[i] // stride[i]) for i in range(nsp)]
+    # taps[i][c]: for the phase of unpadded positions p = j*s + c, the
+    # window index that position j = 0 reads through each of its taps
+    taps = []
+    for i in range(nsp):
+        s, lo = stride[i], pads[i][0]
+        taps.append([[(c + lo) // s - a // s
+                      for a in range((c + lo) % s, kernel[i], s)]
+                     for c in range(s)])
+    # y and g padded once, so that every tap is a static slice of them and
+    # a window index outside the output reads a zero cotangent
+    ylo = [max([0] + [-w for ws in taps[i] for w in ws]) for i in range(nsp)]
+    cfg = [(0, 0, 0)] * x.ndim
+    for i in range(nsp):
+        hi = max([0] + [w + m[i] - osz[i] for ws in taps[i] for w in ws])
+        cfg[sp0 + i] = (ylo[i], hi, 0)
+    zero = jnp.zeros((), g.dtype)
+    yp = lax.pad(y, jnp.asarray(-jnp.inf, y.dtype), cfg)
+    gp = lax.pad(g, zero, cfg)
+    # x viewed as (.., m_i, s_i, ..): a phase is an index into the s axes
+    ext = [(0, 0, 0)] * x.ndim
+    view = list(x.shape[:sp0])
+    for i in range(nsp):
+        ext[sp0 + i] = (0, m[i] * stride[i] - xs[i], 0)
+        view += [m[i], stride[i]]
+    view += list(x.shape[sp0 + nsp:])
+    xv = lax.pad(x, jnp.zeros((), x.dtype), ext).reshape(view)
+
+    def phase(cls):
+        idx = [slice(None)] * len(view)
         for i in range(nsp):
-            hi = padded[i] - taps[i] - ((oshape[i] - 1) * stride[i] + 1)
-            if hi < 0:  # tap runs past the padded edge for every window
-                ok = False
-                break
-            cfg.append((taps[i], hi, stride[i] - 1))
-        if not ok:
-            continue
-        cfg = ((0, 0, 0), (0, 0, 0)) + tuple(cfg)
-        up_y = lax.pad(y, ninf, cfg)
-        up_g = lax.pad(g, jnp.zeros((), g.dtype), cfg)
-        dxp = dxp + jnp.where(xp == up_y, up_g, jnp.zeros((), g.dtype))
-    sl = (slice(None), slice(None)) + tuple(
-        slice(pads[i][0], pads[i][0] + xshape[i]) for i in range(nsp))
-    return dxp[sl]
+            idx[sp0 + 2 * i + 1] = cls[i]
+        xc = xv[tuple(idx)]
+        acc = jnp.zeros(xc.shape, g.dtype)
+        for w0 in itertools.product(*[taps[i][cls[i]] for i in range(nsp)]):
+            sl = [slice(None)] * x.ndim
+            for i in range(nsp):
+                sl[sp0 + i] = slice(w0[i] + ylo[i], w0[i] + ylo[i] + m[i])
+            acc = acc + jnp.where(xc == yp[tuple(sl)], gp[tuple(sl)], zero)
+        return acc
+
+    def interleaved(axis, cls):
+        if axis == nsp:
+            return phase(cls)
+        # deeper axes are already (m, s) pairs, shallower ones still m
+        return jnp.stack([interleaved(axis + 1, cls + (c,))
+                          for c in range(stride[axis])], axis=sp0 + axis + 1)
+
+    dx = interleaved(0, ()).reshape(
+        [d + e[1] for d, e in zip(x.shape, ext)])
+    return dx[tuple(slice(0, d) for d in x.shape)]
 
 
 @functools.lru_cache(maxsize=None)
 def _float_max_pool(kernel, stride, pads, ch_last=False):
     """Float max pooling: cheap `lax.reduce_window` forward, custom
-    backward (reduce_window(max)'s own grad lowers to TPU SelectAndScatter,
-    which serializes; the tap-mask expression below stays elementwise)."""
+    backward in the layout it is called in. reduce_window(max)'s own grad
+    (TPU SelectAndScatter, 1.74 ms at the ResNet-50 stem's shape where the
+    tap form takes 6.56) credits one position a window where the reference
+    credits every tie, so it cannot ship; `_max_pool_taps_bwd` has what
+    each form measured on the chip."""
     window, strides, padding = _pool_window(kernel, stride, pads, ch_last)
 
     nsp = len(kernel)
-    to_ncfirst = _to_ncfirst_perm(nsp + 2)
-    to_chlast = _to_chlast_perm(nsp + 2)
+    sp0 = 1 if ch_last else 2
 
     @jax.custom_vjp
     def mp(x):
@@ -305,34 +352,34 @@ def _float_max_pool(kernel, stride, pads, ch_last=False):
 
     def bwd(res, g):
         x, y = res
-        if ch_last:
-            x = jnp.transpose(x, to_ncfirst)
-            y = jnp.transpose(y, to_ncfirst)
-            g = jnp.transpose(g, to_ncfirst)
-        out_sp = y.shape[2:]
         covers = all(
-            kernel[i] >= x.shape[2 + i] + pads[i][0] + pads[i][1]
+            kernel[i] >= x.shape[sp0 + i] + pads[i][0] + pads[i][1]
             for i in range(nsp))
-        if all(o == 1 for o in out_sp) and covers:
+        if covers:
             # single window COVERING the padded input (global pool): one
             # broadcast compare. The coverage check matters: a 2x2/s2
             # window on a 3x3 input also has 1x1 output but never reads
             # the last row/col, which must not receive gradient.
             dx = jnp.where(x == y, g, jnp.zeros((), g.dtype))
         elif int(_np.prod(kernel)) <= 32:
-            dx = _max_pool_taps_bwd(x, y, g, kernel, stride, pads)
+            dx = _max_pool_taps_bwd(x, y, g, kernel, stride, pads, ch_last)
         else:
             # large overlapping kernels (rare): patches-based fallback,
             # with the same full-credit tie semantics as the taps path
             # (explicit equality mask instead of jnp.max's even-split vjp;
-            # the patch extraction itself is linear, so only it is vjp'd)
+            # the patch extraction itself is linear, so only it is vjp'd).
+            # _extract_patches is channels-first; no cell runs this branch,
+            # so a channels-last caller keeps its transposes here.
+            if ch_last:
+                x, y, g = (jnp.transpose(t, _to_ncfirst_perm(nsp + 2))
+                           for t in (x, y, g))
             patches, pull = jax.vjp(
                 lambda t: _extract_patches(t, kernel, stride, pads, 0), x)
             mask = patches == y[:, :, None]
             dx = pull(jnp.where(mask, g[:, :, None],
                                 jnp.zeros((), g.dtype)))[0]
-        if ch_last:
-            dx = jnp.transpose(dx, to_chlast)
+            if ch_last:
+                dx = jnp.transpose(dx, _to_chlast_perm(nsp + 2))
         return (dx,)
 
     mp.defvjp(fwd, bwd)

@@ -5,6 +5,8 @@ Reference semantics anchors: src/operator/nn/pool.h (max pool backward
 gives every tied in-window maximum the full window cotangent),
 src/operator/nn/batch_norm.cc (train stats + affine, frozen path).
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -26,18 +28,35 @@ def _ref_pool(x, kernel, stride, pads, shape, ch_last):
     return out
 
 
-@pytest.mark.parametrize("kernel,stride,pads,shape,ch_last", [
-    ((3, 3), (2, 2), ((1, 1), (1, 1)), (2, 3, 11, 11), False),  # stem config
-    ((3, 3), (2, 2), ((1, 2), (1, 2)), (2, 3, 10, 10), False),  # full conv.
-    ((2,), (2,), ((0, 0),), (2, 3, 12), False),                  # 1D
-    ((2, 2, 2), (2, 2, 2), ((0, 0),) * 3, (1, 2, 6, 6, 6), False),  # 3D
-    ((3, 3), (2, 2), ((1, 1), (1, 1)), (2, 11, 11, 3), True),    # NHWC
-    ((7, 7), (3, 3), ((0, 0), (0, 0)), (2, 3, 20, 20), False),   # >32 taps
+def _chlast_shape(shape):
+    return (shape[0],) + tuple(shape[2:]) + (shape[1],)
+
+
+# channels-first shapes; every case also runs as its channels-last twin
+_POOL_CASES = [
+    ((3, 3), (2, 2), ((1, 1), (1, 1)), (2, 3, 11, 11)),   # stem config, odd
+    ((3, 3), (2, 2), ((1, 1), (1, 1)), (2, 3, 12, 12)),   # stem config, even
+    ((3, 3), (2, 2), ((1, 2), (1, 2)), (2, 3, 10, 10)),   # full convention
+    ((2,), (2,), ((0, 0),), (2, 3, 12)),                  # 1D
+    ((2, 2, 2), (2, 2, 2), ((0, 0),) * 3, (1, 2, 6, 6, 6)),  # 3D
+    ((7, 7), (3, 3), ((0, 0), (0, 0)), (2, 3, 20, 20)),   # >32 taps
     # 1x1 output whose window does NOT cover the input: the last row/col
     # is never read by forward and must get zero gradient (round-4 review)
-    ((2, 2), (2, 2), ((0, 0), (0, 0)), (2, 3, 3, 3), False),
-])
+    ((2, 2), (2, 2), ((0, 0), (0, 0)), (2, 3, 3, 3)),
+    ((3, 3), (1, 1), ((1, 1), (1, 1)), (2, 3, 7, 7)),     # s1: one phase
+    # stride over kernel: rows between windows are never read, get zero
+    ((2, 2), (3, 3), ((0, 0), (0, 0)), (2, 3, 11, 10)),
+    ((3, 3), (2, 2), ((0, 0), (0, 0)), (2, 3, 9, 9)),     # Inception, odd
+    ((3, 3), (2, 2), ((0, 0), (0, 0)), (2, 3, 10, 10)),   # Inception, even
+    ((3, 2), (2, 3), ((1, 0), (0, 2)), (2, 3, 9, 8)),     # mixed per axis
+]
+
+
+@pytest.mark.parametrize("ch_last", [False, True])
+@pytest.mark.parametrize("kernel,stride,pads,shape", _POOL_CASES)
 def test_max_pool_bwd_matches_patches(kernel, stride, pads, shape, ch_last):
+    if ch_last:
+        shape = _chlast_shape(shape)
     rng = np.random.RandomState(0)
     x = jnp.array(rng.randn(*shape).astype(np.float32))
     mp = _float_max_pool(kernel, stride, pads, ch_last)
@@ -48,22 +67,82 @@ def test_max_pool_bwd_matches_patches(kernel, stride, pads, shape, ch_last):
     dx = jax.grad(lambda t: jnp.vdot(mp(t), ct))(x)
     dx_ref = jax.grad(lambda t: jnp.vdot(
         _ref_pool(t, kernel, stride, pads, shape, ch_last), ct))(x)
+    assert dx.shape == x.shape
     assert np.abs(np.asarray(dx) - np.asarray(dx_ref)).max() < 1e-6
 
 
+@pytest.mark.parametrize("ch_last", [False, True])
+def test_max_pool_bwd_bf16_matches_f32(ch_last):
+    """bfloat16 stays bfloat16 and agrees with the float32 gradient of the
+    same (bfloat16-representable, tie-rich) input at bfloat16's tolerance."""
+    kernel, stride, pads = (3, 3), (2, 2), ((1, 1), (1, 1))
+    shape = (2, 12, 12, 4) if ch_last else (2, 4, 12, 12)
+    rng = np.random.RandomState(1)
+    # a few levels after a ReLU: most windows hold their maximum twice
+    x16 = jnp.maximum(jnp.round(jnp.array(rng.randn(*shape), jnp.bfloat16)), 0)
+    mp = _float_max_pool(kernel, stride, pads, ch_last)
+    ct16 = jnp.array(rng.randn(*mp(x16).shape), jnp.bfloat16)
+
+    def grad(x, ct):
+        return jax.grad(lambda t: jnp.vdot(mp(t), ct))(x)
+
+    dx16 = grad(x16, ct16)
+    assert dx16.dtype == jnp.bfloat16
+    dx32 = np.asarray(grad(x16.astype(jnp.float32), ct16.astype(jnp.float32)))
+    assert np.count_nonzero(dx32) > ct16.size  # more than one a window: ties
+    err = np.abs(np.asarray(dx16.astype(jnp.float32)) - dx32).max()
+    assert err <= 2 ** -6 * np.abs(dx32).max()
+
+
+@pytest.mark.parametrize("ch_last", [False, True])
 @pytest.mark.parametrize("kernel,stride,shape", [
     ((2, 2), (2, 2), (1, 1, 4, 4)),      # taps branch
     ((7, 7), (7, 7), (1, 1, 14, 14)),    # patches-fallback branch
     ((4, 4), (4, 4), (1, 1, 4, 4)),      # covering/global branch
 ])
-def test_max_pool_tie_semantics_full_credit(kernel, stride, shape):
+def test_max_pool_tie_semantics_full_credit(kernel, stride, shape, ch_last):
     """Every tied maximum receives the full window cotangent (pool.h),
-    identically in all three backward branches."""
+    identically in all three backward branches and both layouts."""
     pads = ((0, 0), (0, 0))
+    if ch_last:
+        shape = _chlast_shape(shape)
     x = jnp.ones(shape, jnp.float32)
-    mp = _float_max_pool(kernel, stride, pads, False)
+    mp = _float_max_pool(kernel, stride, pads, ch_last)
     dx = jax.grad(lambda t: mp(t).sum())(x)
     assert np.allclose(np.asarray(dx), 1.0)
+
+
+@pytest.mark.parametrize("ch_last", [False, True])
+def test_max_pool_overlapping_ties_sum_windows(ch_last):
+    """3x3/s2 pad 1 on a constant input: a position is credited once by
+    every window that holds it (1, 2 or 4 of them), the phases' tap counts."""
+    shape = (1, 6, 6, 1) if ch_last else (1, 1, 6, 6)
+    mp = _float_max_pool((3, 3), (2, 2), ((1, 1), (1, 1)), ch_last)
+    dx = jax.grad(lambda t: mp(t).sum())(jnp.ones(shape, jnp.float32))
+    per_axis = np.array([1, 2, 1, 2, 1, 1])  # last row: no window below it
+    assert np.array_equal(np.asarray(dx).reshape(6, 6),
+                          np.outer(per_axis, per_axis))
+
+
+@pytest.mark.parametrize("ch_last", [False, True])
+def test_max_pool_bwd_lowering_keeps_layout(ch_last):
+    """The backward computes in the layout it is given, at the output's
+    size: the lowered gradient of the stem pool holds no transpose and no
+    interior (zero-stuffing) pad of an activation (ISSUE 40)."""
+    shape = (2, 12, 12, 8) if ch_last else (2, 8, 12, 12)
+    mp = _float_max_pool((3, 3), (2, 2), ((1, 1), (1, 1)), ch_last)
+    text = jax.jit(jax.grad(lambda t: mp(t).astype(jnp.float32).sum())).lower(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16)).as_text()
+    assert "transpose" not in text
+    pads = re.findall(r"stablehlo\.pad.*", text)
+    assert pads  # y and g are padded once each, at the edges only
+    for line in pads:
+        interior = re.search(r"interior = (?:array<i64: ([^>]*)>|\[([^\]]*)\])",
+                             line)
+        assert interior, line
+        vals = [int(v) for v in (interior.group(1) or interior.group(2)
+                                 ).split(",")]
+        assert not any(vals), line
 
 
 def _plain_bn(x, g, b, fix_gamma, axis=1, eps=1e-3):
