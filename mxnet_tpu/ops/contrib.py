@@ -771,7 +771,8 @@ def moe_tile_rows(pairs, experts_held):
           aliases=("sigmoid_topk_moe",))
 def sigmoid_topk_moe(data, gate_weight, expert_bias, w1, w3, w2, k=4,
                      expert_offset=0, valid=None, routed_scaling_factor=1.0,
-                     norm_topk_prob=True):
+                     norm_topk_prob=True, n_group=1, topk_group=1,
+                     gate_eps=1e-6):
     """Drop-free top-k expert layer with a sigmoid router.
 
     data (..., C); gate_weight (E, C) and expert_bias (E,) over ALL experts;
@@ -779,8 +780,12 @@ def sigmoid_topk_moe(data, gate_weight, expert_bias, w1, w3, w2, k=4,
     expert_offset + E_held`` that this holder computes. Scores s =
     sigmoid(x Wg) in float32; the k experts of a token are the top k of
     s + expert_bias (the bias selects, it does not weigh); gates g = s / (sum
-    of the selected s + 1e-6) when ``norm_topk_prob``, times
-    ``routed_scaling_factor``. Every (token, expert) pair whose expert is
+    of the selected s + ``gate_eps``) when ``norm_topk_prob``, times
+    ``routed_scaling_factor``. With ``n_group`` > 1 the selection is
+    group-limited: the experts lie in ``n_group`` groups of consecutive
+    indices, a group scores the sum of its two largest biased scores, the
+    ``topk_group`` best groups are kept and the biased scores of the others
+    are set to 0 before the top k. Every (token, expert) pair whose expert is
     held is computed: rows are laid out expert by expert in tiles of
     `moe_tile_rows` and go through one grouped product
     (`pallas_kernels.moe_grouped_ffn`); there is no capacity and nobody is
@@ -802,10 +807,18 @@ def sigmoid_topk_moe(data, gate_weight, expert_bias, w1, w3, w2, k=4,
     with jax.named_scope("mxtpu.lm.moe.route"):
         scores = jax.nn.sigmoid(jnp.einsum(
             "nc,ec->ne", x, gate_weight, preferred_element_type=jnp.float32))
-        _, experts = lax.top_k(scores + expert_bias.astype(jnp.float32), k)
+        biased = scores + expert_bias.astype(jnp.float32)
+        if n_group > 1:
+            per = biased.reshape(n, n_group, -1)
+            _, keep = lax.top_k(jnp.sum(lax.top_k(per, 2)[0], axis=-1),
+                                topk_group)                       # (n, kept)
+            kept = jnp.any(keep[:, :, None] == jnp.arange(n_group), axis=1)
+            biased = jnp.where(kept[:, :, None], per, 0.0).reshape(n, -1)
+        _, experts = lax.top_k(biased, k)
         gates = jnp.take_along_axis(scores, experts, axis=1)      # (n, k)
         if norm_topk_prob:
-            gates = gates / (jnp.sum(gates, axis=1, keepdims=True) + 1e-6)
+            gates = gates / (jnp.sum(gates, axis=1, keepdims=True)
+                             + gate_eps)
         gates = gates * routed_scaling_factor
 
         local = experts - expert_offset

@@ -1081,22 +1081,78 @@ def rms_norm(data, gamma, axis=-1, eps=1e-5):
             * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
+def yarn_mscale(factor, mscale):
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1 past a
+    factor of 1. A latent-attention model multiplies its softmax scale by
+    the square of this (at ``mscale_all_dim``)."""
+    if factor <= 1 or not mscale:
+        return 1.0
+    return 0.1 * float(mscale) * math.log(float(factor)) + 1.0
+
+
+def yarn_args(scaling):
+    """`rope`'s ``yarn`` attribute from a ``rope_scaling`` group (factor,
+    original_max_position_embeddings, beta_fast, beta_slow); None where the
+    positions are not scaled."""
+    if not scaling or scaling["factor"] <= 1:
+        return None
+    return (scaling["factor"], scaling["original_max_position_embeddings"],
+            scaling["beta_fast"], scaling["beta_slow"])
+
+
+def latent_softmax_scale(nope, rope_lanes, scaling):
+    """A latent-attention model's softmax scale: (nope + rope)^-0.5 times
+    YaRN's mscale (at ``mscale_all_dim``) squared."""
+    m = yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]) \
+        if scaling else 1.0
+    return (nope + rope_lanes) ** -0.5 * m ** 2
+
+
+def yarn_inv_freq(dim, theta, factor, original, beta_fast=32, beta_slow=1):
+    """The ``dim // 2`` rotary frequencies under YaRN scaling (a numpy
+    float32 array): pair i's theta^(-2i/dim) is kept where the pair turns
+    more than ``beta_fast`` times over ``original`` positions, divided by
+    ``factor`` where it turns fewer than ``beta_slow`` times, and ramps
+    linearly in i between the two correction dims."""
+    half = dim // 2
+    inv = float(theta) ** (-_np.arange(half, dtype=_np.float64) * 2.0 / dim)
+
+    def correction_dim(rotations):
+        return dim * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = _np.clip((_np.arange(half) - low) / (high - low), 0.0, 1.0)
+    return (inv / factor * ramp + inv * (1 - ramp)).astype(_np.float32)
+
+
 @register("_contrib_rope", aliases=("rope",))
-def rope(data, positions, theta=10000.0):
-    """Rotary position embedding over the whole head, rotate-half form:
-    data (..., H, D); positions broadcastable to data.shape[:-2]. Lane i of
-    the first half pairs with lane i + D/2; the angle of pair i at position p
-    is p * theta^(-2i/D). Computed in float32."""
-    d = data.shape[-1]
+def rope(data, positions, theta=10000.0, start=0, yarn=None):
+    """Rotary position embedding, rotate-half form: data (..., H, D);
+    positions broadcastable to data.shape[:-2]. The lanes from ``start`` on
+    are rotated (0: the whole head) and the ones before pass through; with R
+    lanes rotated, lane i of their first half pairs with lane i + R/2, and
+    the angle of pair i at position p is p * theta^(-2i/R), or p times the
+    YaRN frequency (`yarn_inv_freq`) when ``yarn`` = (factor, original
+    length, beta_fast, beta_slow) is given. Computed in float32."""
+    d = data.shape[-1] - start
     half = d // 2
-    inv = jnp.asarray(float(theta), jnp.float32) ** (
-        -jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
+    if yarn:
+        inv = jnp.asarray(yarn_inv_freq(d, float(theta), *yarn))
+    else:
+        inv = jnp.asarray(float(theta), jnp.float32) ** (
+            -jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
     ang = jnp.asarray(positions, jnp.float32)[..., None, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x = data.astype(jnp.float32)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(data.dtype)
+    x1, x2 = x[..., start:start + half], x[..., start + half:]
+    return jnp.concatenate(
+        ([x[..., :start]] if start else [])
+        + [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+        axis=-1).astype(data.dtype)
 
 
 def matmul_nt(x, w, dtype=None):
@@ -1153,19 +1209,31 @@ def gated_short_conv(r, w_in, w_conv, w_out, state=None, length=None):
 
 
 @register("_contrib_causal_attention", aliases=("causal_attention",))
-def causal_attention(q, k, v, sm_scale=None):
+def causal_attention(q, k, v, sm_scale=None, block=None):
     """Causal softmax attention of a whole sequence, grouped-query: q (...,
-    L, H, D); k, v (..., L, KV, D) with H a multiple of KV; query head i
-    reads KV head i // (H // KV). Scores and softmax in float32."""
+    L, H, D); k (..., L, KV, D), v (..., L, KV, Dv) with H a multiple of KV;
+    query head i reads KV head i // (H // KV). Scores and softmax in
+    float32. With ``block`` and more than ``block`` positions the queries
+    go ``block`` rows at a time, each against the keys up to its own last
+    row: the same numbers, and no (H, L, L) array."""
     l, h, d = q.shape[-3:]
-    kv = k.shape[-2]
+    kv, dv = k.shape[-2], v.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    qg = q.reshape(q.shape[:-2] + (kv, h // kv, d))
-    s = jnp.einsum("...qkgd,...lkd->...kgql", qg, k,
-                   preferred_element_type=jnp.float32) * sm_scale
-    causal = jnp.arange(l)[None, :] <= jnp.arange(l)[:, None]
-    p = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
-    o = jnp.einsum("...kgql,...lkd->...qkgd", p.astype(v.dtype), v,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(q.shape).astype(q.dtype)
+    outs = []
+    step = l if not block or block >= l else block
+    for q0 in range(0, l, step):
+        q1 = min(l, q0 + step)
+        # the whole sequence in one block is the plain form, unsliced
+        qb = q if step == l else q[..., q0:q1, :, :]
+        kb, vb = (k, v) if q1 == l else (k[..., :q1, :, :], v[..., :q1, :, :])
+        qg = qb.reshape(q.shape[:-3] + (q1 - q0, kv, h // kv, d))
+        s = jnp.einsum("...qkgd,...lkd->...kgql", qg, kb,
+                       preferred_element_type=jnp.float32) * sm_scale
+        causal = jnp.arange(q1)[None, :] <= jnp.arange(q0, q1)[:, None]
+        p = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
+        o = jnp.einsum("...kgql,...lkd->...qkgd", p.astype(v.dtype), vb,
+                       preferred_element_type=jnp.float32)
+        outs.append(o.reshape(q.shape[:-3] + (q1 - q0, h, dv)))
+    o = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-3)
+    return o.astype(q.dtype)

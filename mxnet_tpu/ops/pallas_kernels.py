@@ -25,7 +25,8 @@ import functools
 import numpy as _np
 
 __all__ = ["flash_attention", "lstm_layer", "paged_attention",
-           "paged_attention_reference", "moe_grouped_ffn",
+           "paged_attention_reference", "paged_latent_attention",
+           "paged_latent_attention_reference", "moe_grouped_ffn",
            "moe_grouped_ffn_reference"]
 
 _NEG_INF = -1e30
@@ -1017,6 +1018,199 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths,
                rows, k_pages, v_pages, *extra)
     return out[:, :, :kv * d].reshape(b, group, kv, d) \
         .transpose(0, 2, 1, 3).reshape(b, h, d)
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) decode attention over a paged pool of compressed rows.
+#
+# A latent layer caches ONE row a token for all heads: the compressed KV
+# (`rank` lanes) and the shared rotary key beside it, padded to whole lane
+# tiles once at allocation: (pages, page_size, Cp). Decode absorbs the
+# up-projection into the query and the output, so every head's key is the
+# whole row and every head's value its first `rank` lanes: a page is read
+# ONCE from HBM and serves two MXU products, (H, Cp) x (Cp, page) for the
+# scores of all heads and (H, page) x (page, Cp) for their outputs (the lanes
+# past `rank` of that product are dropped by the caller: slicing the page in
+# VMEM would need `rank` on a lane tile).
+#
+# Grid (B, blocks of `per_step` pages): `paged_attention_decode`'s static
+# grid costs 0.23 us a step whatever it does (PERF.md section 5), so a step
+# takes several pages, each through a BlockSpec of its own on the one pool,
+# and a block past a sequence's length names the sequence's last live page
+# again: nothing is fetched for it and its arithmetic is skipped.
+# ---------------------------------------------------------------------------
+
+
+def paged_latent_attention_reference(q, pages, page_tables, lengths,
+                                     sm_scale, rank):
+    """Dense-gather oracle (and fallback) of `paged_latent_attention`: q (B,
+    H, R) with R <= Cp the row's live lanes (compressed KV then rotary key);
+    pages (P, page_size, Cp); every head's key is the cached row's first R
+    lanes, its value the first ``rank``. Returns (B, H, rank). Rows with
+    length 0 return zeros. Both contractions at HIGHEST precision."""
+    import jax
+    import jax.numpy as jnp
+
+    hi = jax.lax.Precision.HIGHEST
+    b, h, r = q.shape
+    ps = pages.shape[1]
+    maxp = page_tables.shape[1]
+    rows = pages[page_tables].reshape(b, maxp * ps, -1).astype(jnp.float32)
+    s = jnp.einsum("bhr,blr->bhl", q.astype(jnp.float32), rows[..., :r],
+                   precision=hi) * sm_scale
+    live = jnp.arange(maxp * ps)[None, None, :] < lengths[:, None, None]
+    s = jnp.where(live, s, _NEG_INF)
+    p = jnp.where(live, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    o = jnp.einsum("bhl,blr->bhr", p, rows[..., :rank], precision=hi)
+    return o.astype(q.dtype)
+
+
+def _latent_kernel(tbl_ref, len_ref, q_ref, *rest, sm_scale, ps, per_step,
+                   n_blocks):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    page_refs = rest[:per_step]
+    o_ref, m_scr, l_scr, acc_scr = rest[per_step:]
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    length = len_ref[b]
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    for g in range(per_step):
+        first = (j * per_step + g) * ps
+
+        @pl.when(first < length)       # a page past the length adds nothing
+        def _(g=g, first=first):
+            q = q_ref[0]                                         # (H, Cp)
+            page = page_refs[g][0]                               # (ps, Cp)
+            s = jax.lax.dot_general(
+                q, page, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale   # (H, ps)
+            col = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(col < length, s, _NEG_INF)
+            m = m_scr[:, 0:1]
+            new_m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - new_m)
+            p = jnp.exp(s - new_m)
+            l = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+                p.astype(page.dtype), page,
+                preferred_element_type=jnp.float32)              # (H, Cp)
+            m_scr[...] = jnp.broadcast_to(new_m, m_scr.shape)
+            l_scr[...] = jnp.broadcast_to(l, l_scr.shape)
+
+    @pl.when(j == n_blocks - 1)
+    def _():
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, 0:1], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def latent_pages_per_step(ps, maxp):
+    """Pages a grid step of the latent kernel takes: about 256 tokens'
+    worth, at most 8 and at most a sequence's pages."""
+    return max(1, min(8, 256 // ps, maxp))
+
+
+def _latent_kernel_takes(ps, cp, pool_dtype):
+    """A page must be whole sublane tiles of the pool's dtype and its rows
+    whole 128-lane tiles."""
+    sublanes = 32 // _np.dtype(pool_dtype).itemsize
+    return ps % sublanes == 0 and cp % 128 == 0
+
+
+@functools.lru_cache(maxsize=64)
+def _latent_compiled(key):
+    (b, h, cp, maxp, ps, dtype, sm_scale, interpret, per_step) = key
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_blocks = -(-maxp // per_step)
+
+    def page(g):
+        def index(bb, j, tbl, lens):
+            # past the sequence's last live page: that page again, so the
+            # pipeline fetches nothing new
+            last = jnp.maximum((lens[bb] + ps - 1) // ps - 1, 0)
+            return (tbl[bb, jnp.minimum(j * per_step + g, last)], 0, 0)
+
+        return index
+
+    def row(bb, j, tbl, lens):
+        return (bb, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,          # page_tables, lengths (SMEM)
+        grid=(b, n_blocks),
+        in_specs=[pl.BlockSpec((1, h, cp), row, memory_space=pltpu.VMEM)]
+        + [pl.BlockSpec((1, ps, cp), page(g), memory_space=pltpu.VMEM)
+           for g in range(per_step)],
+        out_specs=pl.BlockSpec((1, h, cp), row, memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((h, 128), jnp.float32),     # m
+                        pltpu.VMEM((h, 128), jnp.float32),     # l
+                        pltpu.VMEM((h, cp), jnp.float32)],     # acc
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, sm_scale=sm_scale, ps=ps,
+                          per_step=per_step, n_blocks=n_blocks),
+        name="paged_latent_attention_decode",
+        out_shape=jax.ShapeDtypeStruct((b, h, cp), _np.dtype(dtype)),
+        grid_spec=grid_spec,
+        interpret=interpret,
+    )
+
+
+def paged_latent_attention(q, pages, page_tables, lengths, sm_scale, rank):
+    """Decode attention of a latent (MLA) layer in its absorbed form: one
+    query token a sequence against the paged pool of compressed rows
+    (docs/serving.md section Generation).
+
+    q (B, H, R): every head's query against the cached row, the key
+    up-projection already folded in (R = rank + rotary lanes). pages (P,
+    page_size, Cp): row t of a sequence at ``pages[page_tables[b, t //
+    page_size], t % page_size]``, lanes [0, rank) the compressed KV, [rank,
+    R) the rotary key, the rest the allocation's padding (zeros). Returns
+    (B, H, rank): softmax(q . row * sm_scale) over the live rows times their
+    first ``rank`` lanes, which the caller takes through the value
+    up-projection. ``page_tables`` entries past a sequence's pages must be
+    valid indices; ``lengths`` 0 disables a padding row (zeros out).
+
+    A page that is not whole sublane tiles of the pool's dtype, or rows off
+    the lane tile, go to `paged_latent_attention_reference`; the gate is
+    MXTPU_PALLAS_DECODE, as for `paged_attention`."""
+    import jax.numpy as jnp
+
+    from .. import env as _env
+
+    sm_scale = float(sm_scale)
+    b, h, r = q.shape
+    _, ps, cp = pages.shape
+    if not rank <= r <= cp:
+        raise ValueError("a query of %d lanes over rows of %d with %d "
+                         "compressed lanes" % (r, cp, rank))
+    gate = (_env.raw("MXTPU_PALLAS_DECODE") or "auto").strip().lower()
+    interpret = _use_interpret()
+    if (gate == "0" or (gate == "auto" and interpret)
+            or not _latent_kernel_takes(ps, cp, pages.dtype)):
+        return paged_latent_attention_reference(q, pages, page_tables,
+                                                lengths, sm_scale, rank)
+    maxp = page_tables.shape[1]
+    per_step = latent_pages_per_step(ps, maxp)
+    call = _latent_compiled((b, h, cp, maxp, ps, str(q.dtype), sm_scale,
+                             interpret, per_step))
+    rows = jnp.pad(q.astype(pages.dtype), ((0, 0), (0, 0), (0, cp - r)))
+    out = call(page_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+               rows, *([pages] * per_step))
+    return out[:, :, :rank].astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

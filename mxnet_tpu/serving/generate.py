@@ -86,6 +86,10 @@ __all__ = ["KVPageAllocator", "StateSlotPool", "GenRequest", "GenerateScheduler"
 _LOG = logging.getLogger("mxnet_tpu.serving.generate")
 
 _LM_FORMAT = "mxtpu-lm-v1"
+# query rows of one block of a latent layer's prefill attention: 64 heads x
+# 256 rows x 4096 keys of float32 scores are 268 MB, where the whole
+# (H, L, L) would be 4.3 GB
+_PREFILL_Q_BLOCK = 256
 
 
 # ---------------------------------------------------------------------------
@@ -652,6 +656,11 @@ class GenerateScheduler:
                 continue
             lap["prefills"] += 1
             lap["prefill_tokens"] += len(req.tokens)
+            # as `last_moe` below: a stub engine of the tests has neither
+            pairs = getattr(self.engine, "last_prefill_moe_pairs", None)
+            if pairs is not None:
+                lap["prefill_moe_pairs"] = lap.get("prefill_moe_pairs", 0) \
+                    + pairs
             self._m_prefill.observe(prefill.elapsed, exemplar=exemplar)
             if trace is not None and trace.recorded:
                 t0_wall = time.time() - (time.perf_counter() - prefill.t0)
@@ -844,7 +853,9 @@ def _lm_layers(desc, params, x, positions, valid, kv, slots, attention, conv):
     ``positions`` (n,): the block both of the engine's programs run. What
     differs between them is where an operator keeps its state, so the two
     stateful steps are handed in: ``attention((K, V) pages of the layer, q,
-    k, v) -> (attended, new pages)`` (store k and v, attend) and
+    k, v) -> (attended, new pages)`` (store k and v, attend), or for a
+    latent model ``attention(pages of the layer, q, row, layer)`` (store the
+    compressed row, attend through ``kv_b``: `_lm_latent`), and
     ``conv(slots of the layer, r, layer) -> (o, new slots)`` (the gated
     short convolution over its slot); ``kv`` and ``slots`` hold one entry an
     attention / convolution layer. Rows with ``valid`` false are a bucket's
@@ -857,11 +868,17 @@ def _lm_layers(desc, params, x, positions, valid, kv, slots, attention, conv):
 
     n = x.shape[0]
     pre = desc["norm_at"] == "pre"
-    h, kvh, dh = desc["heads"], desc["kv_heads"], desc["head_dim"]
+    latent = desc.get("attention") == "latent"
+    h, kvh, dh = desc["heads"], desc.get("kv_heads"), desc.get("head_dim")
     stats, new_kv, new_slots = [], [], []
     for layer, spec in zip(params["layers"], desc["layers"]):
         r = _lm_norm(desc, x, layer["attn_norm"]) if pre else x
-        if spec["operator"] == "attention":
+        if spec["operator"] == "attention" and latent:
+            with jax.named_scope("mxtpu.lm.attn"):
+                o, pages = _lm_latent(desc, layer, r, positions,
+                                      kv[len(new_kv)], attention)
+                new_kv.append(pages)
+        elif spec["operator"] == "attention":
             with jax.named_scope("mxtpu.lm.attn"):
                 q = _lm_dense(r, layer["q"]).reshape(n, h, dh)
                 k = _lm_dense(r, layer["k"]).reshape(n, kvh, dh)
@@ -893,8 +910,15 @@ def _lm_layers(desc, params, x, positions, valid, kv, slots, attention, conv):
                 layer["ew3"], layer["ew2"], k=ex["per_token"],
                 expert_offset=ex["offset"], valid=valid,
                 routed_scaling_factor=ex["scaling"],
-                norm_topk_prob=ex["norm_topk"])
+                norm_topk_prob=ex["norm_topk"], n_group=ex.get("groups", 1),
+                topk_group=ex.get("topk_groups", 1),
+                gate_eps=ex.get("gate_eps", 1e-6))
             stats.append(st)
+            if ex.get("shared"):
+                # the shared expert: whole on every holder, every token
+                with jax.named_scope("mxtpu.lm.moe.shared"):
+                    f = f + _opsnn.swiglu_ffn(r, layer["sw1"], layer["sw3"],
+                                              layer["sw2"])
         elif desc["ffn"] == "swiglu":
             f = _opsnn.swiglu_ffn(r, layer["w1"], layer["w3"], layer["w2"])
         else:
@@ -904,6 +928,48 @@ def _lm_layers(desc, params, x, positions, valid, kv, slots, attention, conv):
         if not pre:
             x = _lm_norm(desc, x, layer["ffn_norm"])
     return x, stats, tuple(new_kv), tuple(new_slots)
+
+
+def _lm_latent(desc, layer, r, positions, pages, attention):
+    """A latent (MLA) attention operator over rows ``r`` (n, C): the query
+    through its low-rank pair, the token's ONE cached row (the RMS-normed
+    compressed KV, then the rotary key all heads share), and ``attention(
+    pages, q, row, layer) -> ((n, H, v) attended, new pages)``, which stores
+    the row and attends in the program's own way: prefill expands K and V of
+    every head from the rows, decode absorbs ``kv_b`` into the query and the
+    output and reads the pages as they lie."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops import nn as _opsnn
+
+    lat, h, n = desc["latent"], desc["heads"], r.shape[0]
+    yarn = _opsnn.yarn_args(desc["rope_scaling"])
+    with jax.named_scope("mxtpu.lm.mla.q"):
+        c_q = _opsnn.rms_norm(_lm_dense(r, layer["q_a"]),
+                              layer["q_a_norm"]["g"], eps=desc["norm_eps"])
+        q = _opsnn.rope(
+            _lm_dense(c_q, layer["q_b"]).reshape(n, h, lat["nope"]
+                                                 + lat["rope"]),
+            positions, desc["rope_theta"], start=lat["nope"], yarn=yarn)
+    with jax.named_scope("mxtpu.lm.mla.kv"):
+        ckv = _lm_dense(r, layer["kv_a"])
+        c_kv = _opsnn.rms_norm(ckv[:, :lat["kv_rank"]],
+                               layer["kv_a_norm"]["g"], eps=desc["norm_eps"])
+        k_rot = _opsnn.rope(ckv[:, None, lat["kv_rank"]:], positions,
+                            desc["rope_theta"], yarn=yarn)[:, 0]
+        row = jnp.concatenate([c_kv, k_rot], axis=-1)
+    with jax.named_scope("mxtpu.lm.mla.attend"):
+        att, pages = attention(pages, q, row, layer)
+    return _lm_dense(att.astype(r.dtype).reshape(n, h * lat["v"]),
+                     layer["o"]), pages
+
+
+def _latent_scale(desc):
+    from ..ops.nn import latent_softmax_scale
+
+    return latent_softmax_scale(desc["latent"]["nope"],
+                                desc["latent"]["rope"], desc["rope_scaling"])
 
 
 def _lm_embed(desc, params, tokens, positions):
@@ -948,6 +1014,14 @@ class TransformerLMEngine:
       token's K/V to ``[page, slot]``; a decode step appends one row a
       sequence and attends over the page table
       (`ops/pallas_kernels.paged_attention`).
+    * a **latent attention** layer (description ``"attention": "latent"``)
+      owns ONE array of the pool, ``(num_pages, page_size, Cp)``: a row is a
+      token's compressed KV (``kv_rank`` lanes, RMS-normed) and the rotary
+      key all heads share, Cp their sum rounded up to a multiple of 128.
+      Prefill expands every head's K and V from the rows through ``kv_b`` and
+      attends causally in query blocks (no (H, L, L) array); a decode step
+      absorbs ``kv_b`` into the query and the output and attends over the
+      pages as they lie (`ops/pallas_kernels.paged_latent_attention`).
     * a **short-convolution** layer owns one array of state slots
       (`_slots`), ``(state_slots + 1, taps - 1, C)``: the gated input of a
       sequence's last taps-1 positions. Prefill writes the slot at the
@@ -986,8 +1060,15 @@ class TransformerLMEngine:
         self.vocab_size = int(desc["vocab_size"])
         self.units = int(desc["units"])
         self.num_heads = int(desc["heads"])
-        self.kv_heads = int(desc["kv_heads"])
-        self.head_dim = int(desc["head_dim"])
+        # a latent model caches one row a token for all heads: the
+        # compressed KV and the rotary key beside it
+        self.latent = desc.get("attention") == "latent"
+        if self.latent:
+            self.kv_heads, self.head_dim = 1, int(
+                desc["latent"]["kv_rank"] + desc["latent"]["rope"])
+        else:
+            self.kv_heads = int(desc["kv_heads"])
+            self.head_dim = int(desc["head_dim"])
         self.num_layers = len(desc["layers"])
         self.attn_layers = sum(1 for l in desc["layers"]
                                if l["operator"] == "attention")
@@ -1042,8 +1123,11 @@ class TransformerLMEngine:
         self._kv_lanes = self.kv_heads * self.head_dim
         leaf = (self.num_pages, self.page_size,
                 -(-self._kv_lanes // 128) * 128)
+        # a layer's leaf: a (K, V) pair, or a latent layer's one array
         self._kv = tuple(
-            tuple(jax.numpy.zeros(leaf, dtype=self.kv_dtype) for _ in "kv")
+            jax.numpy.zeros(leaf, dtype=self.kv_dtype) if self.latent
+            else tuple(jax.numpy.zeros(leaf, dtype=self.kv_dtype)
+                       for _ in "kv")
             for _ in range(self.attn_layers))
         # row `state_slots` is the inert one that padding rows read
         self._slots = tuple(
@@ -1052,6 +1136,14 @@ class TransformerLMEngine:
                             dtype=self.kv_dtype)
             for _ in range(self.conv_layers))
         self.last_moe = None    # (pairs, experts hit, busiest) of a step
+        # (token, expert) pairs the last prefill computed here, summed over
+        # the expert layers: known from the prompt's length where every
+        # expert is held, else it rides out of the program with the token
+        self.last_prefill_moe_pairs = None
+        ex = desc.get("experts") or {}
+        self._share_held = self.has_experts and ex["held"] < ex["total"]
+        self._pairs_per_token = int(ex.get("per_token", 0)) * sum(
+            1 for l in desc["layers"] if l["ffn"] == "experts")
         # executable identity: description + geometry (params are args,
         # so two engines with one geometry share executables)
         ident = {"config": self.config, "pages": self.num_pages,
@@ -1125,7 +1217,10 @@ class TransformerLMEngine:
 
         desc, ps, nump = self.description, self.page_size, self.num_pages
         lanes, stateful = self._kv_lanes, bool(self.conv_layers)
-        scale = 1.0 / math.sqrt(self.head_dim)
+        latent, share_held = self.latent, self._share_held
+        rank = desc["latent"]["kv_rank"] if latent else None
+        scale = _latent_scale(desc) if latent \
+            else 1.0 / math.sqrt(self.head_dim)
 
         def fn(params, state, tokens, length, page_row, temp, top_k, top_p,
                key, slot=None):
@@ -1148,6 +1243,36 @@ class TransformerLMEngine:
                              mode="drop"))
                 return _opsnn.causal_attention(q, k, v, scale), pages
 
+            def latent_attention(pages, q, row, layer):
+                # the prompt's rows in place, at the pool's full width and
+                # whole pages at a time (a write of a part of a row's lane
+                # tiles, or a scatter of rows, is a serial loop on the chip:
+                # 3 us a row, 12 ms a layer at 4096); the lanes and rows of
+                # the padding are zeros, and no step reads a page's row past
+                # the prompt's length before writing it.  K and V of every
+                # head expanded from the rows, queries `_PREFILL_Q_BLOCK` at
+                # a time
+                n_pg = -(-lp // ps)
+                wide = jnp.pad(row, ((0, n_pg * ps - lp),
+                                     (0, pages.shape[-1] - lanes))) \
+                    .astype(pages.dtype)
+                live = jnp.arange(n_pg) * ps < length
+                pages = pages.at[jnp.where(live, page_row[:n_pg],
+                                           nump)].set(
+                    wide.reshape(n_pg, ps, -1), mode="drop")
+                k_nope = jnp.einsum("lr,hdr->lhd", row[:, :rank],
+                                    layer["kvb_k"],
+                                    preferred_element_type=jnp.float32)
+                k = jnp.concatenate(
+                    [k_nope.astype(row.dtype), jnp.broadcast_to(
+                        row[:, None, rank:], (lp, q.shape[1],
+                                              lanes - rank))], axis=-1)
+                v = jnp.einsum("lr,hdr->lhd", row[:, :rank], layer["kvb_v"],
+                               preferred_element_type=jnp.float32)
+                return _opsnn.causal_attention(
+                    q, k, v.astype(row.dtype), scale,
+                    block=_PREFILL_Q_BLOCK), pages
+
             def conv(held, r, layer):
                 # the slot holds the gated input of the prompt's own last
                 # positions, whatever the bucket's padding computes after
@@ -1157,15 +1282,21 @@ class TransformerLMEngine:
                 return o, held.at[slot].set(st.astype(held.dtype),
                                             mode="drop")
 
-            x, _, kv, slots = _lm_layers(desc, params, x, t_idx,
-                                         t_idx < length, kv, slots,
-                                         attention, conv)
+            x, stats, kv, slots = _lm_layers(
+                desc, params, x, t_idx, t_idx < length, kv, slots,
+                latent_attention if latent else attention, conv)
             new = (kv, slots) if stateful else kv
             if logits_out:
                 return _lm_logits(desc, params, x), new          # (lp, V)
             logits = _lm_logits(desc, params, x[length - 1])     # (V,)
             tok = sample_token_logits(key, logits[None], temp, top_k,
                                       top_p)
+            if share_held:
+                # which of the prompt's pairs fall to the experts held here
+                # is the router's answer: their count rides out with the
+                # token
+                return jnp.stack([tok[0], jnp.sum(jnp.stack(stats)[:, 0])
+                                  .astype(tok.dtype)]), new
             return tok[0], new
 
         # the state is DONATED: without it every call materializes a
@@ -1178,12 +1309,14 @@ class TransformerLMEngine:
         import jax.numpy as jnp
 
         from ..ops import nn as _opsnn
-        from ..ops.pallas_kernels import paged_attention
+        from ..ops.pallas_kernels import (paged_attention,
+                                          paged_latent_attention)
         from ..ops.random_ops import sample_token_logits
 
         desc, lanes, kvh = self.description, self._kv_lanes, self.kv_heads
-        stateful = bool(self.conv_layers)
-        scale = 1.0 / math.sqrt(self.head_dim)
+        stateful, latent = bool(self.conv_layers), self.latent
+        scale = _latent_scale(desc) if latent \
+            else 1.0 / math.sqrt(self.head_dim)
 
         def fn(params, state, tokens, positions, dest_pages, dest_slots,
                tables, lengths, temp, top_k, top_p, key, seq_slots=None):
@@ -1203,6 +1336,23 @@ class TransformerLMEngine:
                 return paged_attention(q, kp, vp, tables, lengths,
                                        sm_scale=scale, kv_heads=kvh), (kp, vp)
 
+            def latent_attention(pages, q, row, layer):
+                # absorbed: the key up-projection folded into the query, the
+                # value up-projection applied to the attended rows; a page
+                # is read once for both products
+                rank, nope = desc["latent"]["kv_rank"], desc["latent"]["nope"]
+                pages = pages.at[dest_pages, dest_slots].set(
+                    jnp.pad(row, ((0, 0), (0, pages.shape[-1] - lanes)))
+                    .astype(pages.dtype), mode="drop")
+                q_lat = jnp.einsum("bhd,hdr->bhr", q[..., :nope],
+                                   layer["kvb_k"])
+                o_lat = paged_latent_attention(
+                    jnp.concatenate([q_lat.astype(q.dtype), q[..., nope:]],
+                                    axis=-1),
+                    pages, tables, lengths, scale, rank)
+                return jnp.einsum("bhr,hdr->bhd", o_lat, layer["kvb_v"],
+                                  preferred_element_type=jnp.float32), pages
+
             def conv(held, r, layer):
                 o, st = _opsnn.gated_short_conv(
                     r[:, None], layer["in"]["w"], layer["conv"],
@@ -1210,9 +1360,9 @@ class TransformerLMEngine:
                 return o[:, 0], held.at[seq_slots].set(
                     st.astype(held.dtype), mode="drop")
 
-            x, stats, kv, slots = _lm_layers(desc, params, x, positions,
-                                             lengths > 0, kv, slots,
-                                             attention, conv)
+            x, stats, kv, slots = _lm_layers(
+                desc, params, x, positions, lengths > 0, kv, slots,
+                latent_attention if latent else attention, conv)
             new = (kv, slots) if stateful else kv
             logits = _lm_logits(desc, params, x)                 # (b, V)
             if logits_out:
@@ -1276,7 +1426,11 @@ class TransformerLMEngine:
         tok, state = self._prefill_exe(lp, lambda: args)(*args)
         self._set_state(state)
         with _goodput.phase("prefill_wait"):    # blocked on the device
-            return int(tok)
+            out = _np.asarray(tok).reshape(-1)
+        if self.has_experts:
+            self.last_prefill_moe_pairs = int(out[1]) if self._share_held \
+                else len(tokens) * self._pairs_per_token
+        return int(out[0])
 
     def _decode_args(self, tokens, positions, dest_pages, dest_slots, tables,
                      lengths, temps, top_ks, top_ps, key, seq_slots):
@@ -1367,6 +1521,7 @@ _LM_ARCHS = {
     "transformer_lm": ("mxnet_tpu.gluon.model_zoo.transformer",
                        "TransformerLM"),
     "lfm2": ("mxnet_tpu.gluon.model_zoo.lfm2", "Lfm2LM"),
+    "gigachat3": ("mxnet_tpu.gluon.model_zoo.gigachat3", "GigaChat3LM"),
 }
 # dtypes numpy's .npy cannot name are written as these views of their bits
 _STORED_AS = {"bfloat16": "uint16"}

@@ -13,3 +13,6 @@ from chipbench.tests.test_trace import *  # noqa: F401,F403
 # the LFM2 cell at its tiny sizes (four rehearsals, about a minute), its work
 # functions and its readers
 from chipbench.tests.test_cell_lfm2 import *  # noqa: F401,F403,E402
+# the GigaChat3 cell likewise (four rehearsals), `mla_moe_work`'s counts and
+# the four readers it brings
+from chipbench.tests.test_cell_gigachat3 import *  # noqa: F401,F403,E402

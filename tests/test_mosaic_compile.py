@@ -96,19 +96,35 @@ def _paged_gqa(b, h, kv, d, n_pages, maxp, ps, dtype):
                 ((b,), jnp.int32)]
 
 
-def _moe(n, c, f, e, k, dtype):
+def _latent(b, h, rank, rot, n_pages, maxp, ps, dtype):
+    """The latent (MLA) decode kernel on a pool of compressed rows: (pages,
+    page_size, Cp) with Cp = rank + rot rounded up to the lane tile."""
+    cp = -(-(rank + rot) // 128) * 128
+    assert pk._latent_kernel_takes(ps, cp, dtype)
+
+    def fn(q, pages, tables, lengths):
+        return pk.paged_latent_attention(q, pages, tables, lengths, 0.14468,
+                                         rank)
+
+    return fn, [((b, h, rank + rot), dtype), ((n_pages, ps, cp), dtype),
+                ((b, maxp), jnp.int32), ((b,), jnp.int32)]
+
+
+def _moe(n, c, f, e, k, dtype, held=None, groups=1, kept=1):
     """The drop-free expert layer (routing in XLA, the grouped product a
     Mosaic kernel) over n rows."""
     from mxnet_tpu.ops.contrib import moe_tile_rows, sigmoid_topk_moe
 
-    assert pk._moe_kernel_takes(moe_tile_rows(n * k, e), c, f, dtype)
+    held = held or e
+    assert pk._moe_kernel_takes(moe_tile_rows(n * k, held), c, f, dtype)
 
     def fn(x, wg, bias, w1, w3, w2, valid):
-        return sigmoid_topk_moe(x, wg, bias, w1, w3, w2, k=k, valid=valid)
+        return sigmoid_topk_moe(x, wg, bias, w1, w3, w2, k=k, valid=valid,
+                                n_group=groups, topk_group=kept)
 
     return fn, [((n, c), dtype), ((e, c), dtype), ((e,), dtype),
-                ((e, f, c), dtype), ((e, f, c), dtype), ((e, f, c), dtype),
-                ((n,), jnp.bool_)]
+                ((held, f, c), dtype), ((held, f, c), dtype),
+                ((held, f, c), dtype), ((n,), jnp.bool_)]
 
 
 bf16, f32 = jnp.bfloat16, jnp.float32
@@ -151,6 +167,19 @@ CASES = {
     "moe-lfm2-decode128-bf16": lambda: _moe(128, 2048, 1536, 64, 4, bf16),
     "moe-lfm2-prefill512-bf16": lambda: _moe(512, 2048, 1536, 64, 4, bf16),
     "moe-small-f32": lambda: _moe(32, 256, 256, 8, 2, f32),
+    # GigaChat3.1-702B-A36B's published widths: 64 heads over one cached row
+    # of 512 + 64 lanes (640 in the pool), pages of 128 tokens, 38 a sequence
+    # (4096 + 768 positions), a decode batch of 128; and a tiny float32 pool
+    "latent-gigachat3-bf16":
+        lambda: _latent(128, 64, 512, 64, 4096, 38, 128, bf16),
+    "latent-tiny-f32": lambda: _latent(4, 4, 32, 8, 64, 3, 8, f32),
+    # the same model's expert layer: 16 held of 256 experts of 2048
+    # at width 7168, 8 a token in 4 of 8 groups; a decode batch of 128 and
+    # the 4096 prompt bucket
+    "moe-gigachat3-decode128-bf16":
+        lambda: _moe(128, 7168, 2048, 256, 8, bf16, 16, 8, 4),
+    "moe-gigachat3-prefill4096-bf16":
+        lambda: _moe(4096, 7168, 2048, 256, 8, bf16, 16, 8, 4),
 }
 
 
@@ -159,6 +188,7 @@ KERNEL_NAMES = {
               "flash_attention_bwd_dkv"),
     "lstm": ("lstm_layer_fwd", "lstm_layer_bwd"),
     "paged": ("paged_attention_decode",),
+    "latent": ("paged_latent_attention_decode",),
     "moe": ("moe_grouped_ffn",),
 }
 
