@@ -14,6 +14,10 @@ Public surface mirrors `import mxnet as mx`:
 """
 from __future__ import annotations
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()    # the start-up account's first stamp
+
 __version__ = "0.1.0"
 
 from . import env
@@ -78,3 +82,9 @@ if "visualization" in globals():
     viz = visualization  # noqa: F821
 if "attribute" in globals():
     AttrScope = attribute.AttrScope  # noqa: F821
+
+# the start-up account's first span, from this file's first line to its last
+# (docs/observability.md §Start-up)
+if "telemetry" in globals():
+    with telemetry.goodput.span("import", t0=_T_IMPORT):  # noqa: F821
+        pass

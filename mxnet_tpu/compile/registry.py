@@ -262,23 +262,27 @@ class Registry:
         # is compile time the training step did not spend on the device;
         # the goodput accountant attributes it whether or not a step
         # bracket is open (warmup compiles land on the cumulative counter)
+        # — and one ``program`` span of the start-up account, under which
+        # jax's own trace / lower / backend compile of this key land
         from ..telemetry import goodput as _goodput
-        import time as _time
 
-        t0 = _time.perf_counter()
+        label = label or key.fingerprint
+        sp = _goodput.span("program", label=label, kind=key.kind,
+                           tier="memory_miss")
         try:
-            label = label or key.fingerprint
-            if callable(example_args):
-                example_args = example_args()
-            if key.concrete and example_args is not None:
-                value = self._fill_concrete(key, build, example_args, label,
-                                            on_fill, event_fields)
-            else:
-                value = self._fill_lazy(key, build, label, on_fill,
-                                        event_fields)
-            return self._insert(key, value)
+            with sp:
+                if callable(example_args):
+                    example_args = example_args()
+                if key.concrete and example_args is not None:
+                    value = self._fill_concrete(key, build, example_args,
+                                                label, on_fill, event_fields,
+                                                span=sp)
+                else:
+                    value = self._fill_lazy(key, build, label, on_fill,
+                                            event_fields)
+                return self._insert(key, value)
         finally:
-            _goodput.add("compile", _time.perf_counter() - t0)
+            _goodput.add("compile", sp.elapsed)
 
     def _insert(self, key, value):
         with self._lock:
@@ -318,17 +322,21 @@ class Registry:
         self._count_fill(label, on_fill, event_fields)
         return _tm_flops.instrument(jitted)
 
-    def _fill_concrete(self, key, build, args, label, on_fill, event_fields):
+    def _fill_concrete(self, key, build, args, label, on_fill, event_fields,
+                       span=None):
         """Fill ONE executable for pinned shapes: disk hit (no compile) or
         AOT trace+compile (+ store when armed). Sharded/donating keys the
         persistent tier refuses (topology-less sharded steps) still take
         the AOT path when memory accounting is on, so their memory figures
         — and the donation verifier — come from the compile the fill pays
-        anyway."""
+        anyway. ``span`` is the miss path's ``program`` span, told here
+        which tier served it."""
         directory = self._dir(key)
         if directory is not None:
             loaded = self._load_persisted(directory, key, label, build)
             if loaded is not None:
+                if span is not None:
+                    span.fields["tier"] = "persist_hit"
                 return loaded
         with _tracing.span("compile.fill",
                            attrs={"kind": key.kind, "label": label}):

@@ -35,6 +35,7 @@ import hashlib
 import json
 
 from ..base import MXNetError
+from ..telemetry import goodput as _goodput
 from .mesh import current_mesh, mesh_fingerprint
 from .sharding import batch_spec, named_sharding
 from .trainer import DistributedTrainer, _host_lr, _traced_update, _tree_map
@@ -170,20 +171,37 @@ class ShardedTrainer(_PersistentStepMixin, DistributedTrainer):
     the fused step's executable comes from on a warm restart: the
     persistent artifact tier instead of a recompile."""
 
+    _ran = False    # until the first step, which the start-up account holds
+
     def __init__(self, block, optimizer, optimizer_params=None, loss=None,
                  mesh=None, rules=None, amp_dtype=None, loss_inputs=None):
-        super().__init__(block, optimizer, optimizer_params=optimizer_params,
-                         loss=loss, mesh=mesh, rules=rules,
-                         amp_dtype=amp_dtype, loss_inputs=loss_inputs)
-        self._topology = mesh_fingerprint(self._mesh)
-        # replace the process-local instance token with the stable
-        # cross-process fingerprint (the quarantine lift)
-        param_items = list(zip(self._param_names, self._param_nds))
-        specs = [sh.spec for sh in self._shardings]
-        self._compile_token = stable_fingerprint(
-            block, param_items, specs, self._optimizer, loss=loss,
-            amp_dtype=amp_dtype, loss_inputs=loss_inputs)
-        self._init_persist("%s|%s" % (self._compile_token, self._topology))
+        with _goodput.span("trainer_build"):
+            super().__init__(block, optimizer,
+                             optimizer_params=optimizer_params, loss=loss,
+                             mesh=mesh, rules=rules, amp_dtype=amp_dtype,
+                             loss_inputs=loss_inputs)
+            self._topology = mesh_fingerprint(self._mesh)
+            # replace the process-local instance token with the stable
+            # cross-process fingerprint (the quarantine lift)
+            param_items = list(zip(self._param_names, self._param_nds))
+            specs = [sh.spec for sh in self._shardings]
+            self._compile_token = stable_fingerprint(
+                block, param_items, specs, self._optimizer, loss=loss,
+                amp_dtype=amp_dtype, loss_inputs=loss_inputs)
+            self._init_persist("%s|%s" % (self._compile_token,
+                                          self._topology))
+
+    def step(self, data, label=None, batch_size=None):
+        if self._ran:
+            return super().step(data, label, batch_size)
+        # the first step: its ``program`` span (trace, lower, backend
+        # compile under it) lies inside ``first_run``; ``ready`` once the
+        # step has returned (docs/observability.md §Start-up)
+        with _goodput.span("first_run", label="dist_trainer_step"):
+            loss = super().step(data, label, batch_size)
+        self._ran = True
+        _goodput.ready(trainer="dist")
+        return loss
 
     @property
     def topology(self):

@@ -1045,115 +1045,121 @@ class TransformerLMEngine:
                  eos_id=None, kv_dtype=None, description=None):
         import jax
 
-        if lm is not None:
-            config = lm.config
-            params = lm.decode_params()
-            if description is None and hasattr(lm, "description"):
-                description = lm.description()
-        if config is None or params is None:
-            raise MXNetError("TransformerLMEngine needs an lm= block or "
-                             "params= + config=")
-        if description is None:
-            description = transformer_lm_description(config)
-        self.config = dict(config)
-        self.description = desc = dict(description)
-        self.vocab_size = int(desc["vocab_size"])
-        self.units = int(desc["units"])
-        self.num_heads = int(desc["heads"])
-        # a latent model caches one row a token for all heads: the
-        # compressed KV and the rotary key beside it
-        self.latent = desc.get("attention") == "latent"
-        if self.latent:
-            self.kv_heads, self.head_dim = 1, int(
-                desc["latent"]["kv_rank"] + desc["latent"]["rope"])
-        else:
-            self.kv_heads = int(desc["kv_heads"])
-            self.head_dim = int(desc["head_dim"])
-        self.num_layers = len(desc["layers"])
-        self.attn_layers = sum(1 for l in desc["layers"]
-                               if l["operator"] == "attention")
-        self.conv_layers = self.num_layers - self.attn_layers
-        self.has_experts = any(l["ffn"] == "experts" for l in desc["layers"])
-        self.eos_id = None if eos_id is None else int(eos_id)
-        self.page_size = int(page_size if page_size is not None
-                             else _env.get("MXTPU_SERVE_KV_PAGE_SIZE"))
-        self.num_pages = int(num_pages if num_pages is not None
-                             else _env.get("MXTPU_SERVE_KV_PAGES"))
-        self.max_prompt = int(max_prompt if max_prompt is not None
-                              else _env.get("MXTPU_SERVE_MAX_PROMPT"))
-        self.max_new_tokens = int(
-            max_new_tokens if max_new_tokens is not None
-            else _env.get("MXTPU_SERVE_MAX_NEW_TOKENS"))
-        max_total = self.max_prompt + self.max_new_tokens
-        if max_total > int(desc["max_length"]):
-            raise MXNetError(
-                "max_prompt + max_new_tokens = %d exceeds the model's "
-                "position table (max_length=%d)"
-                % (max_total, desc["max_length"]))
-        self.max_pages_per_seq = -(-max_total // self.page_size)
-        if self.max_pages_per_seq > self.num_pages:
-            raise MXNetError(
-                "one sequence can need %d pages but the pool has only %d "
-                "(MXTPU_SERVE_KV_PAGES)" % (self.max_pages_per_seq,
-                                            self.num_pages))
-        if decode_buckets is None:
-            if max_batch is None:
-                max_batch = _env.get("MXTPU_SERVE_MAX_BATCH")
-            decode_buckets = power_of_two_buckets(max_batch)
-        self.buckets = sorted(int(b) for b in decode_buckets)
-        if prefill_buckets is None:
-            lo = min(8, self.max_prompt)
-            prefill_buckets = [b for b in
-                               power_of_two_buckets(self.max_prompt)
-                               if b >= lo]
-        self.prefill_buckets = sorted(int(b) for b in prefill_buckets)
-        self.dtype = str(desc["dtype"])
-        self.kv_dtype = str(kv_dtype if kv_dtype is not None else self.dtype)
-        # one state slot a sequence that can be active, for every
-        # short-convolution layer; 0 where the model has none
-        self.state_slots = self.buckets[-1] if self.conv_layers else 0
+        # the parameters into the engine's layout, the page pool and the
+        # slots: one span of the start-up account
+        with _goodput.span("engine_build") as build:
+            if lm is not None:
+                config = lm.config
+                params = lm.decode_params()
+                if description is None and hasattr(lm, "description"):
+                    description = lm.description()
+            if config is None or params is None:
+                raise MXNetError("TransformerLMEngine needs an lm= block or "
+                                 "params= + config=")
+            if description is None:
+                description = transformer_lm_description(config)
+            self.config = dict(config)
+            self.description = desc = dict(description)
+            self.vocab_size = int(desc["vocab_size"])
+            self.units = int(desc["units"])
+            self.num_heads = int(desc["heads"])
+            # a latent model caches one row a token for all heads: the
+            # compressed KV and the rotary key beside it
+            self.latent = desc.get("attention") == "latent"
+            if self.latent:
+                self.kv_heads, self.head_dim = 1, int(
+                    desc["latent"]["kv_rank"] + desc["latent"]["rope"])
+            else:
+                self.kv_heads = int(desc["kv_heads"])
+                self.head_dim = int(desc["head_dim"])
+            self.num_layers = len(desc["layers"])
+            self.attn_layers = sum(1 for l in desc["layers"]
+                                   if l["operator"] == "attention")
+            self.conv_layers = self.num_layers - self.attn_layers
+            self.has_experts = any(l["ffn"] == "experts"
+                                   for l in desc["layers"])
+            self.eos_id = None if eos_id is None else int(eos_id)
+            self.page_size = int(page_size if page_size is not None
+                                 else _env.get("MXTPU_SERVE_KV_PAGE_SIZE"))
+            self.num_pages = int(num_pages if num_pages is not None
+                                 else _env.get("MXTPU_SERVE_KV_PAGES"))
+            self.max_prompt = int(max_prompt if max_prompt is not None
+                                  else _env.get("MXTPU_SERVE_MAX_PROMPT"))
+            self.max_new_tokens = int(
+                max_new_tokens if max_new_tokens is not None
+                else _env.get("MXTPU_SERVE_MAX_NEW_TOKENS"))
+            max_total = self.max_prompt + self.max_new_tokens
+            if max_total > int(desc["max_length"]):
+                raise MXNetError(
+                    "max_prompt + max_new_tokens = %d exceeds the model's "
+                    "position table (max_length=%d)"
+                    % (max_total, desc["max_length"]))
+            self.max_pages_per_seq = -(-max_total // self.page_size)
+            if self.max_pages_per_seq > self.num_pages:
+                raise MXNetError(
+                    "one sequence can need %d pages but the pool has only %d "
+                    "(MXTPU_SERVE_KV_PAGES)" % (self.max_pages_per_seq,
+                                                self.num_pages))
+            if decode_buckets is None:
+                if max_batch is None:
+                    max_batch = _env.get("MXTPU_SERVE_MAX_BATCH")
+                decode_buckets = power_of_two_buckets(max_batch)
+            self.buckets = sorted(int(b) for b in decode_buckets)
+            if prefill_buckets is None:
+                lo = min(8, self.max_prompt)
+                prefill_buckets = [b for b in
+                                   power_of_two_buckets(self.max_prompt)
+                                   if b >= lo]
+            self.prefill_buckets = sorted(int(b) for b in prefill_buckets)
+            self.dtype = str(desc["dtype"])
+            self.kv_dtype = str(kv_dtype if kv_dtype is not None
+                                else self.dtype)
+            # one state slot a sequence that can be active, for every
+            # short-convolution layer; 0 where the model has none
+            self.state_slots = self.buckets[-1] if self.conv_layers else 0
 
-        dtype = jax.numpy.dtype(self.dtype)
-        self._params = jax.tree_util.tree_map(
-            lambda a: jax.numpy.asarray(a, dtype), params)
-        self._param_bytes = int(sum(
-            a.size * a.dtype.itemsize
-            for a in jax.tree_util.tree_leaves(self._params)))
-        # rows padded to the 128-lane tile HERE, once — never per call
-        self._kv_lanes = self.kv_heads * self.head_dim
-        leaf = (self.num_pages, self.page_size,
-                -(-self._kv_lanes // 128) * 128)
-        # a layer's leaf: a (K, V) pair, or a latent layer's one array
-        self._kv = tuple(
-            jax.numpy.zeros(leaf, dtype=self.kv_dtype) if self.latent
-            else tuple(jax.numpy.zeros(leaf, dtype=self.kv_dtype)
-                       for _ in "kv")
-            for _ in range(self.attn_layers))
-        # row `state_slots` is the inert one that padding rows read
-        self._slots = tuple(
-            jax.numpy.zeros((self.state_slots + 1,
-                             int(desc["conv_taps"]) - 1, self.units),
-                            dtype=self.kv_dtype)
-            for _ in range(self.conv_layers))
-        self.last_moe = None    # (pairs, experts hit, busiest) of a step
-        # (token, expert) pairs the last prefill computed here, summed over
-        # the expert layers: known from the prompt's length where every
-        # expert is held, else it rides out of the program with the token
-        self.last_prefill_moe_pairs = None
-        ex = desc.get("experts") or {}
-        self._share_held = self.has_experts and ex["held"] < ex["total"]
-        self._pairs_per_token = int(ex.get("per_token", 0)) * sum(
-            1 for l in desc["layers"] if l["ffn"] == "experts")
-        # executable identity: description + geometry (params are args,
-        # so two engines with one geometry share executables)
-        ident = {"config": self.config, "pages": self.num_pages,
-                 "page_size": self.page_size, "maxp": self.max_pages_per_seq,
-                 "kv": self.kv_dtype}
-        if desc["arch"] != "transformer_lm":
-            ident["description"] = desc
-            ident["slots"] = self.state_slots
-        self._fingerprint = hashlib.sha256(json.dumps(
-            ident, sort_keys=True).encode()).hexdigest()[:32]
+            dtype = jax.numpy.dtype(self.dtype)
+            self._params = jax.tree_util.tree_map(
+                lambda a: jax.numpy.asarray(a, dtype), params)
+            self._param_bytes = int(sum(
+                a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(self._params)))
+            # rows padded to the 128-lane tile HERE, once — never per call
+            self._kv_lanes = self.kv_heads * self.head_dim
+            leaf = (self.num_pages, self.page_size,
+                    -(-self._kv_lanes // 128) * 128)
+            # a layer's leaf: a (K, V) pair, or a latent layer's one array
+            self._kv = tuple(
+                jax.numpy.zeros(leaf, dtype=self.kv_dtype) if self.latent
+                else tuple(jax.numpy.zeros(leaf, dtype=self.kv_dtype)
+                           for _ in "kv")
+                for _ in range(self.attn_layers))
+            # row `state_slots` is the inert one that padding rows read
+            self._slots = tuple(
+                jax.numpy.zeros((self.state_slots + 1,
+                                 int(desc["conv_taps"]) - 1, self.units),
+                                dtype=self.kv_dtype)
+                for _ in range(self.conv_layers))
+            self.last_moe = None    # (pairs, experts hit, busiest) of a step
+            # (token, expert) pairs the last prefill computed here, summed over
+            # the expert layers: known from the prompt's length where every
+            # expert is held, else it rides out of the program with the token
+            self.last_prefill_moe_pairs = None
+            ex = desc.get("experts") or {}
+            self._share_held = self.has_experts and ex["held"] < ex["total"]
+            self._pairs_per_token = int(ex.get("per_token", 0)) * sum(
+                1 for l in desc["layers"] if l["ffn"] == "experts")
+            # executable identity: description + geometry (params are args,
+            # so two engines with one geometry share executables)
+            ident = {"config": self.config, "pages": self.num_pages,
+                     "page_size": self.page_size,
+                     "maxp": self.max_pages_per_seq, "kv": self.kv_dtype}
+            if desc["arch"] != "transformer_lm":
+                ident["description"] = desc
+                ident["slots"] = self.state_slots
+            self._fingerprint = hashlib.sha256(json.dumps(
+                ident, sort_keys=True).encode()).hexdigest()[:32]
+            build.fields["pool_bytes"] = self.kv_bytes()
 
     # -- sizing ------------------------------------------------------------
     def kv_bytes(self):
@@ -1490,25 +1496,28 @@ class TransformerLMEngine:
 
     def warm(self):
         """Compile every prefill + decode bucket (dummy data, dropped
-        writes) so steady-state generation is zero-compile. Returns
-        seconds."""
+        writes) so steady-state generation is zero-compile. Each bucket's
+        call, from dispatch to the fetched result, is a ``first_run`` span
+        of the start-up account (the ``program`` span of a miss lies
+        inside it). Returns seconds."""
         t0 = time.monotonic()
         maxp = self.max_pages_per_seq
         for lp in self.prefill_buckets:
             # a full-bucket prompt so EVERY prefill bucket compiles (a
             # 1-token prompt would only ever warm the smallest)
-            self.prefill([1] * lp, _np.zeros(maxp, _np.int32),
-                         (0.0, 0, 1.0), _random.next_key())
+            with _goodput.span("first_run", label="lm_prefill:l%d" % lp):
+                self.prefill([1] * lp, _np.zeros(maxp, _np.int32),
+                             (0.0, 0, 1.0), _random.next_key())
         for b in self.buckets:
-            self.decode_step(
-                _np.zeros(b, _np.int32), _np.zeros(b, _np.int32),
-                _np.full(b, self.num_pages, _np.int32),
-                _np.zeros(b, _np.int32), _np.zeros((b, maxp), _np.int32),
-                _np.zeros(b, _np.int32), _np.zeros(b, _np.float32),
-                _np.zeros(b, _np.int32), _np.ones(b, _np.float32),
-                _random.next_key())
-            telemetry.record_event("serve_decode_warm", model="engine",
-                                   bucket=b)
+            with _goodput.span("first_run", label="lm_decode:b%d" % b):
+                self.decode_step(
+                    _np.zeros(b, _np.int32), _np.zeros(b, _np.int32),
+                    _np.full(b, self.num_pages, _np.int32),
+                    _np.zeros(b, _np.int32),
+                    _np.zeros((b, maxp), _np.int32),
+                    _np.zeros(b, _np.int32), _np.zeros(b, _np.float32),
+                    _np.zeros(b, _np.int32), _np.ones(b, _np.float32),
+                    _random.next_key())
         return time.monotonic() - t0
 
 
@@ -1573,6 +1582,13 @@ def _adopt_params(lm, path, dtype):
             p.adopt(jax.device_put(a))
 
 
+def _artifact_fields(lm, prefix):
+    """What an artifact span says of its work: the parameter file's bytes
+    and how many arrays it holds."""
+    return {"bytes": os.path.getsize(prefix + "-lm.params"),
+            "arrays": len(lm.collect_params())}
+
+
 def save_lm(lm, prefix):
     """Write a generation-serving artifact: the header (which zoo block, its
     constructor arguments, and the per-layer description the engine builds
@@ -1581,20 +1597,23 @@ def save_lm(lm, prefix):
     from .. import nd
     from ..base import atomic_writer
 
-    if any(p._data is None for p in lm.collect_params().values()):
-        # deferred Dense/LayerNorm shapes materialize on first forward
-        lm(nd.array([[0]], dtype="int32"))
     prefix = os.fspath(prefix)
     arch = _lm_arch(lm)
-    description = lm.description() if hasattr(lm, "description") \
-        else transformer_lm_description(lm.config)
-    with atomic_writer(prefix + "-lmconfig.json", "w") as f:
-        json.dump({"format": _LM_FORMAT, "arch": arch, "config": lm.config,
-                   "description": description}, f, indent=1)
-    if arch == "transformer_lm":
-        lm.save_parameters(prefix + "-lm.params")
-    else:
-        _write_params(lm, prefix + "-lm.params", description["dtype"])
+    with _goodput.span("artifact_write") as sp:
+        if any(p._data is None for p in lm.collect_params().values()):
+            # deferred Dense/LayerNorm shapes materialize on first forward
+            lm(nd.array([[0]], dtype="int32"))
+        description = lm.description() if hasattr(lm, "description") \
+            else transformer_lm_description(lm.config)
+        with atomic_writer(prefix + "-lmconfig.json", "w") as f:
+            json.dump({"format": _LM_FORMAT, "arch": arch,
+                       "config": lm.config, "description": description},
+                      f, indent=1)
+        if arch == "transformer_lm":
+            lm.save_parameters(prefix + "-lm.params")
+        else:
+            _write_params(lm, prefix + "-lm.params", description["dtype"])
+        sp.fields.update(_artifact_fields(lm, prefix))
     return prefix
 
 
@@ -1618,12 +1637,15 @@ def load_lm(prefix):
         raise MXNetError("%s: unknown LM architecture %r (have %s)"
                          % (cfg_path, arch, sorted(_LM_ARCHS)))
     module, cls = _LM_ARCHS[arch]
-    lm = getattr(importlib.import_module(module), cls)(**header["config"])
-    if arch == "transformer_lm":
-        lm.load_parameters(prefix + "-lm.params")
-    else:
-        _adopt_params(lm, prefix + "-lm.params",
-                      header["description"]["dtype"])
+    with _goodput.span("artifact_read") as sp:
+        lm = getattr(importlib.import_module(module),
+                     cls)(**header["config"])
+        if arch == "transformer_lm":
+            lm.load_parameters(prefix + "-lm.params")
+        else:
+            _adopt_params(lm, prefix + "-lm.params",
+                          header["description"]["dtype"])
+        sp.fields.update(_artifact_fields(lm, prefix))
     return lm
 
 

@@ -867,6 +867,9 @@ class ModelRepository:
                 "%s (warn-only budget: publishing anyway)", over_budget)
         telemetry.record_event("serve_model_load", model=model.name,
                                version=model.version)
+        # the start-up account's mark: what a reader of set-up sums ends
+        # at the first one (docs/observability.md §Start-up)
+        telemetry.goodput.ready(model="%s/%d" % (model.name, model.version))
         # chaos hook: a `load_surge@` MXTPU_FAULT_INJECT entry arms a
         # synthetic open-loop burst against this model's admission queue
         # (docs/fault_tolerance.md §5 — the autoscaler test vector)

@@ -49,6 +49,12 @@ named phase of the host. jax is used only if the process has already
 loaded it. Every closed bracket leaves a whole record in a bounded ring
 per kind (:func:`window`).
 
+What happens before the first step or lap — import, artifact IO, engine
+and trainer construction, each program's trace / lower / backend compile
+and its first run — is a third ring, ``window("startup")``, of whole
+:class:`span` records (docs/observability.md §Start-up); nothing is written
+into it by a step or a lap after the first.
+
 Accounting state is thread-local: concurrent trainers (tests, serving +
 training in one process) never cross-attribute. All read paths used by
 signal handlers (:func:`snapshot`, :func:`statusz_block`) are lock-free
@@ -56,6 +62,7 @@ and allocation-light — mxlint's signal-safety checker walks them.
 """
 import atexit
 import collections
+import itertools
 import sys
 import threading
 import time
@@ -87,8 +94,24 @@ _RINGS = {}
 _LAST_TRAIN_KIND = None   # whose ring feeds the gauge and /statusz
 _GAUGE_STEPS = None       # MXTPU_GOODPUT_WINDOW_STEPS, read at first step
 
-_FIRST_STEP_TS = None  # wall-clock ts of the first completed step
-_PROC_T0 = time.time()  # module import ≈ process start (post-fork exec)
+_FIRST_STEP_STARTUP_S = None  # first span's start -> first step's start
+
+# the start-up account (ring ``startup``): jax's own compile stages by the
+# monitoring event that carries each, and what its cache events say of a
+# backend compile
+_JAX_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile"}
+_JAX_CACHE = {"/jax/compilation_cache/cache_hits": "hit",
+              "/jax/compilation_cache/cache_misses": "miss"}
+_JAX_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JAX_ARMED = False     # the listeners are registered once a process
+_JAX_NOT_TRACING = None  # jax's own "no trace is in progress", if it has one
+_SPAN_IDS = itertools.count(1)
+_STARTUP_T0 = None     # start of the first span written (perf_counter)
+_READY = None          # the first ``ready`` mark's record
+_STARTUP = None        # what the first ``ready`` published (/statusz)
 
 _METRICS = None  # (hist_by_phase, ctr_by_phase, wall_ctr, frac_gauge)
 
@@ -283,10 +306,7 @@ def step_end(step=None, **fields):
            "cpu_s": cpu_s, "step": a["step"] if step is None else step}
     rec.update(fields)
     kind = a["kind"]
-    ring = _RINGS.get(kind)
-    if ring is None:
-        ring = _RINGS.setdefault(kind, collections.deque(maxlen=_RING_LEN))
-    ring.append(rec)  # mxlint: gil-atomic — signal-safe ring
+    _ring(kind).append(rec)  # mxlint: gil-atomic — signal-safe ring
     if a["names"] is PHASES:
         _publish_training(kind, ph, wall, now)
     out = dict(ph)
@@ -294,8 +314,15 @@ def step_end(step=None, **fields):
     return out
 
 
+def _ring(kind):
+    ring = _RINGS.get(kind)
+    if ring is None:
+        ring = _RINGS.setdefault(kind, collections.deque(maxlen=_RING_LEN))
+    return ring
+
+
 def _publish_training(kind, ph, wall, now):
-    global _LAST_TRAIN_KIND, _FIRST_STEP_TS
+    global _LAST_TRAIN_KIND, _FIRST_STEP_STARTUP_S
     _TLS.last_end = now
     _LAST_TRAIN_KIND = kind  # mxlint: gil-atomic — plain store
     hists, ctrs, wall_ctr, frac = _metrics()
@@ -312,21 +339,24 @@ def _publish_training(kind, ph, wall, now):
     if w_wall > 0.0:
         frac.set(w_compute / w_wall)
 
-    if _FIRST_STEP_TS is None:
-        _FIRST_STEP_TS = time.time()  # mxlint: gil-atomic — one-time stamp
+    if _FIRST_STEP_STARTUP_S is None:
         # the launcher ledger joins this against generation start to price
         # restart cost (rendezvous + restore + first-step compile).
-        # ``startup_s`` runs module import → first step START (the step
+        # ``startup_s`` runs from the start-up account's first span (the
+        # package import's first line) → first step START (the step
         # itself is already phase-attributed — no double counting);
         # ``step_wall_s`` lets tools/goodput_report.py anchor the
         # attributed window's wall-clock start at ``ts - step_wall_s``.
         # Lazy import: recorder imports goodput for dumps, not the reverse.
         from . import recorder as _recorder
 
+        # mxlint: gil-atomic — one-time stamp
+        _FIRST_STEP_STARTUP_S = 0.0 if _STARTUP_T0 is None \
+            else max(0.0, now - wall - _STARTUP_T0)
         _recorder.record_event(
             "goodput_first_step", trainer=kind,
             generation=_core.restart_generation(),
-            startup_s=round(max(0.0, _FIRST_STEP_TS - wall - _PROC_T0), 3),
+            startup_s=round(_FIRST_STEP_STARTUP_S, 3),
             step_wall_s=round(wall, 4))
 
 
@@ -359,6 +389,198 @@ def finalize():
     wall_ctr.inc(attributed)
 
 
+# -- the start-up account ------------------------------------------------------
+
+def _open_spans():
+    stack = getattr(_TLS, "spans", None)
+    if stack is None:
+        stack = _TLS.spans = []
+    return stack
+
+
+def _write_span(name, t0, t1, fields, span_id=None, back_dated=False):
+    """Append one whole span record to the ``startup`` ring; its ``parent``
+    is the span open on this thread. A back-dated span (one of jax's
+    stages, written when it ends; the package import) adopts what this
+    thread wrote inside its interval under the same parent, so that
+    ``parent`` is the nesting whichever way a span came to be written."""
+    global _STARTUP_T0
+    stack = _open_spans()
+    rec = {"name": name, "t0": t0, "t1": t1,
+           "id": next(_SPAN_IDS) if span_id is None else span_id,
+           "parent": stack[-1] if stack else None,
+           "after_ready": _READY is not None}
+    rec.update(fields)
+    recent = getattr(_TLS, "written", None)
+    if recent is None:
+        recent = _TLS.written = collections.deque(maxlen=256)
+    if back_dated:
+        for inner in reversed(recent):
+            if inner["t0"] < t0:
+                break
+            if inner["parent"] == rec["parent"]:
+                inner["parent"] = rec["id"]
+    recent.append(rec)
+    if _STARTUP_T0 is None or t0 < _STARTUP_T0:
+        _STARTUP_T0 = t0  # mxlint: gil-atomic — plain store
+    _ring("startup").append(rec)  # mxlint: gil-atomic — signal-safe ring
+    return rec
+
+
+class span:
+    """``with goodput.span("artifact_read", bytes=n) as sp:`` — one record
+    of the start-up account (ring ``startup``): ``name``, ``t0``/``t1`` on
+    ``time.perf_counter()``, ``id``, ``parent`` (the id of the span open on
+    this thread when it began, else None), ``after_ready`` and the owner's
+    ``fields`` (writable until the block ends). Usable outside any step
+    bracket; spans nest, nothing is subtracted when one is written, and a
+    reader takes self time as duration less children. Where a profiler
+    session is on the block is also the annotation
+    ``mxtpu.startup.<name>``. ``t0`` back-dates the start (the package
+    import's first line). ``t0`` and ``elapsed`` stay readable after the
+    block, with the accountant off too; nothing is written then."""
+
+    __slots__ = ("name", "fields", "t0", "elapsed", "_id", "_ann",
+                 "_back_dated")
+
+    def __init__(self, name, t0=None, **fields):
+        self.name, self.fields, self.t0 = name, fields, t0
+        self._back_dated = t0 is not None
+
+    def __enter__(self):
+        self._id = self._ann = None
+        if _enabled():
+            _arm_jax()
+            self._id = next(_SPAN_IDS)
+            tm = _trace_me()
+            if tm is not None and tm.is_enabled():
+                self._ann = tm("mxtpu.startup." + self.name)
+                self._ann.__enter__()
+        if self.t0 is None:
+            self.t0 = time.perf_counter()
+        if self._id is not None:
+            _open_spans().append(self._id)
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self.elapsed = t1 - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if self._id is not None:
+            _open_spans().pop()
+            _write_span(self.name, self.t0, t1, self.fields, self._id,
+                        self._back_dated)
+        return False
+
+
+def ready(**fields):
+    """Mark, with a span of zero length, that the process can do what it
+    was started for: a model is published, the first training step has
+    returned. The first mark ends the start-up a reader accounts for and
+    publishes it (``mxtpu_startup_phase_seconds{phase}``, the ``/statusz``
+    ``startup`` block); spans go on being written after it, marked
+    ``after_ready``."""
+    global _READY
+    if not _enabled():
+        return
+    now = time.perf_counter()
+    rec = _write_span("ready", now, now, fields)
+    if _READY is None:
+        _READY = rec  # mxlint: gil-atomic — one-time latch
+        _publish_startup(rec)
+
+
+def self_seconds(spans):
+    """``{name: seconds}`` over ``spans``: each span's duration less that
+    of the spans whose ``parent`` names it, summed by name."""
+    held = {}
+    for r in spans:
+        if r["parent"] is not None:
+            held[r["parent"]] = held.get(r["parent"], 0.0) \
+                + (r["t1"] - r["t0"])
+    out = {}
+    for r in spans:
+        own = (r["t1"] - r["t0"]) - held.get(r["id"], 0.0)
+        out[r["name"]] = out.get(r["name"], 0.0) + max(0.0, own)
+    return out
+
+
+def _publish_startup(mark):
+    global _STARTUP
+    spans = [r for r in window("startup")
+             if r["t1"] <= mark["t1"] and r is not mark]
+    phases = self_seconds(spans)
+    phases["total"] = mark["t1"] - _STARTUP_T0
+    for name, seconds in phases.items():
+        _core.gauge("mxtpu_startup_phase_seconds",
+                    {"phase": name}).set(seconds)
+    _STARTUP = {"ready": True, "spans": len(spans),
+                "phases": {k: round(v, 3) for k, v in phases.items()}}
+
+
+def startup_block():
+    """The ``/statusz`` ``startup`` block: self seconds by span name from
+    the first span's start to the first ``ready`` mark (``total``: all of
+    it), as that mark published them; before it, how many spans there are.
+    A stored dict — signal-safe."""
+    if _STARTUP is None:
+        return {"ready": False, "spans": len(_RINGS.get("startup", ()))}
+    return _STARTUP
+
+
+def _arm_jax():
+    """Register the listeners that write jax's compile stages into the
+    account, once, when the accountant first sees jax loaded. jax calls
+    them on the compiling thread, on a compile and never on an
+    execution."""
+    global _JAX_ARMED, _JAX_NOT_TRACING
+    if _JAX_ARMED:
+        return
+    mon = getattr(sys.modules.get("jax"), "monitoring", None)
+    if mon is None:
+        return
+    _JAX_ARMED = True  # mxlint: gil-atomic — one-time latch
+    _JAX_NOT_TRACING = getattr(sys.modules.get("jax._src.core"),
+                               "trace_state_clean", None)
+    mon.register_event_listener(_on_jax_event)
+    mon.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _on_jax_event(event, **_):
+    verdict = _JAX_CACHE.get(event)
+    if verdict is not None:
+        _TLS.jax_cache = verdict    # of the backend compile now running
+
+
+def _on_jax_duration(event, duration, fun_name=None, **_):
+    name = _JAX_STAGES.get(event)
+    if name is None:
+        if event == _JAX_RETRIEVAL:
+            _TLS.jax_retrieval_s = duration
+        return
+    if name == "trace" and _JAX_NOT_TRACING is not None \
+            and not _JAX_NOT_TRACING():
+        # a jitted function traced inside another's trace (every jnp
+        # call): its seconds are the outer trace's, hundreds a program
+        return
+    fields = {"fun_name": fun_name}
+    if name == "backend_compile":
+        # neither event: jax's cache took no part (not armed, or the
+        # program under its thresholds)
+        fields["cache"] = getattr(_TLS, "jax_cache", None) or "off"
+        retrieval = getattr(_TLS, "jax_retrieval_s", None)
+        if retrieval is not None:
+            fields["retrieval_s"] = retrieval
+        _TLS.jax_cache = _TLS.jax_retrieval_s = None
+    if not _enabled():
+        return
+    # jax stamps its stages on time.time(): the end is now, on the rings'
+    # clock, and the start that long before
+    t1 = time.perf_counter()
+    _write_span(name, t1 - duration, t1, fields, back_dated=True)
+
+
 def window(kind):
     """A copy of ``kind``'s ring: the whole records of its last (up to
     4096) closed brackets, oldest first. Each holds ``t0``/``t1`` on
@@ -367,7 +589,8 @@ def window(kind):
     (``time.thread_time()`` of the bracket's thread across it: wall minus
     device wait minus this is time spent waiting for the GIL or a lock),
     ``step`` and the owner's fields (a lap's ``n``, ``bucket``,
-    ``prefills``, ``admitted``, ``queue_wait_s``). Same retry discipline
+    ``prefills``, ``admitted``, ``queue_wait_s``). Kind ``startup`` holds
+    the start-up account's :class:`span` records instead. Same retry discipline
     as core._win_entries — an append during a signal-context read raises
     RuntimeError."""
     ring = _RINGS.get(kind)
@@ -439,8 +662,8 @@ def statusz_block():
         "top_stall_seconds": round(top[1], 4) if top else 0.0,
         "totals": totals(),
     }
-    if _FIRST_STEP_TS is not None:
-        block["first_step_startup_s"] = round(_FIRST_STEP_TS - _PROC_T0, 3)
+    if _FIRST_STEP_STARTUP_S is not None:
+        block["first_step_startup_s"] = round(_FIRST_STEP_STARTUP_S, 3)
     return block
 
 
@@ -450,12 +673,15 @@ def snapshot():
 
 
 def _reset_for_tests():
-    global _METRICS, _FIRST_STEP_TS, _LAST_TRAIN_KIND, _GAUGE_STEPS
+    global _METRICS, _FIRST_STEP_STARTUP_S, _LAST_TRAIN_KIND, _GAUGE_STEPS
+    global _STARTUP_T0, _READY, _STARTUP
     _RINGS.clear()
     _LAST_TRAIN_KIND = None
     _GAUGE_STEPS = None
     _METRICS = None
-    _FIRST_STEP_TS = None
+    _FIRST_STEP_STARTUP_S = None
+    _STARTUP_T0 = _READY = _STARTUP = None
+    _TLS.spans, _TLS.written = [], None
     stale = _acct()
     if stale is not None and stale["ann"] is not None:
         stale["ann"].__exit__(None, None, None)

@@ -1164,6 +1164,7 @@ def statusz_payload(extra=None):
         "compile_cache": _compile_stats(),
         "memory": memory.snapshot(),
         "training": goodput.statusz_block(),
+        "startup": goodput.startup_block(),
         "slowest_exemplars": _slowest_exemplars(),
     }
     if extra:
@@ -1215,6 +1216,9 @@ def _render_text(payload):
                      % (tr.get("goodput_fraction"), tr["window_steps"],
                         tr.get("top_stall_phase"),
                         tr.get("top_stall_seconds", 0.0)))
+    su = payload.get("startup") or {}
+    if su.get("ready"):
+        lines.append("startup: %s" % su["phases"])
     for name, pool in sorted(payload["pools"].items()):
         lines.append("  pool %s: %s" % (name, pool))
     if payload["compile_cache"]:

@@ -855,6 +855,83 @@ def test_http_generate_e2e(tiny_lm, tmp_path):
         repo.unload("lm", timeout=1.0)
 
 
+_STARTUP_DRIVE = r"""
+import sys, threading, time
+import mxnet_tpu as mx
+from mxnet_tpu.gluon.model_zoo.transformer import lm_mini
+from mxnet_tpu.serving import ModelRepository, save_lm
+from mxnet_tpu.telemetry import goodput
+
+lm = lm_mini(vocab_size=96)
+lm.initialize(mx.init.Xavier())
+prefix = save_lm(lm, sys.argv[1])
+repo = ModelRepository()
+model = repo.load("lm", prefix, generate=True, generate_opts=dict(
+    num_pages=32, page_size=4, max_prompt=8, max_new_tokens=12, max_batch=4))
+recs = sorted(goodput.window("startup"), key=lambda r: r["t0"])
+top = [r for r in recs if r["parent"] is None]
+names = [r["name"] for r in top if r["name"] in (
+    "import", "artifact_write", "artifact_read", "engine_build",
+    "first_run", "ready")]
+buckets = ["lm_prefill:l8", "lm_decode:b1", "lm_decode:b2", "lm_decode:b4"]
+assert names == ["import", "artifact_write", "artifact_read", "engine_build",
+                 "first_run", "first_run", "first_run", "first_run",
+                 "ready"], names
+first = [r for r in recs if r["name"] == "first_run"]
+assert [r["label"] for r in first] == buckets, first
+for run in first:                   # one program span a bucket, inside it
+    held = [r for r in recs if r["name"] == "program"
+            and r["parent"] == run["id"]]
+    assert [r["label"] for r in held] == [run["label"]], (run, held)
+    assert held[0]["tier"] == "memory_miss"
+by_name = {r["name"]: r for r in top}
+assert by_name["artifact_read"]["bytes"] == by_name["artifact_write"]["bytes"]
+assert by_name["artifact_read"]["bytes"] > 0
+assert by_name["artifact_read"]["arrays"] == len(lm.collect_params())
+assert by_name["engine_build"]["pool_bytes"] == model.generate_info["kv_bytes"]
+assert by_name["ready"]["model"] == "lm/1"
+assert not any(r["after_ready"] for r in recs)
+stages = [r for r in recs if r["name"] in ("trace", "lower",
+                                            "backend_compile")]
+assert stages and recs[-1]["name"] == "ready"
+# traffic: no span is written by a lap after the mark
+n = len(goodput.window("startup"))
+laps0 = len(goodput.window("serve"))
+def client(seed):
+    for i in range(10):
+        model.generate([1 + seed, 2, 3 + i], max_new_tokens=8,
+                       timeout_ms=60000)
+threads = [threading.Thread(target=client, args=(k,)) for k in range(3)]
+[t.start() for t in threads]
+[t.join() for t in threads]
+laps = len(goodput.window("serve")) - laps0
+assert laps >= 50, laps
+after = goodput.window("startup")[n:]
+assert after == [], after
+repo.unload("lm", timeout=1.0)
+print("ok", laps)
+"""
+
+
+def test_load_generate_leaves_the_startup_account_and_traffic_adds_nothing(
+        tmp_path):
+    """ISSUE 46: a mini TransformerLM through `ModelRepository.load(
+    generate=True)` leaves import, artifact, engine_build, one program and
+    one first_run a bucket and one ready, in that order, and 50 laps of
+    traffic write no span (a process of its own: the package's import span
+    and the registry's misses are a fresh process's)."""
+    import subprocess
+    import sys
+
+    out = subprocess.run(
+        [sys.executable, "-c", _STARTUP_DRIVE, str(tmp_path / "lm")],
+        capture_output=True, text=True, timeout=300,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.stdout.strip().startswith("ok"), \
+        (out.stdout[-2000:], out.stderr[-4000:])
+
+
 def test_generate_on_predict_model_is_400(tmp_path):
     """:generate against a predict model answers a clear 400."""
     from mxnet_tpu import gluon

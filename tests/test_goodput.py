@@ -307,6 +307,354 @@ def test_slo_goodput_floor_objective(monkeypatch):
 
 
 # --------------------------------------------------------------------------
+# the start-up account (ISSUE 46): ring ``startup`` of whole span records
+# --------------------------------------------------------------------------
+
+def _spans(*names):
+    recs = goodput.window("startup")
+    return [r for r in recs if not names or r["name"] in names]
+
+
+def test_spans_nest_name_their_parent_and_give_self_time():
+    with goodput.span("engine_build", pool_bytes=7) as outer:
+        time.sleep(0.02)
+        with goodput.span("program", label="p") as inner:
+            time.sleep(0.03)
+            inner.fields["tier"] = "persist_hit"    # writable until the end
+    child, parent = _spans()
+    assert (child["name"], parent["name"]) == ("program", "engine_build")
+    assert child["parent"] == parent["id"] and parent["parent"] is None
+    assert parent["t0"] <= child["t0"] <= child["t1"] <= parent["t1"]
+    assert parent["pool_bytes"] == 7 and child["tier"] == "persist_hit"
+    assert not child["after_ready"]
+    # nothing was subtracted when the spans were written ...
+    assert parent["t1"] - parent["t0"] == pytest.approx(outer.elapsed)
+    assert outer.elapsed >= 0.05 and inner.elapsed >= 0.03
+    # ... a reader takes self time as duration less children
+    own = goodput.self_seconds(_spans())
+    assert own["program"] == pytest.approx(inner.elapsed)
+    assert own["engine_build"] == pytest.approx(outer.elapsed - inner.elapsed)
+
+
+def test_a_span_on_a_second_thread_does_not_adopt_the_first_threads_parent():
+    import threading
+
+    def other():
+        with goodput.span("artifact_read"):
+            pass
+
+    with goodput.span("engine_build"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+        with goodput.span("program"):
+            pass
+    by_name = {r["name"]: r for r in _spans()}
+    assert by_name["artifact_read"]["parent"] is None
+    assert by_name["program"]["parent"] == by_name["engine_build"]["id"]
+
+
+def test_spans_after_ready_are_kept_and_marked_as_after_it():
+    with goodput.span("artifact_read"):
+        pass
+    goodput.ready(model="lm/1")
+    with goodput.span("artifact_read"):      # a second model, later
+        pass
+    goodput.ready(model="lm/2")
+    recs = _spans()
+    assert [r["name"] for r in recs] == ["artifact_read", "ready",
+                                         "artifact_read", "ready"]
+    assert [r["after_ready"] for r in recs] == [False, False, True, True]
+    assert recs[1]["t0"] == recs[1]["t1"] and recs[1]["model"] == "lm/1"
+    # what the first mark published is the first start-up's, for good
+    block = goodput.startup_block()
+    assert block["ready"] and block["spans"] == 1
+    assert set(block["phases"]) == {"artifact_read", "total"}
+
+
+def test_a_back_dated_span_adopts_what_its_thread_wrote_inside_it():
+    t0 = time.perf_counter()
+    with goodput.span("program", label="inside"):
+        pass
+    with goodput.span("import", t0=t0):          # as mxnet_tpu/__init__.py
+        pass
+    inside, whole = _spans()
+    assert whole["name"] == "import" and whole["t0"] == t0
+    assert inside["parent"] == whole["id"] and whole["parent"] is None
+
+
+def test_the_package_import_is_the_accounts_first_span():
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import mxnet_tpu\n"
+         "from mxnet_tpu.telemetry import goodput\n"
+         "r = goodput.window('startup')\n"
+         "assert [x['name'] for x in r] == ['import'], r\n"
+         "assert r[0]['t1'] - r[0]['t0'] > 0.05 and r[0]['parent'] is None\n"
+         "assert goodput._STARTUP_T0 == r[0]['t0']\n"
+         "print('ok')"],
+        capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.stdout.strip() == "ok", out.stderr[-2000:]
+
+
+def test_startup_account_writes_nothing_with_the_accountant_off(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setenv("MXTPU_GOODPUT", "0")
+    with goodput.span("engine_build") as sp:
+        jax.jit(lambda x: x * 3 + 46)(jnp.ones(3))
+        time.sleep(0.005)
+    goodput.ready(model="lm/1")
+    assert goodput.window("startup") == []
+    assert sp.elapsed >= 0.005      # the owner's stamps work all the same
+    assert goodput.startup_block() == {"ready": False, "spans": 0}
+
+
+def test_a_span_is_an_annotation_where_a_profiler_session_is_on(monkeypatch):
+    seen = []
+
+    class FakeTraceMe:
+        def __init__(self, name, **kw):
+            self.name = name
+
+        @staticmethod
+        def is_enabled():
+            return True
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(goodput, "_trace_me", lambda: FakeTraceMe)
+    with goodput.span("artifact_read"):
+        with goodput.span("program"):
+            pass
+    assert seen == [("enter", "mxtpu.startup.artifact_read"),
+                    ("enter", "mxtpu.startup.program"),
+                    ("exit", "mxtpu.startup.program"),
+                    ("exit", "mxtpu.startup.artifact_read")]
+
+
+@pytest.mark.parametrize("held", [True, False])
+def test_jax_stages_are_children_of_the_span_that_holds_the_compile(held):
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones((4, 4)) + (2.0 if held else 3.0)  # eager glue, before
+    jax.block_until_ready(x)
+    goodput._reset_for_tests()
+    fn = jax.jit(lambda a: jnp.tanh(a) @ a + (46.0 if held else 47.0))
+    with goodput.span("program", label="unit") if held \
+            else contextlib.nullcontext():
+        fn(x).block_until_ready()
+    stages = _spans("trace", "lower", "backend_compile")
+    assert [r["name"] for r in stages] == ["trace", "lower",
+                                           "backend_compile"]
+    prog = _spans("program")
+    for r in stages:
+        assert r["parent"] == (prog[0]["id"] if held else None)
+        assert r["t1"] > r["t0"] and "<lambda>" in r["fun_name"]
+        if held:
+            assert prog[0]["t0"] <= r["t0"] and r["t1"] <= prog[0]["t1"]
+    # jnp.tanh and @ are jitted functions traced inside the lambda's trace:
+    # their seconds are its own, no span of theirs is written
+    assert stages[0]["t0"] < stages[1]["t0"] < stages[2]["t0"]
+    assert stages[2]["cache"] == "off"     # jax's cache is not armed here
+    n = len(goodput.window("startup"))
+    fn(x).block_until_ready()               # an execution fires nothing
+    assert len(goodput.window("startup")) == n
+
+
+@pytest.mark.parametrize("verdict", ["hit", "miss", "off"])
+def test_the_listeners_write_what_jax_says_of_its_cache(verdict):
+    from jax import monitoring
+
+    with goodput.span("import"):    # arms the listeners if nothing has yet
+        pass
+    goodput._reset_for_tests()
+    if verdict == "hit":
+        monitoring.record_event("/jax/compilation_cache/cache_hits")
+        monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.125)
+    elif verdict == "miss":
+        monitoring.record_event("/jax/compilation_cache/cache_misses")
+    t_before = time.perf_counter()
+    monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.5,
+        fun_name="jit(unit)")
+    (rec,) = goodput.window("startup")
+    assert rec["name"] == "backend_compile" and rec["cache"] == verdict
+    assert rec["fun_name"] == "jit(unit)" and rec["parent"] is None
+    # the end is the listener's call on the rings' clock, the start the
+    # handed duration before it
+    assert rec["t1"] >= t_before
+    assert rec["t1"] - rec["t0"] == pytest.approx(0.5)
+    assert rec.get("retrieval_s") == (0.125 if verdict == "hit" else None)
+    # a verdict is one compile's: the next one does not inherit it
+    monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.25, fun_name="next")
+    assert goodput.window("startup")[-1]["cache"] == "off"
+    assert "retrieval_s" not in goodput.window("startup")[-1]
+
+
+def test_a_second_compile_after_clear_caches_reads_cache_hit(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    x = jnp.arange(12.0).reshape(3, 4)
+    jax.block_until_ready(x)
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        cc.reset_cache()
+        said = []
+        for _ in range(2):
+            goodput._reset_for_tests()
+            jax.jit(lambda a: jnp.cos(a) * 46.25)(x).block_until_ready()
+            said.append([r["cache"] for r in _spans("backend_compile")])
+            jax.clear_caches()
+        assert said[0] == ["miss"]
+        if said[1] != ["hit"]:
+            pytest.skip("this backend's cache did not serve the program: "
+                        "%r (the listeners' part is the test above)" % said)
+        (rec,) = _spans("backend_compile")
+        assert rec["retrieval_s"] > 0.0
+        assert rec["retrieval_s"] <= rec["t1"] - rec["t0"] + 1e-3
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def _tiny_sharded_trainer(prefix):
+    from mxnet_tpu import gluon
+    from mxnet_tpu.gluon import nn, loss as gloss
+
+    ctx = mx.cpu()
+    with ctx:
+        net = nn.HybridSequential(prefix=prefix)
+        with net.name_scope():
+            net.add(nn.Dense(8, activation="relu", prefix="fc1_"))
+            net.add(nn.Dense(3, prefix="fc2_"))
+        net.initialize(ctx=ctx)
+    x = mx.nd.array(np.random.RandomState(0)
+                    .uniform(-1, 1, (8, 5)).astype(np.float32))
+    y = mx.nd.array(np.random.RandomState(1)
+                    .randint(0, 3, (8,)).astype(np.float32))
+    net(x)
+    goodput._reset_for_tests()
+    goodput._metrics()               # totals() reads from here on
+    with goodput.span("import"):     # stands for the package's, long past
+        time.sleep(0.01)
+    tr = gluon.Trainer(net.collect_params(), "sgd",
+                       {"learning_rate": 0.05}, sharded=True, block=net,
+                       loss=gloss.SoftmaxCrossEntropyLoss())
+    return tr, x, y
+
+
+def test_the_sharded_trainers_start_up_and_nothing_after_the_first_step():
+    tr, x, y = _tiny_sharded_trainer("su46_")
+    before = goodput.totals()
+    n_before = len(_spans("program"))
+    tr.step_batch(x, y).asnumpy()
+    recs = sorted(_spans(), key=lambda r: r["t0"])
+    top = [r["name"] for r in recs if r["parent"] is None]
+    assert top[:2] == ["import", "trainer_build"]
+    assert top[-2:] == ["first_run", "ready"]
+    first = [r for r in recs if r["name"] == "first_run"]
+    progs = [r for r in recs if r["name"] == "program"
+             and r.get("label") == "dist_trainer_step"]
+    assert len(first) == 1 and len(progs) == 1
+    assert progs[0]["parent"] == first[0]["id"]
+    assert progs[0]["kind"] == "sharded_step"
+    assert progs[0]["tier"] == "memory_miss"
+    # the fused step's three stages lie under its program span, whole
+    ids = {r["id"]: r for r in recs}
+
+    def under(r, span_id):
+        while r["parent"] is not None:
+            if r["parent"] == span_id:
+                return True
+            r = ids[r["parent"]]
+        return False
+
+    held = [r["name"] for r in recs if under(r, progs[0]["id"])]
+    for stage in ("trace", "lower", "backend_compile"):
+        assert stage in held
+    assert recs[-1]["name"] == "ready" and recs[-1]["trainer"] == "dist"
+    # the registry's clock and the account's are one: the compile phase's
+    # seconds are the program spans', to the letter
+    assert _phases_delta(before)["compile"] == pytest.approx(
+        sum(r["t1"] - r["t0"] for r in _spans("program")[n_before:]),
+        abs=1e-5)
+    n = len(goodput.window("startup"))
+    for _ in range(5):
+        tr.step_batch(x, y).asnumpy()
+    assert len(goodput.window("startup")) == n
+
+
+def test_first_step_startup_is_worked_out_from_the_account():
+    from mxnet_tpu.telemetry import slo
+
+    tr, x, y = _tiny_sharded_trainer("su46b_")
+    t_first = goodput.window("startup")[0]["t0"]
+    n0 = len([e for e in telemetry.events()
+              if e["event"] == "goodput_first_step"])
+    tr.step_batch(x, y).asnumpy()
+    events = [e for e in telemetry.events()
+              if e["event"] == "goodput_first_step"]
+    assert len(events) == n0 + 1
+    fields = events[-1]["fields"]
+    step = goodput.window("dist")[0]
+    # first span's start to the first step's start
+    assert fields["startup_s"] == pytest.approx(step["t0"] - t_first,
+                                                abs=2e-3)
+    assert fields["startup_s"] >= 0.01 and fields["step_wall_s"] > 0.0
+    assert events[-1]["ts"] == pytest.approx(time.time(), abs=60.0)
+    block = slo.statusz_payload()["training"]
+    assert block["first_step_startup_s"] == fields["startup_s"]
+    assert not hasattr(goodput, "_PROC_T0")
+
+
+def test_ready_sets_the_gauge_family_and_the_statusz_block():
+    from mxnet_tpu.telemetry import slo
+
+    assert slo.statusz_payload()["startup"] == {"ready": False, "spans": 0}
+    with goodput.span("import"):
+        time.sleep(0.01)
+    with goodput.span("engine_build"):
+        time.sleep(0.01)
+        with goodput.span("program"):
+            time.sleep(0.02)
+    time.sleep(0.01)            # nobody's: in ``total`` only
+    goodput.ready(model="lm/1")
+    block = slo.statusz_payload()["startup"]
+    assert block["ready"] and block["spans"] == 3
+    ph = block["phases"]
+    assert set(ph) == {"import", "engine_build", "program", "total"}
+    assert ph["total"] >= ph["import"] + ph["engine_build"] + ph["program"] \
+        + 0.009
+    assert ph["program"] >= 0.02 and ph["engine_build"] < 0.02
+    snap = telemetry.snapshot()
+    for name, seconds in ph.items():
+        g = snap['mxtpu_startup_phase_seconds{phase="%s"}' % name]
+        assert g["value"] == pytest.approx(seconds, abs=1e-3)
+    assert "startup: " in slo.render_statusz("text")[1].decode()
+
+
+# --------------------------------------------------------------------------
 # tools/goodput_report.py — synthetic ledger unit
 # --------------------------------------------------------------------------
 
