@@ -187,10 +187,11 @@ _var("MXTPU_PALLAS_LSTM", "str", "auto",
      "tests), `0` disables (lax.scan fallback).")
 _var("MXTPU_PALLAS_DECODE", "str", "auto",
      "Paged decode-attention kernel (`ops/pallas_kernels.paged_attention` "
-     "— flash-decode, q_len=1 against the block-allocated KV cache, page "
-     "tables via scalar prefetch): `auto` = kernel on TPU, dense-gather "
-     "jnp fallback elsewhere; `1` forces the kernel everywhere (interpret "
-     "mode on CPU — parity tests); `0` forces the jnp path; shapes the "
+     "— flash-decode, q_len=1 against the block-allocated KV cache, the "
+     "live pages copied by the kernel itself): `auto` = kernel on TPU, "
+     "dense-gather jnp fallback elsewhere; `1` forces the kernel "
+     "everywhere (interpret mode on CPU — parity tests); `0` forces the "
+     "jnp path; shapes the "
      "kernel cannot take go to the jnp path under any value. Read at trace "
      "time of each decode executable — flip it between processes, not "
      "mid-process.")

@@ -38,6 +38,16 @@ def _use_interpret():
     return jax.default_backend() != "tpu"
 
 
+def _decode_kernels_on():
+    """The decode kernels' gate, MXTPU_PALLAS_DECODE: `auto` = the kernels
+    on a TPU and the jnp paths elsewhere, `1` the kernels everywhere
+    (interpret mode off the chip: the parity tests), `0` the jnp paths."""
+    from .. import env as _env
+
+    gate = (_env.raw("MXTPU_PALLAS_DECODE") or "auto").strip().lower()
+    return gate != "0" and not (gate == "auto" and _use_interpret())
+
+
 def _attention_reference(q, k, v, causal, sm_scale):
     """Plain jnp attention (the vjp source for backward; also the numerics
     oracle in tests)."""
@@ -735,40 +745,51 @@ def lstm_layer(gx, wh, h0, c0):
 # sequence page table, not in one contiguous (L, D) slab. A dense gather
 # (k_pages[page_tables] -> (B, max_pages, ...)) materializes a batch-wide
 # padded COPY of every sequence's history in HBM per step; the Pallas
-# kernel instead streams one PAGE per grid step straight from the paged
-# array — the page table rides scalar-prefetch (SMEM), so the BlockSpec
-# index_map picks each sequence's next page and nothing is ever copied
-# out of the pool.
+# kernel instead leaves both pools in HBM and copies the live pages, and
+# only those, into two VMEM buffers of a BLOCK of `paged_pages_per_step`
+# pages each, the next block's pages streaming in under this block's
+# arithmetic. Nothing is ever copied out of the pool in HBM.
+#
+# The walk is a WORK LIST of the batch's live blocks, (sequence, block) in
+# order, which the kernel's scalar core writes to SMEM from `lengths` before
+# the first page moves (a few microseconds a call): one invocation, one loop
+# over the list, no grid. A grid over (sequence, block) pays for the TABLE,
+# not for the lengths: each grid step costs the pipeline 0.1-0.2 us an
+# operand whether it fetches or not, and with a page a BlockSpec operand a
+# step of 16 operands read 1.4 us (PR 45's chip runs, PERF.md section 6);
+# the list pays for what is live. A page's copy is issued from a loop over
+# the block's live pages, so the body holds one copy and one wait, not one
+# a page, and a block's rows arrive already stacked by rows in the buffer:
+# the body does not grow with the block.
 #
 # The pool is TOKEN-MAJOR: (pages, page_size, Cp), a page being page_size
 # rows of all heads' values side by side (head h owns lanes [h*D, (h+1)*D);
 # Cp is H*D rounded up to the 128-lane tile ONCE, by whoever allocates the
-# pool). A (page_size, Cp) block is whole (8, 128) tiles whatever H and D
+# pool). A (page_size, Cp) page is whole (8, 128) tiles whatever H and D
 # are, so the kernel reads the pool where it lies: no slice, no pad. The
-# per-head dot product is a sum over D adjacent lanes; a butterfly of lane
-# rotations leaves every head's score replicated over its own D lanes, so
-# the online softmax (m, l, acc) lives as (., Cp) rows with no per-head
-# reshape anywhere, all on the VPU in float32. (The MXU form, (q*k) @ a 0/1
-# head-indicator matrix, compiles too, but a 16-row page leaves it bound by
-# loading that matrix once a page, at three to six bf16 passes for float32.)
+# per-head dot product is a sum over D adjacent lanes, and the MXU takes it:
+# the block's q*k products (float32, on the VPU), every lane tile's and
+# every query head's of a group, are stacked by rows and multiplied once a
+# block by the 0/1 matrix of the heads' lane segments, which leaves every
+# head's score replicated over its own D lanes, so the online softmax (m, l,
+# acc) lives as (., Cp) rows with no per-head reshape anywhere and is updated
+# once a block. The block is what makes the MXU worth it: a page of 16 rows
+# alone is a product of 96 rows, bound by the load of the segment matrix (the
+# lane butterfly this form replaced, twelve rotations a vreg a page on the
+# XLU, was half the old kernel's time); 8 pages are 768 rows a load. A head
+# wider than a lane tile takes the same product with a (D, D) matrix of
+# ones: one body for every head size.
+#
+# Precision: a float32 product goes through the MXU as two bfloat16 terms
+# (16 bits of mantissa: scores good to 1e-5); p*v and the accumulators are
+# float32 on the VPU.
 #
 # Grouped-query (fewer KV heads than query heads): the `group` query heads
 # that share a KV head arrive as `group` rows of Cp lanes and each keeps a
-# row of the softmax state; a page's tile is read from VMEM once for all of
-# them. Their q*k products, every lane tile's and every group row's stacked
-# by rows, are summed inside the heads' lane segments by one MXU product a
-# page with the segments' 0/1 matrix (float32 as two bfloat16 terms) in
-# place of a butterfly a query head: with group*tiles*page_size = 256 rows a
-# product the matrix's load is paid once a page, which the one-row-a-head
-# form above could not amortise.
+# row of the softmax state; a block's tile is read from VMEM once for all of
+# them, and their products are further rows of the block's one product.
 #
-# Known bound: the grid is static (B, max_pages), one page a step, so a
-# short sequence still DMAs its table's padding pages (their arithmetic is
-# skipped) — the streamed bytes scale with max_pages, not actual length.
-#
-# Gate: MXTPU_PALLAS_DECODE — `auto` = kernel on TPU, jnp gather fallback
-# elsewhere; `1` forces the kernel everywhere (interpret mode on CPU —
-# the parity tests); `0` forces the jnp path. Shapes the kernel cannot
+# Gate: MXTPU_PALLAS_DECODE (`_decode_kernels_on`). Shapes the kernel cannot
 # take (`_paged_kernel_takes`) go to the jnp path whatever the gate says.
 # ---------------------------------------------------------------------------
 
@@ -810,147 +831,221 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
     return o.reshape(b, h, d).astype(q.dtype)
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest, sm_scale, ps,
-                  d, n_pages, group, mxu=False):
+def _paged_kernel(tbl_ref, len_ref, q_ref, seg_ref, k_hbm, v_hbm, o_ref, k_buf,
+                  v_buf, sem, seq_ref, blk_ref, m_scr, l_scr, acc_scr, *,
+                  sm_scale, ps, d, per_step, maxp, group, terms):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    seg_ref = rest[0] if mxu else None
-    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    cp = k_ref.shape[-1]
+    rows = per_step * ps               # a block's tokens
+    cp = q_ref.shape[-1]
+    w = max(128, d)                    # a lane tile, or one head if wider
+    tiles = list(range(0, cp, w))
 
-    @pl.when(j == 0)
-    def _():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def length_of(b):
+        return jnp.clip(len_ref[b], 0, maxp * ps)
 
-    @pl.when(j * ps < len_ref[b])      # a page past the length adds nothing
+    # the work list: every live block of every sequence, in order, written
+    # to SMEM by the scalar core before the first page moves
+    def list_blocks(b, n):
+        blocks = (length_of(b) + rows - 1) // rows
+
+        def one(j, carry):
+            seq_ref[n + j] = b
+            blk_ref[n + j] = j
+            return carry
+
+        jax.lax.fori_loop(0, blocks, one, 0)
+        return n + blocks
+
+    n = jax.lax.fori_loop(0, o_ref.shape[0], list_blocks, 0)
+
+    def copies(page, slot, g):
+        return [pltpu.make_async_copy(
+            pool.at[page], buf.at[slot, pl.ds(pl.multiple_of(g * ps, ps), ps)],
+            sem.at[slot]) for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf))]
+
+    def live_pages(i):
+        # work item i's sequence, its block's first page and how many of the
+        # block's pages hold live tokens (every listed block holds one)
+        b, first = seq_ref[i], blk_ref[i] * per_step
+        return b, first, jnp.minimum(
+            (length_of(b) + ps - 1) // ps - first, per_step)
+
+    def fetch(i, slot):
+        b, first, count = live_pages(i)
+
+        def one(g, carry):
+            for copy in copies(tbl_ref[b * maxp + first + g], slot, g):
+                copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, count, one, 0)
+
+    def arrive(i, slot):
+        def one(g, carry):
+            for copy in copies(0, slot, 0):    # a page's bytes, whichever
+                copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, live_pages(i)[2], one, 0)
+
+    # a sequence of length 0 is on no list: zeros, as its empty sum reads.
+    # Rows of a buffer that no page has reached yet are masked by position,
+    # so they only have to be finite
+    o_ref[...] = jnp.zeros_like(o_ref)
+    k_buf[...] = jnp.zeros_like(k_buf)
+    v_buf[...] = jnp.zeros_like(v_buf)
+
+    @pl.when(n > 0)
     def _():
-        # a lane tile (or one head, if wider) at a time: its heads' scores,
-        # softmax state and output never meet another tile's, and a rotation
-        # inside one tile is a single-vreg operation
-        w = max(128, d)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (ps, w), 1)
-        row = j * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, w), 0)
-        live = row < len_ref[b]
-        if mxu:
-            # every (lane tile, query head of the group)'s q*k stacked by
-            # rows, summed inside each head's D lanes by ONE product with
-            # the 0/1 matrix of the heads' segments (the same for every
-            # tile), which leaves each head's score on all its lanes as the
-            # butterfly does; the float32 products go through the MXU as two
-            # bfloat16 terms (16 bits of mantissa: scores good to 1e-5)
+        fetch(0, 0)
+
+    def block(i, carry):
+        slot = i % 2
+        b, j = seq_ref[i], blk_ref[i]
+        length = length_of(b)
+
+        @pl.when(i + 1 < n)            # the next block streams under this one
+        def _():
+            fetch(i + 1, 1 - slot)
+
+        @pl.when(j == 0)
+        def _():
+            m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        arrive(i, slot)
+        live = j * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, w), 0) < length
+        # every (lane tile, query head of the group)'s q*k over the block's
+        # rows stacked by rows, summed inside each head's D lanes by ONE
+        # product with the 0/1 matrix of the heads' segments (the same for
+        # every tile), which leaves each head's score on all its lanes; a
+        # float32 product goes through the MXU as `terms` bfloat16 terms of
+        # 8 bits of mantissa each. 2048 rows a product at most
+        chunk = max(1, 2048 // (group * rows))
+        for t0 in range(0, len(tiles), chunk):
+            cs = tiles[t0:t0 + chunk]
             prod = jnp.concatenate(
-                [q_ref[0, g:g + 1, c:c + w].astype(jnp.float32) * sm_scale
-                 * k_ref[0, :, c:c + w].astype(jnp.float32)
-                 for c in range(0, cp, w) for g in range(group)], axis=0)
-            hi = prod.astype(jnp.bfloat16)
-            lo = (prod - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-            s_all = jnp.dot(jnp.concatenate([hi, lo], axis=0), seg_ref[...],
-                            preferred_element_type=jnp.float32)
-            s_all = s_all[:prod.shape[0]] + s_all[prod.shape[0]:]
-        for c in range(0, cp, w):
-            # the page's tile is read once; the `group` query heads that
-            # share each KV head (row g of q, of the state and of the output)
-            # take it in turn
-            k = k_ref[0, :, c:c + w].astype(jnp.float32)             # (ps, w)
-            v = v_ref[0, :, c:c + w].astype(jnp.float32)
-            for g in range(group):
-                if mxu:
-                    at = ((c // w) * group + g) * ps
-                    s = s_all[at:at + ps]
-                else:
-                    q = q_ref[0, g:g + 1, c:c + w].astype(jnp.float32) \
-                        * sm_scale                                   # (1, w)
-                    # every head's q.k at once: multiply, then all-reduce
-                    # inside each head's D lanes (D a power of two; lane i's
-                    # partner at stride sh is i ^ sh, which never leaves the
-                    # head's segment)
-                    s = q * k
-                    sh = 1
-                    while sh < d:
-                        s = s + jnp.where((lane & sh) == 0,
-                                          pltpu.roll(s, w - sh, 1),
-                                          pltpu.roll(s, sh, 1))
-                        sh *= 2
-                s = jnp.where(live, s, _NEG_INF)
-                m = m_scr[g:g + 1, c:c + w]
-                new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-                alpha = jnp.exp(m - new_m)
-                p = jnp.exp(s - new_m)                               # (ps, w)
-                l = l_scr[g:g + 1, c:c + w] * alpha + jnp.sum(
-                    p, axis=0, keepdims=True)
-                acc = acc_scr[g:g + 1, c:c + w] * alpha + jnp.sum(
-                    p * v, axis=0, keepdims=True)
-                if group == 1:
-                    m_scr[:, c:c + w] = jnp.broadcast_to(new_m, (8, w))
-                    l_scr[:, c:c + w] = jnp.broadcast_to(l, (8, w))
-                    acc_scr[:, c:c + w] = jnp.broadcast_to(acc, (8, w))
-                else:
+                [q_ref[b, g:g + 1, c:c + w].astype(jnp.float32) * sm_scale
+                 * k_buf[slot, :, c:c + w].astype(jnp.float32)
+                 for c in cs for g in range(group)], axis=0)
+            s_all = None
+            for t in range(terms):
+                term = prod.astype(jnp.bfloat16)
+                part = jnp.dot(term, seg_ref[...],
+                               preferred_element_type=jnp.float32)
+                s_all = part if s_all is None else s_all + part
+                if t + 1 < terms:
+                    prod = prod - term.astype(jnp.float32)
+            for at, c in enumerate(cs):
+                v = v_buf[slot, :, c:c + w].astype(jnp.float32)  # (rows, w)
+                for g in range(group):
+                    # row g of q, of the state and of the output: the g-th
+                    # of the query heads that share each KV head
+                    lo = (at * group + g) * rows
+                    s = jnp.where(live, s_all[lo:lo + rows], _NEG_INF)
+                    m = m_scr[g:g + 1, c:c + w]
+                    new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+                    alpha = jnp.exp(m - new_m)
+                    p = jnp.exp(s - new_m)                       # (rows, w)
                     m_scr[g:g + 1, c:c + w] = new_m
-                    l_scr[g:g + 1, c:c + w] = l
-                    acc_scr[g:g + 1, c:c + w] = acc
+                    l_scr[g:g + 1, c:c + w] = l_scr[g:g + 1, c:c + w] \
+                        * alpha + jnp.sum(p, axis=0, keepdims=True)
+                    acc_scr[g:g + 1, c:c + w] = acc_scr[g:g + 1, c:c + w] \
+                        * alpha + jnp.sum(p * v, axis=0, keepdims=True)
 
-    @pl.when(j == n_pages - 1)
-    def _():
-        o_ref[0] = (acc_scr[0:group, :]
-                    / jnp.maximum(l_scr[0:group, :], 1e-30)
-                    ).astype(o_ref.dtype)
+        @pl.when((j + 1) * rows >= length)     # the sequence's last block
+        def _():
+            o_ref[b] = (acc_scr[0:group, :]
+                        / jnp.maximum(l_scr[0:group, :], 1e-30)
+                        ).astype(o_ref.dtype)
+
+        return carry
+
+    jax.lax.fori_loop(0, n, block, 0)
 
 
-def _paged_kernel_takes(d, ps, cp, pool_dtype, group=1):
-    """Whether the Pallas kernel can read a pool of this form: the lane
-    butterfly needs a power-of-two head size, a page must be whole sublane
-    tiles of the pool's dtype (8 rows of 32 bits, 16 of 16, 32 of 8), its
-    rows whole 128-lane tiles, and the query heads that share a KV head
-    one row each of the (8, Cp) softmax state."""
-    sublanes = 32 // _np.dtype(pool_dtype).itemsize
-    return (d & (d - 1) == 0 and ps % sublanes == 0 and cp % 128 == 0
-            and 1 <= group <= 8)
+def paged_pages_per_step(ps, maxp):
+    """Pages a block of the paged decode kernel holds: about 128 tokens'
+    worth, at most a sequence's pages."""
+    return max(1, min(128 // ps, maxp))
+
+
+# bfloat16 terms a q*k product goes through the MXU as: two keep 16 bits of
+# mantissa (scores good to 1e-5), which is all a bfloat16 pool's products have
+_PAGED_TERMS = 2
+
+
+def _paged_vmem_bytes(b, cp, ps, maxp, itemsize, group):
+    """VMEM the kernel's call holds: two blocks of K and of V, every
+    sequence's query and output rows (a group padded to a sublane tile), the
+    stacked products and the softmax state."""
+    rows = paged_pages_per_step(ps, maxp) * ps
+    sublanes = 32 // itemsize
+    return (4 * rows * cp * itemsize
+            + 2 * b * -(-group // sublanes) * sublanes * cp * itemsize
+            + 6 * min(2048, group * rows * cp // 128) * 128 * 4
+            + 3 * 8 * cp * 4)
+
+
+_PAGED_VMEM_LIMIT = 96 << 20     # of a v5e core's 128 MiB
+
+
+def _paged_kernel_takes(d, ps, cp, pool_dtype, group=1, b=1, maxp=8):
+    """Whether the Pallas kernel can read a pool of this form: a head's
+    lanes must tile the 128 lanes of the segment product or be whole lane
+    tiles (a power-of-two head size), a page must be whole sublane tiles of
+    the pool's dtype (8 rows of 32 bits, 16 of 16, 32 of 8), its rows whole
+    128-lane tiles, the query heads that share a KV head one row each of
+    the (8, Cp) softmax state, and the batch's query and output rows must
+    fit VMEM beside the blocks."""
+    itemsize = _np.dtype(pool_dtype).itemsize
+    return (d & (d - 1) == 0 and ps % (32 // itemsize) == 0
+            and cp % 128 == 0 and 1 <= group <= 8
+            and _paged_vmem_bytes(b, cp, ps, maxp, itemsize, group)
+            <= _PAGED_VMEM_LIMIT)
 
 
 @functools.lru_cache(maxsize=128)
 def _paged_compiled(key):
-    (b, d, cp, maxp, ps, dtype, sm_scale, interpret, group, mxu) = key
+    (b, d, cp, maxp, ps, dtype, pool_dtype, sm_scale, interpret, group) = key
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    def page(bb, j, tbl, lens):
-        # the paged gather: the page table names which KV page this grid
-        # step streams into VMEM
-        return (tbl[bb, j], 0, 0)
-
-    def row(bb, j, tbl, lens):
-        return (bb, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # page_tables, lengths (SMEM)
-        grid=(b, maxp),
-        in_specs=[
-            pl.BlockSpec((1, group, cp), row, memory_space=pltpu.VMEM),  # q
-            pl.BlockSpec((1, ps, cp), page, memory_space=pltpu.VMEM),  # k
-            pl.BlockSpec((1, ps, cp), page, memory_space=pltpu.VMEM),  # v
-        ] + ([pl.BlockSpec((128, 128), lambda bb, j, tbl, lens: (0, 0),
-                           memory_space=pltpu.VMEM)] if mxu else []),
-        out_specs=pl.BlockSpec((1, group, cp), row,
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((8, cp), jnp.float32),     # m
-                        pltpu.VMEM((8, cp), jnp.float32),     # l
-                        pltpu.VMEM((8, cp), jnp.float32)],    # acc
-    )
+    per_step = paged_pages_per_step(ps, maxp)
+    itemsize = _np.dtype(pool_dtype).itemsize
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    pool = pl.BlockSpec(memory_space=pl.ANY)      # stays in HBM, never copied
+    items = b * -(-maxp // per_step)              # the longest work list
     return pl.pallas_call(
-        functools.partial(_paged_kernel, sm_scale=sm_scale, ps=ps, d=d,
-                          n_pages=maxp, group=group, mxu=mxu),
+        functools.partial(
+            _paged_kernel, sm_scale=sm_scale, ps=ps, d=d, per_step=per_step,
+            maxp=maxp, group=group, terms=_PAGED_TERMS),
         name="paged_attention_decode",
         out_shape=jax.ShapeDtypeStruct((b, group, cp), _np.dtype(dtype)),
-        grid_spec=grid_spec,
+        # page table, lengths; the queries, the heads' segments; the pools
+        in_specs=[smem] * 2 + [vmem] * 2 + [pool] * 2,
+        out_specs=vmem,
+        scratch_shapes=[
+            pltpu.VMEM((2, per_step * ps, cp), _np.dtype(pool_dtype)),  # K
+            pltpu.VMEM((2, per_step * ps, cp), _np.dtype(pool_dtype)),  # V
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((items,), jnp.int32),      # work list: sequence,
+            pltpu.SMEM((items,), jnp.int32),      # block
+            pltpu.VMEM((8, cp), jnp.float32),     # m
+            pltpu.VMEM((8, cp), jnp.float32),     # l
+            pltpu.VMEM((8, cp), jnp.float32)],    # acc
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=int(max(
+            32 << 20, _paged_vmem_bytes(b, cp, ps, maxp, itemsize, group)
+            + (8 << 20)))),
         interpret=interpret,
     )
 
@@ -972,50 +1067,45 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths,
     ``page_tables[b, t // page_size]`` row ``t % page_size``; entries
     past the sequence's used pages must still be VALID page indices
     (they are masked by ``lengths``, never dereferenced out of bounds).
-    lengths: (B,) int32 live-token counts (0 disables a padding row).
+    lengths: (B,) int32 live-token counts (0 disables a padding row, whose
+    output is zeros).
 
     A head size that is no power of two, a page that is not whole sublane
-    tiles of the pool's dtype, a Cp off the lane tile or more than 8 query
-    heads a KV head goes to `paged_attention_reference`: decided from the
-    shapes alone.
+    tiles of the pool's dtype, a Cp off the lane tile, more than 8 query
+    heads a KV head or a batch whose rows do not fit VMEM goes to
+    `paged_attention_reference`: decided from the shapes alone.
     """
     import jax.numpy as jnp
-
-    from .. import env as _env
 
     if sm_scale is None:
         sm_scale = 1.0 / float(_np.sqrt(q.shape[-1]))
     sm_scale = float(sm_scale)
     b, h, d = q.shape
     _, ps, cp = k_pages.shape
+    maxp = page_tables.shape[1]
     kv = h if kv_heads is None else int(kv_heads)
     if h % kv or kv * d > cp:
         raise ValueError("%d query heads cannot share %d KV heads of %d in "
                          "a pool row of %d lanes" % (h, kv, d, cp))
     group = h // kv
-    gate = (_env.raw("MXTPU_PALLAS_DECODE") or "auto").strip().lower()
-    interpret = _use_interpret()
-    if (gate == "0" or (gate == "auto" and interpret)
-            or not _paged_kernel_takes(d, ps, cp, k_pages.dtype, group)):
+    if not (_decode_kernels_on() and _paged_kernel_takes(
+            d, ps, cp, k_pages.dtype, group, b, maxp)):
         return paged_attention_reference(q, k_pages, v_pages, page_tables,
                                          lengths, sm_scale, kv)
-    # grouped-query heads inside one lane tile: the lanes' sums go through
-    # the MXU, one product a page (on the chip 2.2 ms a call at LFM2's
-    # shapes where the butterfly once a query head took 7.8: PERF.md)
-    mxu = group > 1 and d <= 128
-    call = _paged_compiled((b, d, cp, page_tables.shape[1], ps,
-                            str(q.dtype), sm_scale, interpret, group, mxu))
+    call = _paged_compiled((b, d, cp, maxp, ps, str(q.dtype),
+                            str(k_pages.dtype), sm_scale, _use_interpret(),
+                            group))
     # row g holds, for every KV head, the g-th of the query heads that
     # share it, in that KV head's lanes
     rows = q.reshape(b, kv, group, d).transpose(0, 2, 1, 3) \
         .reshape(b, group, kv * d)
     rows = jnp.pad(rows, ((0, 0), (0, 0), (0, cp - kv * d)))
-    extra = ()
-    if mxu:
-        lanes = jnp.arange(128) // d
-        extra = ((lanes[:, None] == lanes[None, :]).astype(jnp.bfloat16),)
-    out = call(page_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-               rows, k_pages, v_pages, *extra)
+    # lanes of one head: a 0/1 block on the diagonal (one block of all ones
+    # where a head is wider than a lane tile)
+    lanes = jnp.arange(max(128, d)) // d
+    seg = (lanes[:, None] == lanes[None, :]).astype(jnp.bfloat16)
+    out = call(page_tables.astype(jnp.int32).reshape(-1),
+               lengths.astype(jnp.int32), rows, seg, k_pages, v_pages)
     return out[:, :, :kv * d].reshape(b, group, kv, d) \
         .transpose(0, 2, 1, 3).reshape(b, h, d)
 
@@ -1033,8 +1123,8 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths,
 # past `rank` of that product are dropped by the caller: slicing the page in
 # VMEM would need `rank` on a lane tile).
 #
-# Grid (B, blocks of `per_step` pages): `paged_attention_decode`'s static
-# grid costs 0.23 us a step whatever it does (PERF.md section 5), so a step
+# Grid (B, blocks of `per_step` pages): a grid step costs 0.23 us whatever
+# it does (PERF.md section 5: a page a step was that cost), so a step
 # takes several pages, each through a BlockSpec of its own on the one pool,
 # and a block past a sequence's length names the sequence's last live page
 # again: nothing is fetched for it and its arithmetic is skipped.
@@ -1189,28 +1279,44 @@ def paged_latent_attention(q, pages, page_tables, lengths, sm_scale, rank):
     MXTPU_PALLAS_DECODE, as for `paged_attention`."""
     import jax.numpy as jnp
 
-    from .. import env as _env
-
     sm_scale = float(sm_scale)
     b, h, r = q.shape
     _, ps, cp = pages.shape
     if not rank <= r <= cp:
         raise ValueError("a query of %d lanes over rows of %d with %d "
                          "compressed lanes" % (r, cp, rank))
-    gate = (_env.raw("MXTPU_PALLAS_DECODE") or "auto").strip().lower()
-    interpret = _use_interpret()
-    if (gate == "0" or (gate == "auto" and interpret)
-            or not _latent_kernel_takes(ps, cp, pages.dtype)):
+    if not (_decode_kernels_on()
+            and _latent_kernel_takes(ps, cp, pages.dtype)):
         return paged_latent_attention_reference(q, pages, page_tables,
                                                 lengths, sm_scale, rank)
     maxp = page_tables.shape[1]
     per_step = latent_pages_per_step(ps, maxp)
     call = _latent_compiled((b, h, cp, maxp, ps, str(q.dtype), sm_scale,
-                             interpret, per_step))
+                             _use_interpret(), per_step))
     rows = jnp.pad(q.astype(pages.dtype), ((0, 0), (0, 0), (0, cp - r)))
     out = call(page_tables.astype(jnp.int32), lengths.astype(jnp.int32),
                rows, *([pages] * per_step))
     return out[:, :, :rank].astype(q.dtype)
+
+
+def decode_attention_form(latent, d, ps, cp, pool_dtype, group, batch, maxp):
+    """What an engine's decode attention runs at these shapes, for the
+    engine to publish (`TransformerLMEngine.geometry`, the `engine_build`
+    span): the kernel's name, the pages it takes at a time and whether a
+    head's lanes are summed on the MXU; the jnp path's where the gate or the
+    shapes send the call there. `paged_attention` and
+    `paged_latent_attention` decide by the same functions."""
+    if latent:
+        kernel = _latent_kernel_takes(ps, cp, pool_dtype)
+        name, per_step = "paged_latent_attention_decode", \
+            latent_pages_per_step(ps, maxp)
+    else:
+        kernel = _paged_kernel_takes(d, ps, cp, pool_dtype, group, batch, maxp)
+        name, per_step = "paged_attention_decode", \
+            paged_pages_per_step(ps, maxp)
+    if not (kernel and _decode_kernels_on()):
+        return {"kernel": None, "pages_per_step": maxp, "lanes_on_mxu": False}
+    return {"kernel": name, "pages_per_step": per_step, "lanes_on_mxu": True}
 
 
 # ---------------------------------------------------------------------------
@@ -1360,16 +1466,11 @@ def moe_grouped_ffn(xs, tile_expert, n_tiles, w1, w3, w2, tm):
     `moe_grouped_ffn_reference`, decided from the shapes alone."""
     import jax.numpy as jnp
 
-    from .. import env as _env
-
     tiles = tile_expert.shape[0]
     c, f = xs.shape[-1], w1.shape[1]
-    gate = (_env.raw("MXTPU_PALLAS_DECODE") or "auto").strip().lower()
-    interpret = _use_interpret()
-    if (gate == "0" or (gate == "auto" and interpret)
-            or not _moe_kernel_takes(tm, c, f, xs.dtype)):
+    if not (_decode_kernels_on() and _moe_kernel_takes(tm, c, f, xs.dtype)):
         return moe_grouped_ffn_reference(xs, tile_expert, n_tiles, w1, w3,
                                          w2, tm)
-    call = _moe_compiled((tiles, tm, c, f, str(xs.dtype), interpret))
+    call = _moe_compiled((tiles, tm, c, f, str(xs.dtype), _use_interpret()))
     return call(tile_expert.astype(jnp.int32), n_tiles.astype(jnp.int32),
                 xs, w1, w3, w2)

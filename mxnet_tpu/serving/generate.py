@@ -29,8 +29,8 @@ repo already has:
     (prompt + max_new_tokens), so a running batch can never deadlock on
     the pool.
   * **decode attention** runs the flash-decode Pallas kernel
-    (`ops/pallas_kernels.paged_attention` — page tables via scalar
-    prefetch, online softmax over streamed pages) on TPU, the dense-
+    (`ops/pallas_kernels.paged_attention` — a work list of live blocks
+    of pages, online softmax over the streamed blocks) on TPU, the dense-
     gather jnp fallback elsewhere (`MXTPU_PALLAS_DECODE`).
   * **sampling** (greedy / temperature / top-k / top-p) is folded into
     the decode executable with PER-ROW parameter arrays
@@ -1160,6 +1160,14 @@ class TransformerLMEngine:
             self._fingerprint = hashlib.sha256(json.dumps(
                 ident, sort_keys=True).encode()).hexdigest()[:32]
             build.fields["pool_bytes"] = self.kv_bytes()
+            # how the decode attention walks the pool at the widest bucket:
+            # static for the engine, so geometry() and this span carry it
+            from ..ops.pallas_kernels import decode_attention_form
+            build.fields["kernel_form"] = self.kernel_form = \
+                decode_attention_form(
+                    self.latent, self.head_dim, self.page_size, leaf[-1],
+                    self.kv_dtype, self.num_heads // self.kv_heads,
+                    self.buckets[-1], self.max_pages_per_seq)
 
     # -- sizing ------------------------------------------------------------
     def kv_bytes(self):
@@ -1184,7 +1192,8 @@ class TransformerLMEngine:
                 "kv_dtype": self.kv_dtype,
                 "state_slots": self.state_slots,
                 "kv_bytes": self.kv_bytes(),
-                "param_bytes": self.param_bytes()}
+                "param_bytes": self.param_bytes(),
+                "kernel_form": dict(self.kernel_form)}
 
     # -- executables -------------------------------------------------------
     def _key(self, kind, shape_sig):

@@ -123,35 +123,63 @@ def _paged_inputs(seed, b, h, d, pages, ps, maxp, dtype):
 
 
 # ragged lengths: a FULL table, a page-straddling row, a 1-token row and an
-# INERT row (length 0 — the scheduler's batch padding)
-@pytest.mark.parametrize("h,d,ps,maxp,dtype,tol", [
-    (12, 64, 16, 6, "float32", 2e-6),     # GPT-2 small's heads: Cp = 768
-    (2, 32, 8, 5, "float32", 2e-6),       # tiny, unaligned: Cp padded to 128
-    (3, 16, 8, 4, "float32", 2e-6),       # odd head count, padded lanes
-    (16, 128, 8, 3, "float32", 2e-6),     # a head is a whole lane tile
-    (2, 256, 8, 3, "float32", 2e-6),      # a head is two lane tiles
-    (12, 64, 16, 6, "bfloat16", 4e-2),    # bf16 pool: sublane tile 16
+# INERT row (length 0 — the scheduler's batch padding). The kernel walks a
+# work list of live blocks (`pk.paged_pages_per_step` pages each: 8 pages of
+# 16, 16 of 8), so the later cases put lengths on a block's edges, end the
+# rows of one batch in different blocks, give the table a length that is no
+# multiple of a block, and name page 0 in every dead entry, as the engine's
+# tables do. float32 at 2e-5: a head's lanes are summed on the MXU as two
+# bfloat16 terms (the lane butterfly's float32 sums read 2e-6)
+_LENS = "full, a page and a row, one row, inert"
+@pytest.mark.parametrize("h,d,ps,maxp,dtype,tol,lens,zero_dead", [
+    (12, 64, 16, 6, "float32", 2e-5, _LENS, False),    # GPT-2 small: Cp 768
+    (2, 32, 8, 5, "float32", 2e-5, _LENS, False),      # Cp padded to 128
+    (3, 16, 8, 4, "float32", 2e-5, _LENS, False),      # odd head count
+    (16, 128, 8, 3, "float32", 2e-5, _LENS, False),    # a head is a lane tile
+    (2, 256, 8, 3, "float32", 2e-5, _LENS, False),     # a head is two tiles
+    (12, 64, 16, 6, "bfloat16", 4e-2, _LENS, False),   # bf16: sublane tile 16
+    # a table of 11 pages is a block of 8 and a part of one: the whole
+    # table, exactly one block, one row into the next block, inert
+    (12, 64, 16, 11, "float32", 2e-5, [176, 128, 129, 0], False),
+    # rows that end in the third, second, first and second block
+    (12, 64, 16, 20, "float32", 2e-5, [320, 130, 17, 255], False),
+    # dead entries all name page 0; an inert row between live ones
+    (12, 64, 16, 20, "float32", 2e-5, [129, 0, 320, 1], True),
+    (12, 64, 16, 11, "bfloat16", 4e-2, [176, 0, 129, 128], True),
+    # pages of 8: a block is 16 pages, the table 19
+    (2, 32, 8, 19, "float32", 2e-5, [152, 128, 129, 1], False),
+    # a table shorter than a block: the block is the table
+    (2, 32, 8, 3, "float32", 2e-5, [24, 0, 0, 9], True),
 ], ids=["gpt2s-f32", "tiny-f32", "odd-heads-f32", "wide-head-f32",
-        "two-tile-head-f32", "gpt2s-bf16"])
+        "two-tile-head-f32", "gpt2s-bf16", "table-off-the-block",
+        "rows-end-in-different-blocks", "dead-entries-name-page-0",
+        "dead-entries-name-page-0-bf16", "blocks-of-16-pages",
+        "table-shorter-than-a-block"])
 def test_paged_attention_pallas_vs_jnp(monkeypatch, h, d, ps, maxp, dtype,
-                                       tol):
+                                       tol, lens, zero_dead):
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import pallas_kernels as pk
 
-    q, kp, vp, tbl = _paged_inputs(7, 4, h, d, 16, ps, maxp, dtype)
-    lens = jnp.asarray([maxp * ps, ps + 1, 1, 0], jnp.int32)
+    q, kp, vp, tbl = _paged_inputs(7, 4, h, d, 32, ps, maxp, dtype)
+    if lens is _LENS:
+        lens = [maxp * ps, ps + 1, 1, 0]
+    if zero_dead:
+        tbl = jnp.where(jnp.arange(maxp)[None, :]
+                        < -(-np.asarray(lens)[:, None] // ps), tbl, 0)
+    lens = jnp.asarray(lens, jnp.int32)
+    live = np.asarray(lens) > 0
     assert pk._paged_kernel_takes(d, ps, kp.shape[-1], kp.dtype)
     ref = pk.paged_attention_reference(q, kp, vp, tbl, lens,
                                        1.0 / np.sqrt(d))
     monkeypatch.setenv("MXTPU_PALLAS_DECODE", "1")   # force the kernel
     out = pk.paged_attention(q, kp, vp, tbl, lens)
     assert out.shape == q.shape and out.dtype == q.dtype
-    # live rows match to dtype tolerance; the inert row is unused garbage
-    err = np.max(np.abs(np.asarray(ref, np.float32)[:3]
-                        - np.asarray(out, np.float32)[:3]))
+    # live rows match to dtype tolerance; an inert row reads zeros
+    err = np.max(np.abs(np.asarray(ref, np.float32)[live]
+                        - np.asarray(out, np.float32)[live]))
     assert err < tol, err
-    assert np.all(np.isfinite(np.asarray(out, np.float32)))
+    assert not np.any(np.asarray(out, np.float32)[~live])
 
 
 def test_paged_attention_reference_is_dense_attention():
@@ -215,6 +243,39 @@ def test_paged_attention_refused_shapes_fall_to_reference(monkeypatch, h, d,
                           np.asarray(ref, np.float32))
 
 
+@pytest.mark.parametrize("gate,latent,d,ps,cp,dtype,group,maxp,want", [
+    # the GPT-2 cells and the LFM2 cell: blocks of 8 pages of 16
+    ("1", False, 64, 16, 768, "float32", 1, 44,
+     ("paged_attention_decode", 8, True)),
+    ("1", False, 64, 16, 512, "bfloat16", 4, 64,
+     ("paged_attention_decode", 8, True)),
+    # pages of 8: 16 a block; a table shorter than a block
+    ("1", False, 32, 8, 128, "float32", 1, 44,
+     ("paged_attention_decode", 16, True)),
+    ("1", False, 32, 16, 128, "float32", 1, 3,
+     ("paged_attention_decode", 3, True)),
+    # the latent kernel's own rule (GigaChat3's pages of 128)
+    ("1", True, 576, 128, 640, "bfloat16", 64, 38,
+     ("paged_latent_attention_decode", 2, True)),
+    # refused shapes and a closed gate: the jnp path reads the whole table
+    ("1", False, 24, 8, 128, "float32", 1, 6, (None, 6, False)),
+    ("1", False, 32, 8, 128, "bfloat16", 1, 6, (None, 6, False)),
+    ("0", False, 64, 16, 768, "float32", 1, 44, (None, 44, False)),
+    ("auto", False, 64, 16, 768, "float32", 1, 44, (None, 44, False)),
+])
+def test_decode_attention_form_says_what_runs(monkeypatch, gate, latent, d,
+                                              ps, cp, dtype, group, maxp,
+                                              want):
+    """What an engine publishes as `kernel_form` is decided by the
+    functions the calls themselves decide by."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setenv("MXTPU_PALLAS_DECODE", gate)
+    form = pk.decode_attention_form(latent, d, ps, cp, dtype, group, 64, maxp)
+    assert (form["kernel"], form["pages_per_step"],
+            form["lanes_on_mxu"]) == want
+
+
 def test_paged_attention_gate_fallback(monkeypatch):
     """`0` forces the jnp path; `auto` off-TPU is the jnp path too — all
     three spellings agree numerically."""
@@ -229,7 +290,7 @@ def test_paged_attention_gate_fallback(monkeypatch):
         monkeypatch.setenv("MXTPU_PALLAS_DECODE", gate)
         outs[gate] = np.asarray(pk.paged_attention(q, kp, vp, tbl, lens))
     assert np.allclose(outs["0"], outs["auto"])
-    assert np.max(np.abs(outs["0"] - outs["1"])) < 2e-6
+    assert np.max(np.abs(outs["0"] - outs["1"])) < 2e-5
 
 
 # ---------------------------------------------------------------------------
@@ -889,6 +950,10 @@ assert by_name["artifact_read"]["bytes"] == by_name["artifact_write"]["bytes"]
 assert by_name["artifact_read"]["bytes"] > 0
 assert by_name["artifact_read"]["arrays"] == len(lm.collect_params())
 assert by_name["engine_build"]["pool_bytes"] == model.generate_info["kv_bytes"]
+# pages of 4 rows are no float32 sublane tile: the jnp path, and both say so
+assert by_name["engine_build"]["kernel_form"] == \
+    model.generate_info["kernel_form"] == {
+        "kernel": None, "pages_per_step": 5, "lanes_on_mxu": False}
 assert by_name["ready"]["model"] == "lm/1"
 assert not any(r["after_ready"] for r in recs)
 stages = [r for r in recs if r["name"] in ("trace", "lower",

@@ -182,20 +182,30 @@ def test_precision_float32_tightens_and_float8_breaks(weights):
 # grouped-query paged attention: the kernel (interpret mode) and its oracle
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("dtype,heads,kv,d", [
-    ("float32", 4, 2, 32), ("float32", 8, 2, 64), ("bfloat16", 8, 4, 32),
-    ("float32", 4, 4, 32)])
-def test_grouped_query_paged_attention_matches_its_oracle(monkeypatch, dtype,
-                                                          heads, kv, d):
+@pytest.mark.parametrize("dtype,heads,kv,d,maxp,lengths", [
+    ("float32", 4, 2, 32, 3, [5, 33, 48]),
+    ("float32", 8, 2, 64, 3, [5, 33, 48]),
+    ("bfloat16", 8, 4, 32, 3, [5, 33, 48]),
+    ("float32", 4, 4, 32, 3, [5, 33, 48]),
+    # the kernel walks blocks of 8 pages: a table of 19 (two blocks and a
+    # part), rows that end in the first, second and third block, a row one
+    # token into a block, an inert row
+    ("float32", 8, 2, 64, 19, [5, 129, 304]),
+    ("bfloat16", 32, 8, 64, 19, [0, 128, 257]),
+    ("float32", 4, 2, 32, 11, [176, 1, 0])])
+def test_grouped_query_paged_attention_matches_its_oracle(
+        monkeypatch, dtype, heads, kv, d, maxp, lengths):
     monkeypatch.setenv("MXTPU_PALLAS_DECODE", "1")
     rng = np.random.RandomState(heads * d)
-    b, ps, pages, maxp = 3, 16, 12, 3
+    b, ps, pages = 3, 16, 12
     cp = -(-kv * d // 128) * 128
     q = jnp.asarray(rng.randn(b, heads, d), dtype)
     kp = jnp.asarray(rng.randn(pages, ps, cp), dtype)
     vp = jnp.asarray(rng.randn(pages, ps, cp), dtype)
     tables = jnp.asarray(rng.randint(0, pages, (b, maxp)), jnp.int32)
-    lengths = jnp.asarray([5, 33, 48], jnp.int32)
+    n = lengths[1]
+    live = np.asarray(lengths) > 0
+    lengths = jnp.asarray(lengths, jnp.int32)
     assert pk._paged_kernel_takes(d, ps, cp, dtype, heads // kv)
     got = pk.paged_attention(q, kp, vp, tables, lengths, kv_heads=kv)
     want = pk.paged_attention_reference(q, kp, vp, tables, lengths,
@@ -203,9 +213,9 @@ def test_grouped_query_paged_attention_matches_its_oracle(monkeypatch, dtype,
     # the oracle by hand for one query head: it reads KV head i // group
     i, g = heads - 1, heads // kv
     k = np.asarray(kp, np.float32)[np.asarray(tables[1])].reshape(
-        maxp * ps, cp)[:33, (i // g) * d:(i // g + 1) * d]
+        maxp * ps, cp)[:n, (i // g) * d:(i // g + 1) * d]
     v = np.asarray(vp, np.float32)[np.asarray(tables[1])].reshape(
-        maxp * ps, cp)[:33, (i // g) * d:(i // g + 1) * d]
+        maxp * ps, cp)[:n, (i // g) * d:(i // g + 1) * d]
     s = k @ np.asarray(q, np.float32)[1, i] / np.sqrt(d)
     p = np.exp(s - s.max())
     by_hand = (p / p.sum()) @ v
@@ -214,7 +224,8 @@ def test_grouped_query_paged_attention_matches_its_oracle(monkeypatch, dtype,
     tol = 2e-5 if dtype == "float32" else 2e-2
     assert np.abs(np.asarray(want, np.float32)[1, i] - by_hand).max() < tol
     assert np.abs(np.asarray(got, np.float32)
-                  - np.asarray(want, np.float32)).max() < tol
+                  - np.asarray(want, np.float32))[live].max() < tol
+    assert not np.any(np.asarray(got, np.float32)[~live])
 
 
 # ---------------------------------------------------------------------------

@@ -154,8 +154,12 @@ CASES = {
     "paged-aligned-bf16": lambda: _paged(32, 16, 128, 1024, 64, 16, bf16),
     # a tiny model: 2 heads of 32 in one padded 128-lane row
     "paged-tiny-f32": lambda: _paged(4, 2, 32, 64, 8, 8, f32),
-    # a head wider than a lane tile: the butterfly crosses vregs
+    # a head wider than a lane tile: its segment matrix is (256, 256) ones
     "paged-head256-f32": lambda: _paged(8, 4, 256, 256, 16, 16, f32),
+    # the two GPT-2 cells' top decode buckets at their tables' lengths:
+    # gpt2s_chat_open 64 x 44 pages, gpt2s_docs_closed 32 x 61
+    "paged-gpt2s-chat-f32": lambda: _paged(64, 12, 64, 3072, 44, 16, f32),
+    "paged-gpt2s-docs-f32": lambda: _paged(32, 12, 64, 3072, 61, 16, f32),
     # chipbench/configs/lfm2_24b_a2b.json: 32 query heads on 8 KV heads of
     # 64, a bfloat16 pool row of 512 lanes, the cell's top decode bucket
     "paged-gqa-lfm2-bf16":
@@ -207,6 +211,63 @@ def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
     # autodiff, `%transpose_jvp_lstm_layer_bwd__.1`)
     for name in KERNEL_NAMES[case.split("-")[0]]:
         assert re.search(r"%%\w*%s_*\.\d+ = " % name, text), name
+
+
+# ---------------------------------------------------------------------------
+# the paged decode kernel's body: what a program's set-up pays for it is the
+# body's size (traced, lowered and compiled once a decode bucket), so the
+# body may not grow with the pages a block holds. No clock in here:
+# tools/paged_lower_cost.py has the seconds
+# ---------------------------------------------------------------------------
+
+def _lower_cost_tool():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "paged_lower_cost", os.path.join(os.path.dirname(__file__), "..",
+                                         "tools", "paged_lower_cost.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+# equations of the kernel this one replaced (PR 46's tree: a page a grid
+# step, the lanes summed by a butterfly of rotations or, grouped-query, by
+# one MXU product a page), counted by the same function at the same shapes
+PARENT_EQUATIONS = {"gpt2s_chat_open": 486, "gpt2s_docs_closed": 486,
+                    "lfm2_reason_closed": 586}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_EQUATIONS))
+def test_paged_kernel_body_is_no_larger_than_the_one_it_replaced(
+        cell, monkeypatch):
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    monkeypatch.setenv("MXTPU_PALLAS_DECODE", "1")
+    tool = _lower_cost_tool()
+    kv, bucket = tool.CELLS[cell][1], tool.CELLS[cell][-1][-1]
+    count = tool.kernel_equations(tool.stacked(pk, kv),
+                                  tool.shapes(cell, bucket, 1))
+    assert 0 < count <= 1.5 * PARENT_EQUATIONS[cell], count
+
+
+def test_paged_kernel_body_does_not_grow_with_the_block(monkeypatch):
+    """Blocks of 4, 8 and 16 pages (a short table; pages of 16; pages of 8)
+    trace to bodies that differ by a few equations a page at most: the
+    block's rows are one array whatever their count, not a body a page."""
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    monkeypatch.setenv("MXTPU_PALLAS_DECODE", "1")
+    tool = _lower_cost_tool()
+    counts = {}
+    for ps, maxp in ((16, 4), (16, 44), (8, 44)):
+        per_step = pk.paged_pages_per_step(ps, maxp)
+        args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+            ((16, 12, 64), f32), ((512, ps, 768), f32), ((512, ps, 768), f32),
+            ((16, maxp), jnp.int32), ((16,), jnp.int32))]
+        counts[per_step] = tool.kernel_equations(pk.paged_attention, args)
+    assert sorted(counts) == [4, 8, 16], counts
+    for a in counts:
+        for b in counts:
+            assert abs(counts[a] - counts[b]) <= 4 * abs(a - b), counts
 
 
 # ---------------------------------------------------------------------------
