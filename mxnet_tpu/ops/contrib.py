@@ -769,19 +769,29 @@ def moe_tile_rows(pairs, experts_held):
 
 @register("_contrib_sigmoid_topk_moe", num_outputs=2, num_visible_outputs=2,
           aliases=("sigmoid_topk_moe",))
-def sigmoid_topk_moe(data, gate_weight, expert_bias, w1, w3, w2, k=4,
-                     expert_offset=0, valid=None, routed_scaling_factor=1.0,
-                     norm_topk_prob=True, n_group=1, topk_group=1,
-                     gate_eps=1e-6):
-    """Drop-free top-k expert layer with a sigmoid router.
+def sigmoid_topk_moe(data, gate_weight, expert_bias, w1, w3, w2,
+                     router_data=None, k=4, expert_offset=0, valid=None,
+                     routed_scaling_factor=1.0, norm_topk_prob=True,
+                     n_group=1, topk_group=1, gate_eps=1e-6,
+                     scores="sigmoid", activation="silu"):
+    """Drop-free top-k expert layer: the one routing and tile dispatch of
+    every expert model (the name is its first router's).
 
-    data (..., C); gate_weight (E, C) and expert_bias (E,) over ALL experts;
-    w1, w3, w2 (E_held, F, C): the experts ``expert_offset ..
-    expert_offset + E_held`` that this holder computes. Scores s =
-    sigmoid(x Wg) in float32; the k experts of a token are the top k of
+    data (..., C); gate_weight (E, C) and expert_bias (E,; None: no bias)
+    over ALL experts; w1, w3, w2 (E_held, F, C): the experts
+    ``expert_offset .. expert_offset + E_held`` that this holder computes.
+    The router reads ``router_data`` (..., C) where given (a model whose
+    router sees the operator's input rows, not the feed-forward's), else
+    ``data``. ``scores`` = ``sigmoid``: s = sigmoid(x Wg) in float32; the k
+    experts of a token are the top k of
     s + expert_bias (the bias selects, it does not weigh); gates g = s / (sum
     of the selected s + ``gate_eps``) when ``norm_topk_prob``, times
-    ``routed_scaling_factor``. With ``n_group`` > 1 the selection is
+    ``routed_scaling_factor``. ``scores`` = ``softmax_selected``: the k
+    experts are the top k of the raw logits z = x Wg (+ expert_bias) and
+    the gates a softmax over the k selected logits (they sum to 1, so
+    ``norm_topk_prob`` has nothing to do and is not applied), times
+    ``routed_scaling_factor``. An expert computes w2 (act(w1 x) * w3 x),
+    ``activation`` ``silu`` or ``relu``. With ``n_group`` > 1 the selection is
     group-limited: the experts lie in ``n_group`` groups of consecutive
     indices, a group scores the sum of its two largest biased scores, the
     ``topk_group`` best groups are kept and the biased scores of the others
@@ -804,10 +814,18 @@ def sigmoid_topk_moe(data, gate_weight, expert_bias, w1, w3, w2, k=4,
     pairs = n * k
     tm = moe_tile_rows(pairs, held)
     tiles = pairs // tm + held          # covers any routing
+    if scores not in ("sigmoid", "softmax_selected"):
+        raise ValueError("scores is sigmoid or softmax_selected, not %r"
+                         % (scores,))
+    softmax = scores == "softmax_selected"
     with jax.named_scope("mxtpu.lm.moe.route"):
-        scores = jax.nn.sigmoid(jnp.einsum(
-            "nc,ec->ne", x, gate_weight, preferred_element_type=jnp.float32))
-        biased = scores + expert_bias.astype(jnp.float32)
+        logits = jnp.einsum(
+            "nc,ec->ne", x if router_data is None
+            else router_data.reshape(-1, c), gate_weight,
+            preferred_element_type=jnp.float32)
+        s = logits if softmax else jax.nn.sigmoid(logits)
+        biased = s if expert_bias is None \
+            else s + expert_bias.astype(jnp.float32)
         if n_group > 1:
             per = biased.reshape(n, n_group, -1)
             _, keep = lax.top_k(jnp.sum(lax.top_k(per, 2)[0], axis=-1),
@@ -815,8 +833,10 @@ def sigmoid_topk_moe(data, gate_weight, expert_bias, w1, w3, w2, k=4,
             kept = jnp.any(keep[:, :, None] == jnp.arange(n_group), axis=1)
             biased = jnp.where(kept[:, :, None], per, 0.0).reshape(n, -1)
         _, experts = lax.top_k(biased, k)
-        gates = jnp.take_along_axis(scores, experts, axis=1)      # (n, k)
-        if norm_topk_prob:
+        gates = jnp.take_along_axis(s, experts, axis=1)           # (n, k)
+        if softmax:
+            gates = jax.nn.softmax(gates, axis=1)
+        elif norm_topk_prob:
             gates = gates / (jnp.sum(gates, axis=1, keepdims=True)
                              + gate_eps)
         gates = gates * routed_scaling_factor
@@ -845,7 +865,7 @@ def sigmoid_topk_moe(data, gate_weight, expert_bias, w1, w3, w2, k=4,
     with jax.named_scope("mxtpu.lm.moe.experts"):
         xs = jnp.concatenate([x, jnp.zeros((1, c), x.dtype)])[row_token]
         ys = moe_grouped_ffn(xs, tile_expert, n_tiles.reshape(1), w1, w3,
-                             w2, tm)
+                             w2, tm, activation)
         picked = ys.at[dest].get(mode="fill", fill_value=0.0) \
             .reshape(n, k, c)
         out = jnp.sum(picked * gates[:, :, None], axis=1).astype(data.dtype)

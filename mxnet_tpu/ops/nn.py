@@ -1209,13 +1209,15 @@ def gated_short_conv(r, w_in, w_conv, w_out, state=None, length=None):
 
 
 @register("_contrib_causal_attention", aliases=("causal_attention",))
-def causal_attention(q, k, v, sm_scale=None, block=None):
+def causal_attention(q, k, v, sm_scale=None, block=None, window=None):
     """Causal softmax attention of a whole sequence, grouped-query: q (...,
     L, H, D); k (..., L, KV, D), v (..., L, KV, Dv) with H a multiple of KV;
     query head i reads KV head i // (H // KV). Scores and softmax in
     float32. With ``block`` and more than ``block`` positions the queries
     go ``block`` rows at a time, each against the keys up to its own last
-    row: the same numbers, and no (H, L, L) array."""
+    row: the same numbers, and no (H, L, L) array. With ``window`` query i
+    sees keys (i - window, i], ``window`` of them with its own, and a block
+    of queries is multiplied with the keys of its band alone."""
     l, h, d = q.shape[-3:]
     kv, dv = k.shape[-2], v.shape[-1]
     if sm_scale is None:
@@ -1224,13 +1226,18 @@ def causal_attention(q, k, v, sm_scale=None, block=None):
     step = l if not block or block >= l else block
     for q0 in range(0, l, step):
         q1 = min(l, q0 + step)
+        k0 = max(0, q0 - window + 1) if window else 0
         # the whole sequence in one block is the plain form, unsliced
         qb = q if step == l else q[..., q0:q1, :, :]
-        kb, vb = (k, v) if q1 == l else (k[..., :q1, :, :], v[..., :q1, :, :])
+        kb, vb = (k, v) if (k0, q1) == (0, l) \
+            else (k[..., k0:q1, :, :], v[..., k0:q1, :, :])
         qg = qb.reshape(q.shape[:-3] + (q1 - q0, kv, h // kv, d))
         s = jnp.einsum("...qkgd,...lkd->...kgql", qg, kb,
                        preferred_element_type=jnp.float32) * sm_scale
-        causal = jnp.arange(q1)[None, :] <= jnp.arange(q0, q1)[:, None]
+        causal = jnp.arange(k0, q1)[None, :] <= jnp.arange(q0, q1)[:, None]
+        if window:
+            causal &= jnp.arange(k0, q1)[None, :] \
+                > jnp.arange(q0, q1)[:, None] - window
         p = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
         o = jnp.einsum("...kgql,...lkd->...qkgd", p.astype(v.dtype), vb,
                        preferred_element_type=jnp.float32)

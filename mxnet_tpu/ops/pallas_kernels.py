@@ -795,7 +795,7 @@ def lstm_layer(gx, wh, h0, c0):
 
 
 def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
-                              sm_scale, kv_heads=None):
+                              sm_scale, kv_heads=None, starts=None):
     """Dense-gather oracle (and fallback): q (B, H, D); k_pages / v_pages
     (P, page_size, Cp) token-major with Cp >= KV*D (lanes past KV*D are the
     allocation's padding and are ignored), KV = ``kv_heads`` heads of keys
@@ -805,9 +805,12 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
     sequence b are live, laid out page_tables[b, t // page_size] slot
     t % page_size. A row with length 0 returns zeros-ish garbage that
     callers mask out (its scores are uniformly _NEG_INF, which is finite by
-    design — no NaNs). Both contractions run at HIGHEST precision: an oracle
-    whose f32 scores the MXU rounded to bf16 could not tell a right kernel
-    from a wrong one on the chip."""
+    design — no NaNs). With ``starts`` (B,) int32 the table is a RING: token
+    t lies in ``page_tables[b, (t // page_size) % max_pages]`` and tokens
+    [starts[b], lengths[b]) are live, at most max_pages * page_size of them
+    (a slot holds the newest token that maps to it). Both contractions run
+    at HIGHEST precision: an oracle whose f32 scores the MXU rounded to bf16
+    could not tell a right kernel from a wrong one on the chip."""
     import jax
     import jax.numpy as jnp
 
@@ -824,37 +827,64 @@ def paged_attention_reference(q, k_pages, v_pages, page_tables, lengths,
     s = jnp.einsum("bkgd,blkd->bkgl", qg, k.astype(jnp.float32),
                    precision=hi) * sm_scale
     ids = jnp.arange(maxp * ps)[None, None, None, :]
-    s = jnp.where(ids < lengths[:, None, None, None], s, _NEG_INF)
+    if starts is None:
+        live = ids < lengths[:, None, None, None]
+    else:
+        # the newest token below the length that maps to each slot
+        last = lengths[:, None, None, None] - 1
+        token = ids + (last - ids) // (maxp * ps) * (maxp * ps)
+        live = (ids <= last) & (token >= starts[:, None, None, None])
+    s = jnp.where(live, s, _NEG_INF)
     p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
     p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
     o = jnp.einsum("bkgl,blkd->bkgd", p, v.astype(jnp.float32), precision=hi)
     return o.reshape(b, h, d).astype(q.dtype)
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, seg_ref, k_hbm, v_hbm, o_ref, k_buf,
-                  v_buf, sem, seq_ref, blk_ref, m_scr, l_scr, acc_scr, *,
-                  sm_scale, ps, d, per_step, maxp, group, terms):
+def _paged_kernel(tbl_ref, len_ref, *refs, sm_scale, ps, d, per_step, maxp,
+                  group, terms, ring):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    # ``ring``: the table is a ring of maxp pages and a sequence's live keys
+    # start at ``start_ref[b]`` (a window layer); else every key below the
+    # length is live and the table holds them all
+    start_ref = refs[0] if ring else None
+    (q_ref, seg_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, seq_ref, blk_ref,
+     m_scr, l_scr, acc_scr) = refs[1:] if ring else refs
     rows = per_step * ps               # a block's tokens
     cp = q_ref.shape[-1]
     w = max(128, d)                    # a lane tile, or one head if wider
     tiles = list(range(0, cp, w))
 
     def length_of(b):
+        if ring:
+            return jnp.maximum(len_ref[b], 0)
         return jnp.clip(len_ref[b], 0, maxp * ps)
+
+    def start_of(b):
+        # never more live keys than the ring holds: the work list is sized
+        # by it
+        length = length_of(b)
+        return jnp.clip(start_ref[b], jnp.maximum(length - maxp * ps, 0),
+                        length)
+
+    def first_block(b):
+        return start_of(b) // rows if ring else 0
 
     # the work list: every live block of every sequence, in order, written
     # to SMEM by the scalar core before the first page moves
     def list_blocks(b, n):
         blocks = (length_of(b) + rows - 1) // rows
+        if ring:                       # from the first live key's block on
+            j0 = first_block(b)
+            blocks = blocks - j0
 
         def one(j, carry):
             seq_ref[n + j] = b
-            blk_ref[n + j] = j
+            blk_ref[n + j] = j0 + j if ring else j
             return carry
 
         jax.lax.fori_loop(0, blocks, one, 0)
@@ -868,21 +898,25 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, seg_ref, k_hbm, v_hbm, o_ref, k_buf,
             sem.at[slot]) for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf))]
 
     def live_pages(i):
-        # work item i's sequence, its block's first page and how many of the
-        # block's pages hold live tokens (every listed block holds one)
+        # work item i's sequence, its block's first page, and which of the
+        # block's pages hold live tokens: [skip, count) (every listed block
+        # holds one)
         b, first = seq_ref[i], blk_ref[i] * per_step
-        return b, first, jnp.minimum(
+        skip = jnp.maximum(start_of(b) // ps - first, 0) if ring else 0
+        return b, first, skip, jnp.minimum(
             (length_of(b) + ps - 1) // ps - first, per_step)
 
     def fetch(i, slot):
-        b, first, count = live_pages(i)
+        b, first, skip, count = live_pages(i)
 
         def one(g, carry):
-            for copy in copies(tbl_ref[b * maxp + first + g], slot, g):
+            at = b * maxp + (first + g) % maxp if ring \
+                else b * maxp + first + g
+            for copy in copies(tbl_ref[at], slot, g):
                 copy.start()
             return carry
 
-        jax.lax.fori_loop(0, count, one, 0)
+        jax.lax.fori_loop(skip, count, one, 0)
 
     def arrive(i, slot):
         def one(g, carry):
@@ -890,7 +924,8 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, seg_ref, k_hbm, v_hbm, o_ref, k_buf,
                 copy.wait()
             return carry
 
-        jax.lax.fori_loop(0, live_pages(i)[2], one, 0)
+        _, _, skip, count = live_pages(i)
+        jax.lax.fori_loop(skip, count, one, 0)
 
     # a sequence of length 0 is on no list: zeros, as its empty sum reads.
     # Rows of a buffer that no page has reached yet are masked by position,
@@ -912,15 +947,17 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, seg_ref, k_hbm, v_hbm, o_ref, k_buf,
         def _():
             fetch(i + 1, 1 - slot)
 
-        @pl.when(j == 0)
+        @pl.when(j == first_block(b))
         def _():
             m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
         arrive(i, slot)
-        live = j * rows + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, w), 0) < length
+        at = j * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, w), 0)
+        live = at < length
+        if ring:
+            live = live & (at >= start_of(b))
         # every (lane tile, query head of the group)'s q*k over the block's
         # rows stacked by rows, summed inside each head's D lanes by ONE
         # product with the 0/1 matrix of the heads' segments (the same for
@@ -1013,7 +1050,8 @@ def _paged_kernel_takes(d, ps, cp, pool_dtype, group=1, b=1, maxp=8):
 
 @functools.lru_cache(maxsize=128)
 def _paged_compiled(key):
-    (b, d, cp, maxp, ps, dtype, pool_dtype, sm_scale, interpret, group) = key
+    (b, d, cp, maxp, ps, dtype, pool_dtype, sm_scale, interpret, group,
+     ring) = key
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -1024,15 +1062,18 @@ def _paged_compiled(key):
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     pool = pl.BlockSpec(memory_space=pl.ANY)      # stays in HBM, never copied
-    items = b * -(-maxp // per_step)              # the longest work list
+    # the longest work list; a ring's live keys may begin inside a block,
+    # which is one block more a sequence
+    items = b * (-(-maxp // per_step) + int(ring))
     return pl.pallas_call(
         functools.partial(
             _paged_kernel, sm_scale=sm_scale, ps=ps, d=d, per_step=per_step,
-            maxp=maxp, group=group, terms=_PAGED_TERMS),
+            maxp=maxp, group=group, terms=_PAGED_TERMS, ring=ring),
         name="paged_attention_decode",
         out_shape=jax.ShapeDtypeStruct((b, group, cp), _np.dtype(dtype)),
-        # page table, lengths; the queries, the heads' segments; the pools
-        in_specs=[smem] * 2 + [vmem] * 2 + [pool] * 2,
+        # page table, lengths (and a ring's first live keys); the queries,
+        # the heads' segments; the pools
+        in_specs=[smem] * (3 if ring else 2) + [vmem] * 2 + [pool] * 2,
         out_specs=vmem,
         scratch_shapes=[
             pltpu.VMEM((2, per_step * ps, cp), _np.dtype(pool_dtype)),  # K
@@ -1051,7 +1092,7 @@ def _paged_compiled(key):
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, lengths,
-                    sm_scale=None, kv_heads=None):
+                    sm_scale=None, kv_heads=None, starts=None):
     """Flash-decode attention: one query token per sequence against a
     paged KV cache (docs/serving.md §Generation).
 
@@ -1069,6 +1110,11 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths,
     (they are masked by ``lengths``, never dereferenced out of bounds).
     lengths: (B,) int32 live-token counts (0 disables a padding row, whose
     output is zeros).
+    starts: None, or (B,) int32 first live keys of a WINDOW layer, whose
+    table is a ring: token t lies in ``page_tables[b, (t // page_size) %
+    max_pages]``, tokens [starts[b], lengths[b]) are live (at most max_pages
+    * page_size of them; an earlier start is raised to that) and the work
+    list visits only the blocks that hold one.
 
     A head size that is no power of two, a page that is not whole sublane
     tiles of the pool's dtype, a Cp off the lane tile, more than 8 query
@@ -1091,10 +1137,10 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths,
     if not (_decode_kernels_on() and _paged_kernel_takes(
             d, ps, cp, k_pages.dtype, group, b, maxp)):
         return paged_attention_reference(q, k_pages, v_pages, page_tables,
-                                         lengths, sm_scale, kv)
+                                         lengths, sm_scale, kv, starts)
     call = _paged_compiled((b, d, cp, maxp, ps, str(q.dtype),
                             str(k_pages.dtype), sm_scale, _use_interpret(),
-                            group))
+                            group, starts is not None))
     # row g holds, for every KV head, the g-th of the query heads that
     # share it, in that KV head's lanes
     rows = q.reshape(b, kv, group, d).transpose(0, 2, 1, 3) \
@@ -1104,10 +1150,80 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths,
     # where a head is wider than a lane tile)
     lanes = jnp.arange(max(128, d)) // d
     seg = (lanes[:, None] == lanes[None, :]).astype(jnp.bfloat16)
+    bounds = (lengths,) if starts is None else (lengths, starts)
     out = call(page_tables.astype(jnp.int32).reshape(-1),
-               lengths.astype(jnp.int32), rows, seg, k_pages, v_pages)
+               *(a.astype(jnp.int32) for a in bounds), rows, seg, k_pages,
+               v_pages)
     return out[:, :, :kv * d].reshape(b, group, kv, d) \
         .transpose(0, 2, 1, 3).reshape(b, h, d)
+
+
+# ---------------------------------------------------------------------------
+# Attention of a whole long prompt (a prefill): jax's own splash-attention
+# kernel (`jax.experimental.pallas.ops.tpu.splash_attention`), a flash
+# attention over a block-sparse mask. The blockwise jnp form
+# (`ops.nn.causal_attention(block=, window=)`) writes every block's float32
+# scores to HBM and reads them back two or three times: 28 heads x 8192 x
+# 4600 keys of a window layer are 4 GB a layer, and the prompt's attention
+# was most of a prefill's 0.73 s at 8192 tokens (PERF.md section 6, PR 48).
+# The kernel keeps a (block, block) tile of scores in VMEM and visits only
+# the key blocks that a query block's mask reaches: the triangle of a full
+# layer, the band of a window layer. Grouped-query: one multi-query call a
+# KV head (vmapped), its query heads sharing the streamed K and V.
+#
+# Gate and fallback as for the decode kernels: MXTPU_PALLAS_DECODE, and
+# shapes the kernel does not take (a head off the lane tile, a prompt that
+# is no whole number of blocks) go to the jnp form.
+# ---------------------------------------------------------------------------
+
+_PROMPT_BLOCK = 512     # rows and keys of a tile of the prompt's scores
+
+
+def _prompt_kernel_takes(l, d):
+    """A head of whole lane tiles, a prompt of whole blocks."""
+    return d % 128 == 0 and l % _PROMPT_BLOCK == 0
+
+
+@functools.lru_cache(maxsize=64)
+def _prompt_kernel(l, group, window, block, interpret):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash, splash_attention_mask as masks)
+
+    # keys (i - window, i]: window - 1 to the left of the query, none to
+    # its right
+    mask = masks.LocalMask((l, l), (window - 1, 0), 0) if window \
+        else masks.CausalMask((l, l))
+    return splash.make_splash_mqa_single_device(
+        mask=masks.MultiHeadMask([mask] * group),
+        block_sizes=splash.BlockSizes(block_q=block, block_kv=block,
+                                      block_kv_compute=block),
+        interpret=interpret)
+
+
+def prompt_attention(q, k, v, sm_scale, window=None):
+    """Causal attention of one prompt, grouped-query: q (L, H, D); k, v (L,
+    KV, D); query head i reads KV head i // (H // KV); with ``window``
+    query i sees keys (i - window, i]. The same function as
+    `ops.nn.causal_attention(q, k, v, sm_scale, window=window)`, through the
+    splash-attention kernel where the shapes allow (the scale is folded
+    into q, in q's dtype; scores, softmax and accumulation in float32)."""
+    import jax.numpy as jnp
+
+    l, h, d = q.shape
+    kv = k.shape[1]
+    if not (_decode_kernels_on() and _prompt_kernel_takes(l, d)):
+        from .nn import causal_attention
+
+        return causal_attention(q, k, v, sm_scale, block=_PROMPT_BLOCK,
+                                window=window)
+    kernel = _prompt_kernel(l, h // kv, window, _PROMPT_BLOCK,
+                            _use_interpret())
+    heads = (q * sm_scale).astype(q.dtype).reshape(l, kv, h // kv, d) \
+        .transpose(1, 2, 0, 3)                           # (KV, G, L, D)
+    # one call a KV head (a vmapped call loses the kernel's name in the
+    # program, and so in the device trace)
+    out = jnp.stack([kernel(heads[i], k[:, i], v[:, i]) for i in range(kv)])
+    return out.transpose(2, 0, 1, 3).reshape(l, h, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -1321,7 +1437,7 @@ def decode_attention_form(latent, d, ps, cp, pool_dtype, group, batch, maxp):
 
 # ---------------------------------------------------------------------------
 # Grouped expert feed-forward: rows sorted by expert into tiles of `tm`, one
-# expert a tile; tile t computes w2_e (silu(w1_e x) * w3_e x) for its rows
+# expert a tile; tile t computes w2_e (act(w1_e x) * w3_e x) for its rows
 # with the weights of expert `tile_expert[t]`, which ride scalar prefetch so
 # that the BlockSpec index maps stream exactly the experts that were hit, once
 # a tile, in blocks of `_moe_tf` of the expert's width. Tiles past `n_tiles` (the
@@ -1336,7 +1452,11 @@ def decode_attention_form(latent, d, ps, cp, pool_dtype, group, batch, maxp):
 _MOE_BLOCK_BYTES = 40 << 20     # the three weight blocks, double-buffered
 
 
-def moe_grouped_ffn_reference(xs, tile_expert, n_tiles, w1, w3, w2, tm):
+_MOE_ACTIVATIONS = ("silu", "relu")     # SwiGLU, ReGLU
+
+
+def moe_grouped_ffn_reference(xs, tile_expert, n_tiles, w1, w3, w2, tm,
+                              activation="silu"):
     """Oracle and fallback of `moe_grouped_ffn`: every tile's weights
     gathered whole, float32 accumulation, zeros past ``n_tiles``."""
     import jax
@@ -1348,7 +1468,8 @@ def moe_grouped_ffn_reference(xs, tile_expert, n_tiles, w1, w3, w2, tm):
                    preferred_element_type=jnp.float32)
     b = jnp.einsum("tmc,tfc->tmf", x, w3[tile_expert],
                    preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(a) * b).astype(xs.dtype)
+    gate = jax.nn.relu(a) if activation == "relu" else jax.nn.silu(a)
+    h = (gate * b).astype(xs.dtype)
     y = jnp.einsum("tmf,tfc->tmc", h, w2[tile_expert],
                    preferred_element_type=jnp.float32)
     live = jnp.arange(t)[:, None, None] < n_tiles
@@ -1356,7 +1477,7 @@ def moe_grouped_ffn_reference(xs, tile_expert, n_tiles, w1, w3, w2, tm):
 
 
 def _moe_kernel(te_ref, nt_ref, x_ref, w1_ref, w3_ref, w2_ref, o_ref,
-                acc_ref, *, nf):
+                acc_ref, *, nf, activation):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -1377,7 +1498,9 @@ def _moe_kernel(te_ref, nt_ref, x_ref, w1_ref, w3_ref, w2_ref, o_ref,
                                 preferred_element_type=jnp.float32)
         b = jax.lax.dot_general(x, w3_ref[0], nt,
                                 preferred_element_type=jnp.float32)
-        h = (a * jax.nn.sigmoid(a) * b).astype(x.dtype)
+        gate = jnp.maximum(a, 0.0) if activation == "relu" \
+            else a * jax.nn.sigmoid(a)
+        h = (gate * b).astype(x.dtype)
         acc_ref[...] += jnp.dot(h, w2_ref[0],
                                 preferred_element_type=jnp.float32)
 
@@ -1409,7 +1532,7 @@ def _moe_kernel_takes(tm, c, f, dtype):
 
 @functools.lru_cache(maxsize=64)
 def _moe_compiled(key):
-    (tiles, tm, c, f, dtype, interpret) = key
+    (tiles, tm, c, f, dtype, interpret, activation) = key
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -1444,7 +1567,7 @@ def _moe_compiled(key):
         scratch_shapes=[pltpu.VMEM((tm, c), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(_moe_kernel, nf=nf),
+        functools.partial(_moe_kernel, nf=nf, activation=activation),
         name="moe_grouped_ffn",
         out_shape=jax.ShapeDtypeStruct((tiles * tm, c), jnp.float32),
         grid_spec=grid_spec,
@@ -1455,8 +1578,11 @@ def _moe_compiled(key):
     )
 
 
-def moe_grouped_ffn(xs, tile_expert, n_tiles, w1, w3, w2, tm):
-    """Grouped SwiGLU over the experts that were hit.
+def moe_grouped_ffn(xs, tile_expert, n_tiles, w1, w3, w2, tm,
+                    activation="silu"):
+    """Grouped gated feed-forward over the experts that were hit: w2_e
+    (act(w1_e x) * w3_e x), ``activation`` (static) ``silu`` (SwiGLU) or
+    ``relu`` (ReGLU).
 
     xs (T*tm, C): the routed rows laid out by `ops.contrib.sigmoid_topk_moe`,
     tile t holding rows of expert ``tile_expert[t]`` only (rows past an
@@ -1466,11 +1592,15 @@ def moe_grouped_ffn(xs, tile_expert, n_tiles, w1, w3, w2, tm):
     `moe_grouped_ffn_reference`, decided from the shapes alone."""
     import jax.numpy as jnp
 
+    if activation not in _MOE_ACTIVATIONS:
+        raise ValueError("an expert's activation is one of %s, not %r"
+                         % (_MOE_ACTIVATIONS, activation))
     tiles = tile_expert.shape[0]
     c, f = xs.shape[-1], w1.shape[1]
     if not (_decode_kernels_on() and _moe_kernel_takes(tm, c, f, xs.dtype)):
         return moe_grouped_ffn_reference(xs, tile_expert, n_tiles, w1, w3,
-                                         w2, tm)
-    call = _moe_compiled((tiles, tm, c, f, str(xs.dtype), _use_interpret()))
+                                         w2, tm, activation)
+    call = _moe_compiled((tiles, tm, c, f, str(xs.dtype), _use_interpret(),
+                          activation))
     return call(tile_expert.astype(jnp.int32), n_tiles.astype(jnp.int32),
                 xs, w1, w3, w2)

@@ -27,7 +27,9 @@ repo already has:
     (`mxtpu_serve_kv_pages_{total,used}` gauges track occupancy).
     Admission reserves a sequence's worst-case pages up front
     (prompt + max_new_tokens), so a running batch can never deadlock on
-    the pool.
+    the pool. A model with sliding-window layers has a SECOND group of
+    page ids for them, in which a sequence holds a bounded ring of pages
+    beside the growing pages of its full-attention layers.
   * **decode attention** runs the flash-decode Pallas kernel
     (`ops/pallas_kernels.paged_attention` — a work list of live blocks
     of pages, online softmax over the streamed blocks) on TPU, the dense-
@@ -56,6 +58,7 @@ router-side) over the existing supervisor wire protocol.
 from __future__ import annotations
 
 import collections
+import contextlib
 import hashlib
 import itertools
 import json
@@ -90,6 +93,9 @@ _LM_FORMAT = "mxtpu-lm-v1"
 # 256 rows x 4096 keys of float32 scores are 268 MB, where the whole
 # (H, L, L) would be 4.3 GB
 _PREFILL_Q_BLOCK = 256
+# rows an expert layer takes at a time: a longer prompt goes in equal chunks
+# (`_lm_experts`); every bucket of the accepted cells is at most this
+_MOE_ROWS = 4096
 
 
 # ---------------------------------------------------------------------------
@@ -105,9 +111,17 @@ class KVPageAllocator:
     grant size; `free` returns pages for immediate reuse — a completed
     sequence's pages serve the next admission the same scheduler lap.
     Occupancy rides the `mxtpu_serve_kv_pages_{total,used}` gauges.
+
+    One allocator is one GROUP of page ids. Every model has the group of
+    its growing pages (a sequence holds ``pages_for(prompt + max_new)`` of
+    them, one table for all its full-attention layers); a model with
+    sliding-window layers has a second, ``group="window"``, whose pages a
+    sequence holds as a ring (`TransformerLMEngine.ring_pages` at most) and
+    whose gauges are `mxtpu_serve_kv_window_pages_{total,used}` and
+    `mxtpu_serve_kv_window_occupancy`.
     """
 
-    def __init__(self, num_pages, page_size, name="default"):
+    def __init__(self, num_pages, page_size, name="default", group=None):
         if num_pages < 1 or page_size < 1:
             raise MXNetError("KV pool needs >=1 pages of >=1 tokens, got "
                              "%d x %d" % (num_pages, page_size))
@@ -118,11 +132,24 @@ class KVPageAllocator:
         # cache lines / artifact pages are warmest)
         self._free = list(range(self.num_pages - 1, -1, -1))
         labels = {"model": name}
-        self._m_total = telemetry.gauge("mxtpu_serve_kv_pages_total", labels)
-        self._m_used = telemetry.gauge("mxtpu_serve_kv_pages_used", labels)
-        # used/total as one ratio gauge: the SLO occupancy-ceiling
+        # used/total as one ratio gauge too: the SLO occupancy-ceiling
         # objective and /statusz read a single windowed series
-        self._m_occ = telemetry.gauge("mxtpu_serve_kv_occupancy", labels)
+        if group == "window":
+            self._m_total = telemetry.gauge(
+                "mxtpu_serve_kv_window_pages_total", labels)
+            self._m_used = telemetry.gauge(
+                "mxtpu_serve_kv_window_pages_used", labels)
+            self._m_occ = telemetry.gauge(
+                "mxtpu_serve_kv_window_occupancy", labels)
+        elif group is None:
+            self._m_total = telemetry.gauge("mxtpu_serve_kv_pages_total",
+                                            labels)
+            self._m_used = telemetry.gauge("mxtpu_serve_kv_pages_used",
+                                           labels)
+            self._m_occ = telemetry.gauge("mxtpu_serve_kv_occupancy", labels)
+        else:
+            raise MXNetError("a page group is None or 'window', not %r"
+                             % (group,))
         self._m_total.set(self.num_pages)
         self._m_used.set(0)
         self._m_occ.set(0.0)
@@ -284,14 +311,19 @@ class GenRequest:
 class _Sequence:
     """Scheduler-internal state of one RUNNING sequence."""
 
-    __slots__ = ("req", "pages", "page_row", "slot", "pos", "generated",
-                 "t_last", "n_steps")
+    __slots__ = ("req", "pages", "page_row", "slot", "ring", "ring_row",
+                 "pos", "generated", "t_last", "n_steps")
 
-    def __init__(self, req, pages, page_row, pos, first_token, slot=None):
+    def __init__(self, req, pages, page_row, pos, first_token, slot=None,
+                 ring=None, ring_row=None):
         self.req = req
         self.pages = pages
         self.page_row = page_row
         self.slot = slot          # state slot (models with recurrent layers)
+        # the window group's pages (models with sliding-window layers):
+        # token t in ring_row[(t // page_size) % ring_pages]
+        self.ring = ring
+        self.ring_row = ring_row
         self.pos = pos            # position of the NEXT token to feed
         self.generated = [first_token]
         self.t_last = time.perf_counter()
@@ -308,7 +340,8 @@ class GenerateScheduler:
     active set and the page allocator's grants. Each lap:
 
       1. **admit**: pop waiting requests while batch slots AND worst-case
-         pages are available; run one PREFILL each (its own bucketed
+         pages (of both page groups, where the model has window layers)
+         are available; run one PREFILL each (its own bucketed
          executable), which also samples the first token.
       2. **decode**: one step for the whole active set, padded to the
          smallest power-of-two batch bucket — one cached executable per
@@ -341,6 +374,12 @@ class GenerateScheduler:
         self.queue_depth = max(1, int(queue_depth))
         self.allocator = KVPageAllocator(engine.num_pages, engine.page_size,
                                          name=self.name)
+        # the second page group: a ring a sequence, only for an engine
+        # whose model has sliding-window layers
+        n_window = getattr(engine, "window_pages", 0)
+        self.window_allocator = KVPageAllocator(
+            n_window, engine.page_size, name=self.name, group="window") \
+            if n_window else None
         # the second kind of state: one fixed slot a sequence, only for an
         # engine whose model has recurrent layers
         n_slots = getattr(engine, "state_slots", 0)
@@ -610,21 +649,10 @@ class GenerateScheduler:
                     self._queue.popleft()
                     self._m_queue.set(len(self._queue))
                     continue
-                # worst-case reservation: prompt + max_new tokens. Pages
-                # are granted up front so a RUNNING sequence can never
-                # stall mid-decode waiting for the pool (no deadlock,
-                # no mid-flight eviction)
-                need = self.allocator.pages_for(
-                    len(req.tokens) + req.max_new_tokens)
-                pages = self.allocator.alloc(need)
-                if pages is None:
+                held = self._reserve(req)
+                if held is None:
                     break            # pool pressure: stays queued
-                slot = None
-                if self.slots is not None:
-                    slot = self.slots.alloc()
-                    if slot is None:     # never while active < max_active
-                        self.allocator.free(pages)
-                        break
+                pages, ring, slot = held
                 self._queue.popleft()
                 self._m_queue.set(len(self._queue))
             req.queue_seconds = time.perf_counter() - req._t_submit
@@ -635,18 +663,20 @@ class GenerateScheduler:
             lap["queue_wait_s"] += req.queue_seconds
             page_row = _np.zeros(self.engine.max_pages_per_seq, _np.int32)
             page_row[:len(pages)] = pages
+            state, ring_row = {} if slot is None else {"slot": slot}, None
+            if ring is not None:
+                ring_row = state["ring_row"] = _np.zeros(
+                    self.engine.ring_pages, _np.int32)
+                ring_row[:len(ring)] = ring
             try:
                 # the engine claims `prefill_wait` inside this phase
                 with _goodput.phase("prefill_host") as prefill:
                     first = self.engine.prefill(
                         req.tokens, page_row,
                         (req.temperature, req.top_k, req.top_p),
-                        _random.next_key(),
-                        **({} if slot is None else {"slot": slot}))
+                        _random.next_key(), **state)
             except Exception as e:  # bad prompt/model: answer, free state
-                self.allocator.free(pages)
-                if slot is not None:
-                    self.slots.free(slot)
+                self._unreserve(pages, ring, slot)
                 err = ServingError("prefill on %r failed: %r"
                                    % (self.name, e))
                 err.__cause__ = e
@@ -673,13 +703,46 @@ class GenerateScheduler:
                     attrs={"prompt": len(req.tokens), "pages": len(pages)})
             self._m_tokens.inc()
             seq = _Sequence(req, pages, page_row, len(req.tokens), first,
-                            slot)
+                            slot, ring, ring_row)
             with _goodput.phase("retire"):
                 done = self._finish_if_done(seq)
             if not done:
                 with self._cv:
                     self._active.append(seq)
             self._m_active.set(len(self._active))
+
+    def _reserve(self, req):
+        """A request's worst-case state, all or nothing: (growing pages,
+        window group's ring or None, state slot or None), or None while any
+        of the three is short. Worst case is prompt + max_new tokens: pages
+        are granted up front so a RUNNING sequence can never stall
+        mid-decode waiting for a pool (no deadlock, no mid-flight
+        eviction). A window layer keeps the last window of tokens alone, so
+        the second group's grant is bounded by the ring."""
+        need = self.allocator.pages_for(len(req.tokens) + req.max_new_tokens)
+        pages = self.allocator.alloc(need)
+        if pages is None:
+            return None
+        ring = slot = None
+        if self.window_allocator is not None:
+            ring = self.window_allocator.alloc(
+                min(need, self.engine.ring_pages))
+            if ring is None:
+                self._unreserve(pages, None, None)
+                return None
+        if self.slots is not None:
+            slot = self.slots.alloc()
+            if slot is None:         # never while active < max_active
+                self._unreserve(pages, ring, None)
+                return None
+        return pages, ring, slot
+
+    def _unreserve(self, pages, ring, slot):
+        self.allocator.free(pages)
+        if ring is not None:
+            self.window_allocator.free(ring)
+        if slot is not None:
+            self.slots.free(slot)
 
     def _step(self):
         """One decode step for the whole active set, padded to the
@@ -727,6 +790,16 @@ class GenerateScheduler:
                 seq_slots = extra["seq_slots"] = _np.full(
                     bucket, self.slots.num_slots + 1, _np.int32)
                 seq_slots[:n] = [seq.slot for seq in live]
+            if self.window_allocator is not None:
+                ring = self.engine.ring_pages
+                # a padding row's write drops past the window group's pages
+                ring_dest = extra["ring_dest"] = _np.full(
+                    bucket, self.engine.window_pages, _np.int32)
+                ring_tables = extra["ring_tables"] = _np.zeros(
+                    (bucket, ring), _np.int32)
+                for i, seq in enumerate(live):
+                    ring_dest[i] = seq.ring_row[(seq.pos // ps) % ring]
+                    ring_tables[i] = seq.ring_row
             for i, seq in enumerate(live):
                 tokens[i] = seq.generated[-1]
                 positions[i] = seq.pos
@@ -746,6 +819,13 @@ class GenerateScheduler:
                                           **extra)
         self._lap.update(n=n, bucket=bucket, sampled=sampled,
                          context_tokens=int(lengths.sum()))
+        if self.window_allocator is not None:
+            # what the window layers' kernel streams, beside what the full
+            # layers' does; and the second group's occupancy
+            self._lap.update(
+                window_tokens=int(_np.minimum(
+                    lengths, self.engine.window).sum()),
+                ring_pages=self.window_allocator.used_pages)
         if self.slots is not None:
             self._lap["state_slots"] = self.slots.used_slots
         moe = getattr(self.engine, "last_moe", None)
@@ -795,10 +875,9 @@ class GenerateScheduler:
         return False
 
     def _release(self, seq):
-        """Return everything a sequence holds: its pages and its slot."""
-        self.allocator.free(seq.pages)
-        if seq.slot is not None:
-            self.slots.free(seq.slot)
+        """Return everything a sequence holds: its pages of both groups and
+        its slot."""
+        self._unreserve(seq.pages, seq.ring, seq.slot)
 
     def _retire(self, seq, finish_reason, error=None):
         self._release(seq)
@@ -848,23 +927,108 @@ def _lm_dense(x, p):
     return y + p["b"] if "b" in p else y
 
 
+def _lm_attn_scope(spec):
+    """A model that says its layers' windows names the two kinds of
+    attention in the device trace; any other model's trace is as it was."""
+    import jax
+
+    if "window" not in spec:
+        return contextlib.nullcontext()
+    return jax.named_scope("mxtpu.lm.attn.window" if spec["window"]
+                           else "mxtpu.lm.attn.full")
+
+
+def _write_pages(pool, rows, table, length, ring=0):
+    """A prompt's rows (lp, lanes) into ``pool`` (pages, page_size, Cp) at
+    the pool's full width and whole pages at a time (a write of a part of a
+    row's lane tiles, or a scatter of rows, is a serial loop on the chip:
+    3 us a row, 12 ms a layer at 4096); the lanes and rows of the padding are
+    zeros, and no step reads a page's row past the prompt's length before
+    writing it. Page p of the prompt goes to ``table[p]``, or with ``ring``
+    > 0 to ``table[p % ring]`` if it is one of the last ``ring`` pages the
+    prompt's ``length`` reaches: the rows a ring still holds."""
+    import jax.numpy as jnp
+
+    lp, lanes = rows.shape
+    ps = pool.shape[1]
+    n_pg = -(-lp // ps)
+    wide = jnp.pad(rows, ((0, n_pg * ps - lp), (0, pool.shape[-1] - lanes))) \
+        .astype(pool.dtype)
+    page = jnp.arange(n_pg)
+    live = page * ps < length
+    if ring:
+        live &= page > (length - 1) // ps - ring
+        dest = table[page % ring]
+    else:
+        dest = table[:n_pg]
+    return pool.at[jnp.where(live, dest, pool.shape[0])].set(
+        wide.reshape(n_pg, ps, -1), mode="drop")
+
+
+def _lm_experts(ex, layer, r, valid, router_rows):
+    """The expert feed-forward of rows ``r`` (n, C) -> (rows, stats). More
+    than `_MOE_ROWS` rows (a long prompt) go through the layer in equal
+    chunks of at most that many, one after the other: routing is a row's
+    own, so the rows are the same, and the routed copies of the rows, which
+    are ``per_token`` times the rows in float32, never exist for more than a
+    chunk (2 GB at 12288 rows of 2560 for 6 experts a token). The chunks'
+    stats are summed (pairs) and their largest taken (experts hit, busiest
+    expert): a prefill reads the pairs alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.contrib import sigmoid_topk_moe
+
+    def experts(rows, live, router=None):
+        return sigmoid_topk_moe(
+            rows, layer["gate"], layer.get("expert_bias"), layer["ew1"],
+            layer["ew3"], layer["ew2"], router, k=ex["per_token"],
+            expert_offset=ex["offset"], valid=live,
+            routed_scaling_factor=ex["scaling"],
+            norm_topk_prob=ex["norm_topk"], n_group=ex.get("groups", 1),
+            topk_group=ex.get("topk_groups", 1),
+            gate_eps=ex.get("gate_eps", 1e-6),
+            scores=ex.get("scores", "sigmoid"),
+            activation=ex.get("activation", "silu"))
+
+    n = r.shape[0]
+    if n <= _MOE_ROWS:
+        return experts(r, valid, router_rows)
+    chunks = next(c for c in range(-(-n // _MOE_ROWS), n + 1) if n % c == 0)
+    parts = tuple(a.reshape((chunks, n // chunks) + a.shape[1:])
+                  for a in (r, valid, router_rows) if a is not None)
+    f, st = jax.lax.map(lambda a: experts(*a), parts)
+    return f.reshape(r.shape), jnp.concatenate(
+        [jnp.sum(st[:, :1], axis=0), jnp.max(st[:, 1:], axis=0)])
+
+
 def _lm_layers(desc, params, x, positions, valid, kv, slots, attention, conv):
     """The layers of a decoder LM over rows ``x`` (n, C), one row a token at
     ``positions`` (n,): the block both of the engine's programs run. What
     differs between them is where an operator keeps its state, so the two
     stateful steps are handed in: ``attention((K, V) pages of the layer, q,
-    k, v) -> (attended, new pages)`` (store k and v, attend), or for a
+    k, v, window) -> (attended, new pages)`` (store k and v, attend;
+    ``window`` the layer's sliding window or None, which says which of the
+    two page groups the layer's pages belong to), or for a
     latent model ``attention(pages of the layer, q, row, layer)`` (store the
     compressed row, attend through ``kv_b``: `_lm_latent`), and
     ``conv(slots of the layer, r, layer) -> (o, new slots)`` (the gated
     short convolution over its slot); ``kv`` and ``slots`` hold one entry an
     attention / convolution layer. Rows with ``valid`` false are a bucket's
-    padding: they are computed like any row and route to no expert. Returns
+    padding: they are computed like any row and route to no expert.
+
+    What is per layer in a description's ``layers`` entry beside the
+    operator and the feed-forward: ``window`` (keys (i - window, i] are
+    live; absent or None: the whole context) and ``rotary`` (whether q and k
+    are rotated; absent: as the model's ``positions`` says, so a layer with
+    ``rotary`` false in a rotary model has no positional encoding at all).
+    ``experts`` may say ``router_rows: "operator"`` (the router reads the
+    operator's normed input rows, the experts the feed-forward's),
+    ``scores`` and ``activation`` (`ops.contrib.sigmoid_topk_moe`). Returns
     (rows, per-expert-layer stats, new kv, new slots)."""
     import jax
 
     from ..ops import nn as _opsnn
-    from ..ops.contrib import sigmoid_topk_moe
 
     n = x.shape[0]
     pre = desc["norm_at"] == "pre"
@@ -872,7 +1036,7 @@ def _lm_layers(desc, params, x, positions, valid, kv, slots, attention, conv):
     h, kvh, dh = desc["heads"], desc.get("kv_heads"), desc.get("head_dim")
     stats, new_kv, new_slots = [], [], []
     for layer, spec in zip(params["layers"], desc["layers"]):
-        r = _lm_norm(desc, x, layer["attn_norm"]) if pre else x
+        r = r_operator = _lm_norm(desc, x, layer["attn_norm"]) if pre else x
         if spec["operator"] == "attention" and latent:
             with jax.named_scope("mxtpu.lm.attn"):
                 o, pages = _lm_latent(desc, layer, r, positions,
@@ -888,10 +1052,12 @@ def _lm_layers(desc, params, x, positions, valid, kv, slots, attention, conv):
                                         eps=desc["norm_eps"])
                     k = _opsnn.rms_norm(k, layer["k_norm"]["g"],
                                         eps=desc["norm_eps"])
-                if desc["positions"] == "rotary":
+                if spec.get("rotary", desc["positions"] == "rotary"):
                     q = _opsnn.rope(q, positions, desc["rope_theta"])
                     k = _opsnn.rope(k, positions, desc["rope_theta"])
-                att, pages = attention(kv[len(new_kv)], q, k, v)
+                with _lm_attn_scope(spec):
+                    att, pages = attention(kv[len(new_kv)], q, k, v,
+                                           spec.get("window"))
                 new_kv.append(pages)
                 o = _lm_dense(att.astype(x.dtype).reshape(n, h * dh),
                               layer["o"])
@@ -905,14 +1071,9 @@ def _lm_layers(desc, params, x, positions, valid, kv, slots, attention, conv):
         r = _lm_norm(desc, x, layer["ffn_norm"]) if pre else x
         if spec["ffn"] == "experts":
             ex = desc["experts"]
-            f, st = sigmoid_topk_moe(
-                r, layer["gate"], layer["expert_bias"], layer["ew1"],
-                layer["ew3"], layer["ew2"], k=ex["per_token"],
-                expert_offset=ex["offset"], valid=valid,
-                routed_scaling_factor=ex["scaling"],
-                norm_topk_prob=ex["norm_topk"], n_group=ex.get("groups", 1),
-                topk_group=ex.get("topk_groups", 1),
-                gate_eps=ex.get("gate_eps", 1e-6))
+            f, st = _lm_experts(
+                ex, layer, r, valid,
+                r_operator if ex.get("router_rows") == "operator" else None)
             stats.append(st)
             if ex.get("shared"):
                 # the shared expert: whole on every holder, every token
@@ -1004,16 +1165,28 @@ class TransformerLMEngine:
     function (`_lm_layers`); they differ only in where an operator keeps
     its state:
 
-    * an **attention** layer owns one (K, V) pair of the page pool (`_kv`),
-      each array ``(num_pages, page_size, Cp)``: a page is page_size rows, a
+    * an **attention** layer owns one (K, V) pair of arrays (`_kv`), each
+      ``(pages of its group, page_size, Cp)``; a page id names the same page
+      in every layer of a group, so a sequence has one page table a GROUP,
+      not a layer. A full-attention layer is of the growing group
+      (``num_pages``; a sequence holds pages for prompt + max_new tokens); a
+      **sliding-window** layer (its entry of the description's ``layers``
+      says ``window``) is of the window group (``window_pages``), in which
+      a sequence holds a ring of ``ring_pages`` = ceil((window - 1) /
+      page_size) + 1 pages at most and token t lies in ring entry
+      ``(t // page_size) % ring_pages``: the window layers' bytes a
+      sequence stop growing at the window. A page is page_size rows, a
       row one token's values of all KV heads side by side (KV head h in
       columns [h*Dh, (h+1)*Dh)), Cp = kv_heads*head_dim rounded up to a
       multiple of 128 at allocation. Grouped-query models keep kv_heads <
       heads rows wide; nothing is repeated per query head. Prefill computes
       the causal forward of a padded prompt bucket and scatters every live
-      token's K/V to ``[page, slot]``; a decode step appends one row a
-      sequence and attends over the page table
-      (`ops/pallas_kernels.paged_attention`).
+      token's K/V to ``[page, slot]`` (a model with window layers attends in
+      query blocks, window layers in a band, and writes whole pages: to a
+      ring only the rows it still holds); a decode step appends one row a
+      sequence and attends over the page table of the layer's group
+      (`ops/pallas_kernels.paged_attention`; a window layer's call names
+      each sequence's first live key).
     * a **latent attention** layer (description ``"attention": "latent"``)
       owns ONE array of the pool, ``(num_pages, page_size, Cp)``: a row is a
       token's compressed KV (``kv_rank`` lanes, RMS-normed) and the rotary
@@ -1042,7 +1215,8 @@ class TransformerLMEngine:
     def __init__(self, lm=None, params=None, config=None, num_pages=None,
                  page_size=None, max_prompt=None, max_new_tokens=None,
                  max_batch=None, decode_buckets=None, prefill_buckets=None,
-                 eos_id=None, kv_dtype=None, description=None):
+                 eos_id=None, kv_dtype=None, description=None,
+                 window_pages=None):
         import jax
 
         # the parameters into the engine's layout, the page pool and the
@@ -1117,6 +1291,29 @@ class TransformerLMEngine:
             # one state slot a sequence that can be active, for every
             # short-convolution layer; 0 where the model has none
             self.state_slots = self.buckets[-1] if self.conv_layers else 0
+            # sliding-window layers: one window for all of them, a ring of
+            # pages a sequence in the second page group
+            # (one entry an attention layer)
+            windows = self._windows = tuple(
+                l.get("window") for l in desc["layers"]
+                if l["operator"] == "attention")
+            if len({w for w in windows if w}) > 1 \
+                    or (self.latent and any(windows)):
+                raise MXNetError("window layers share one window and cache "
+                                 "K and V, got %r" % (windows,))
+            self.window = int(max((w or 0 for w in windows), default=0))
+            self.ring_pages = min(self.max_pages_per_seq, -(
+                -(self.window - 1) // self.page_size) + 1) \
+                if self.window else 0
+            # every sequence of the widest batch at its whole ring, unless
+            # told: more could never be held
+            self.window_pages = 0 if not self.window else int(
+                window_pages if window_pages is not None
+                else self.buckets[-1] * self.ring_pages)
+            if self.window_pages < self.ring_pages:
+                raise MXNetError(
+                    "one sequence's ring is %d pages but the window group "
+                    "has only %d" % (self.ring_pages, self.window_pages))
 
             dtype = jax.numpy.dtype(self.dtype)
             self._params = jax.tree_util.tree_map(
@@ -1128,12 +1325,14 @@ class TransformerLMEngine:
             self._kv_lanes = self.kv_heads * self.head_dim
             leaf = (self.num_pages, self.page_size,
                     -(-self._kv_lanes // 128) * 128)
-            # a layer's leaf: a (K, V) pair, or a latent layer's one array
+            # a layer's leaf: a (K, V) pair of its group's pages, or a
+            # latent layer's one array
             self._kv = tuple(
                 jax.numpy.zeros(leaf, dtype=self.kv_dtype) if self.latent
-                else tuple(jax.numpy.zeros(leaf, dtype=self.kv_dtype)
-                           for _ in "kv")
-                for _ in range(self.attn_layers))
+                else tuple(jax.numpy.zeros(
+                    (self.window_pages,) + leaf[1:] if w else leaf,
+                    dtype=self.kv_dtype) for _ in "kv")
+                for w in windows)
             # row `state_slots` is the inert one that padding rows read
             self._slots = tuple(
                 jax.numpy.zeros((self.state_slots + 1,
@@ -1157,17 +1356,25 @@ class TransformerLMEngine:
             if desc["arch"] != "transformer_lm":
                 ident["description"] = desc
                 ident["slots"] = self.state_slots
+            if self.window:
+                ident["window_pages"] = self.window_pages
             self._fingerprint = hashlib.sha256(json.dumps(
                 ident, sort_keys=True).encode()).hexdigest()[:32]
             build.fields["pool_bytes"] = self.kv_bytes()
-            # how the decode attention walks the pool at the widest bucket:
-            # static for the engine, so geometry() and this span carry it
+            if self.window:
+                build.fields.update(window_pages=self.window_pages,
+                                    window_pool_bytes=self.window_kv_bytes())
+            # how the decode attention walks the pool at the widest bucket
+            # (a table of the growing group's width, or of the ring's where
+            # every attention layer has the window): static for the engine,
+            # so geometry() and this span carry it
             from ..ops.pallas_kernels import decode_attention_form
             build.fields["kernel_form"] = self.kernel_form = \
                 decode_attention_form(
                     self.latent, self.head_dim, self.page_size, leaf[-1],
                     self.kv_dtype, self.num_heads // self.kv_heads,
-                    self.buckets[-1], self.max_pages_per_seq)
+                    self.buckets[-1], self.ring_pages if windows
+                    and all(windows) else self.max_pages_per_seq)
 
     # -- sizing ------------------------------------------------------------
     def kv_bytes(self):
@@ -1178,6 +1385,13 @@ class TransformerLMEngine:
 
         return int(sum(a.size * a.dtype.itemsize for a in
                        jax.tree_util.tree_leaves((self._kv, self._slots))))
+
+    def window_kv_bytes(self):
+        """The window group's share of `kv_bytes`: the (K, V) arrays of the
+        sliding-window layers."""
+        return int(sum(a.size * a.dtype.itemsize
+                       for pair, w in zip(self._kv, self._windows) if w
+                       for a in pair))
 
     def param_bytes(self):
         return self._param_bytes
@@ -1193,7 +1407,11 @@ class TransformerLMEngine:
                 "state_slots": self.state_slots,
                 "kv_bytes": self.kv_bytes(),
                 "param_bytes": self.param_bytes(),
-                "kernel_form": dict(self.kernel_form)}
+                "kernel_form": dict(self.kernel_form),
+                # the second page group (zeros: no window layers)
+                "window": self.window, "ring_pages": self.ring_pages,
+                "window_pages": self.window_pages,
+                "window_kv_bytes": self.window_kv_bytes()}
 
     # -- executables -------------------------------------------------------
     def _key(self, kind, shape_sig):
@@ -1209,7 +1427,9 @@ class TransformerLMEngine:
             static=(("pages", self.num_pages),
                     ("page_size", self.page_size),
                     ("maxp", self.max_pages_per_seq),
-                    ("kv", self.kv_dtype)),
+                    ("kv", self.kv_dtype))
+            + ((("window_pages", self.window_pages),) if self.window
+               else ()),
             no_persist=True)
 
     def _state(self):
@@ -1228,27 +1448,45 @@ class TransformerLMEngine:
         import jax.numpy as jnp
 
         from ..ops import nn as _opsnn
+        from ..ops.pallas_kernels import prompt_attention
         from ..ops.random_ops import sample_token_logits
 
         desc, ps, nump = self.description, self.page_size, self.num_pages
         lanes, stateful = self._kv_lanes, bool(self.conv_layers)
         latent, share_held = self.latent, self._share_held
+        windowed, ring = bool(self.window), self.ring_pages
         rank = desc["latent"]["kv_rank"] if latent else None
         scale = _latent_scale(desc) if latent \
             else 1.0 / math.sqrt(self.head_dim)
 
         def fn(params, state, tokens, length, page_row, temp, top_k, top_p,
-               key, slot=None):
+               key, *held):
             # tokens (lp,) int32 padded; length () int32; page_row (maxp,);
-            # slot () int32, the sequence's state slot (stateful models)
+            # then slot () int32, the sequence's state slot (stateful
+            # models), and ring_row (ring_pages,), its pages of the window
+            # group (models with window layers)
+            held = list(held)
+            slot = held.pop(0) if stateful else None
+            ring_row = held.pop(0) if windowed else None
             kv, slots = state if stateful else (state, ())
             t_idx = jnp.arange(lp)
             x = _lm_embed(desc, params, tokens, t_idx)           # (lp, C)
             tpage = jnp.where(t_idx < length, page_row[t_idx // ps], nump)
             tslot = t_idx % ps
 
-            def attention(pages, q, k, v):
+            def attention(pages, q, k, v, window):
                 kp, vp = pages
+                if windowed:
+                    # a model with window layers: whole pages, to a ring
+                    # only those it still holds; attention a tile of
+                    # scores at a time (28 heads x 12288^2 float32 scores
+                    # would be 17 GB), a window layer's over its band alone
+                    table, r = (ring_row, ring) if window else (page_row, 0)
+                    pages = tuple(
+                        _write_pages(pool, x.reshape(lp, lanes), table,
+                                     length, r)
+                        for pool, x in ((kp, k), (vp, v)))
+                    return prompt_attention(q, k, v, scale, window), pages
                 # one row of contiguous values a token, in place
                 pages = (kp.at[tpage, tslot, :lanes].set(
                              k.reshape(lp, lanes).astype(kp.dtype),
@@ -1259,22 +1497,10 @@ class TransformerLMEngine:
                 return _opsnn.causal_attention(q, k, v, scale), pages
 
             def latent_attention(pages, q, row, layer):
-                # the prompt's rows in place, at the pool's full width and
-                # whole pages at a time (a write of a part of a row's lane
-                # tiles, or a scatter of rows, is a serial loop on the chip:
-                # 3 us a row, 12 ms a layer at 4096); the lanes and rows of
-                # the padding are zeros, and no step reads a page's row past
-                # the prompt's length before writing it.  K and V of every
-                # head expanded from the rows, queries `_PREFILL_Q_BLOCK` at
-                # a time
-                n_pg = -(-lp // ps)
-                wide = jnp.pad(row, ((0, n_pg * ps - lp),
-                                     (0, pages.shape[-1] - lanes))) \
-                    .astype(pages.dtype)
-                live = jnp.arange(n_pg) * ps < length
-                pages = pages.at[jnp.where(live, page_row[:n_pg],
-                                           nump)].set(
-                    wide.reshape(n_pg, ps, -1), mode="drop")
+                # the prompt's rows in place, whole pages at a time.  K and V
+                # of every head expanded from the rows, queries
+                # `_PREFILL_Q_BLOCK` at a time
+                pages = _write_pages(pages, row, page_row, length)
                 k_nope = jnp.einsum("lr,hdr->lhd", row[:, :rank],
                                     layer["kvb_k"],
                                     preferred_element_type=jnp.float32)
@@ -1330,26 +1556,38 @@ class TransformerLMEngine:
 
         desc, lanes, kvh = self.description, self._kv_lanes, self.kv_heads
         stateful, latent = bool(self.conv_layers), self.latent
+        windowed = bool(self.window)
         scale = _latent_scale(desc) if latent \
             else 1.0 / math.sqrt(self.head_dim)
 
         def fn(params, state, tokens, positions, dest_pages, dest_slots,
-               tables, lengths, temp, top_k, top_p, key, seq_slots=None):
-            # seq_slots (b,) int32: each row's state slot; a padding row
-            # names the row past the inert one, so it reads the inert row
-            # and its write drops (stateful models)
+               tables, lengths, temp, top_k, top_p, key, *held):
+            # then seq_slots (b,) int32: each row's state slot; a padding
+            # row names the row past the inert one, so it reads the inert
+            # row and its write drops (stateful models); and ring_dest (b,),
+            # ring_tables (b, ring_pages): where the row's K/V goes in the
+            # window group, and its ring (models with window layers)
+            held = list(held)
+            seq_slots = held.pop(0) if stateful else None
+            ring_dest, ring_tables = held if windowed else (None, None)
             b = tokens.shape[0]
             kv, slots = state if stateful else (state, ())
             x = _lm_embed(desc, params, tokens, positions)       # (b, C)
 
-            def attention(pages, q, k, v):
+            def attention(pages, q, k, v, window):
                 kp, vp = pages
-                kp = kp.at[dest_pages, dest_slots, :lanes].set(
+                # a window layer: the ring's pages, and each row's first
+                # live key
+                dest, tbl, starts = (
+                    ring_dest, ring_tables, jnp.maximum(lengths - window, 0)
+                ) if window else (dest_pages, tables, None)
+                kp = kp.at[dest, dest_slots, :lanes].set(
                     k.reshape(b, lanes).astype(kp.dtype), mode="drop")
-                vp = vp.at[dest_pages, dest_slots, :lanes].set(
+                vp = vp.at[dest, dest_slots, :lanes].set(
                     v.reshape(b, lanes).astype(vp.dtype), mode="drop")
-                return paged_attention(q, kp, vp, tables, lengths,
-                                       sm_scale=scale, kv_heads=kvh), (kp, vp)
+                return paged_attention(
+                    q, kp, vp, tbl, lengths, sm_scale=scale, kv_heads=kvh,
+                    starts=starts), (kp, vp)
 
             def latent_attention(pages, q, row, layer):
                 # absorbed: the key up-projection folded into the query, the
@@ -1414,7 +1652,8 @@ class TransformerLMEngine:
             label="%s:b%d" % (kind, bucket), example_args=example_args)
 
     # -- driving -----------------------------------------------------------
-    def _prefill_args(self, tokens, page_row, sampling, key, slot):
+    def _prefill_args(self, tokens, page_row, sampling, key, slot,
+                      ring_row):
         lp = bucket_for(len(tokens), self.prefill_buckets)
         if lp is None:
             raise MXNetError("prompt of %d tokens overflows the prefill "
@@ -1431,13 +1670,21 @@ class TransformerLMEngine:
             # no slot named (warm-up): the row past the inert one, dropped
             args += (_np.int32(self.state_slots + 1 if slot is None
                                else slot),)
+        if self.window:
+            # no ring named (warm-up): page 0, which nobody holds yet
+            args += (_np.zeros(self.ring_pages, _np.int32) if ring_row is None
+                     else _np.asarray(ring_row, _np.int32),)
         return lp, args
 
-    def prefill(self, tokens, page_row, sampling, key, slot=None):
+    def prefill(self, tokens, page_row, sampling, key, slot=None,
+                ring_row=None):
         """Run one prompt through its padded prefill bucket; writes the
-        prompt's K/V into `page_row`'s pages and its convolution state into
-        state slot `slot`, and returns the sampled first token (int)."""
-        lp, args = self._prefill_args(tokens, page_row, sampling, key, slot)
+        prompt's K/V into `page_row`'s pages (a window layer's, what its
+        ring still holds of them, into `ring_row`'s) and its convolution
+        state into state slot `slot`, and returns the sampled first token
+        (int)."""
+        lp, args = self._prefill_args(tokens, page_row, sampling, key, slot,
+                                      ring_row)
         tok, state = self._prefill_exe(lp, lambda: args)(*args)
         self._set_state(state)
         with _goodput.phase("prefill_wait"):    # blocked on the device
@@ -1448,7 +1695,8 @@ class TransformerLMEngine:
         return int(out[0])
 
     def _decode_args(self, tokens, positions, dest_pages, dest_slots, tables,
-                     lengths, temps, top_ks, top_ps, key, seq_slots):
+                     lengths, temps, top_ks, top_ps, key, seq_slots,
+                     ring_dest, ring_tables):
         args = (self._params, self._state(), tokens, positions, dest_pages,
                 dest_slots, tables, lengths, temps, top_ks, top_ps, key)
         if self.conv_layers:
@@ -1457,19 +1705,30 @@ class TransformerLMEngine:
                 seq_slots = _np.full(len(tokens), self.state_slots + 1,
                                      _np.int32)
             args += (_np.asarray(seq_slots, _np.int32),)
+        if self.window:
+            # no rings named (warm-up): writes past the group's pages, dropped
+            if ring_dest is None:
+                ring_dest = _np.full(len(tokens), self.window_pages, _np.int32)
+                ring_tables = _np.zeros((len(tokens), self.ring_pages),
+                                        _np.int32)
+            args += (_np.asarray(ring_dest, _np.int32),
+                     _np.asarray(ring_tables, _np.int32))
         return args
 
     def decode_step(self, tokens, positions, dest_pages, dest_slots,
                     tables, lengths, temps, top_ks, top_ps, key,
-                    seq_slots=None):
+                    seq_slots=None, ring_dest=None, ring_tables=None):
         """One token for every row (rows with length 0 are inert padding:
         their K/V and state writes drop and their sampled token is
-        discarded). Returns an int32 numpy array of next tokens; a model
-        with expert layers leaves the step's (pairs, experts hit, busiest
-        expert's pairs) in ``last_moe``."""
+        discarded). ``dest_pages`` and ``tables`` are of the growing page
+        group; a model with window layers is also told each row's page of
+        the window group to write (``ring_dest``) and its ring
+        (``ring_tables``, (b, ring_pages)). Returns an int32 numpy array of
+        next tokens; a model with expert layers leaves the step's (pairs,
+        experts hit, busiest expert's pairs) in ``last_moe``."""
         args = self._decode_args(tokens, positions, dest_pages, dest_slots,
                                  tables, lengths, temps, top_ks, top_ps, key,
-                                 seq_slots)
+                                 seq_slots, ring_dest, ring_tables)
         out, state = self._decode_exe(len(tokens), lambda: args)(*args)
         self._set_state(state)
         with _goodput.phase("decode_wait"):     # blocked on the device
@@ -1481,24 +1740,26 @@ class TransformerLMEngine:
 
     # test-only: the logits both programs sample from, through the same
     # pages and slots (tests/test_lfm2.py compares them with a reference)
-    def prefill_logits(self, tokens, page_row, slot=None):
+    def prefill_logits(self, tokens, page_row, slot=None, ring_row=None):
         """(len(tokens), V) float32 logits of a prompt's every position;
         writes pages and slot as `prefill` does."""
         lp, args = self._prefill_args(tokens, page_row, (0.0, 0, 1.0),
-                                      _random.next_key(), slot)
+                                      _random.next_key(), slot, ring_row)
         logits, state = self._prefill_exe(lp, lambda: args, True)(*args)
         self._set_state(state)
         return _np.asarray(logits)[:len(tokens)]
 
     def decode_logits(self, tokens, positions, dest_pages, dest_slots,
-                      tables, lengths, seq_slots=None):
+                      tables, lengths, seq_slots=None, ring_dest=None,
+                      ring_tables=None):
         """(b, V) float32 logits of one decode step; writes as
         `decode_step` does."""
         b = len(tokens)
         args = self._decode_args(
             tokens, positions, dest_pages, dest_slots, tables, lengths,
             _np.zeros(b, _np.float32), _np.zeros(b, _np.int32),
-            _np.ones(b, _np.float32), _random.next_key(), seq_slots)
+            _np.ones(b, _np.float32), _random.next_key(), seq_slots,
+            ring_dest, ring_tables)
         logits, state = self._decode_exe(b, lambda: args, True)(*args)
         self._set_state(state)
         return _np.asarray(logits)
@@ -1540,6 +1801,8 @@ _LM_ARCHS = {
                        "TransformerLM"),
     "lfm2": ("mxnet_tpu.gluon.model_zoo.lfm2", "Lfm2LM"),
     "gigachat3": ("mxnet_tpu.gluon.model_zoo.gigachat3", "GigaChat3LM"),
+    "smallthinker": ("mxnet_tpu.gluon.model_zoo.smallthinker",
+                     "SmallThinkerLM"),
 }
 # dtypes numpy's .npy cannot name are written as these views of their bits
 _STORED_AS = {"bfloat16": "uint16"}
@@ -1720,6 +1983,7 @@ class ServedLM:
                 worker_args = ["--generate", os.fspath(prefix)]
                 flag_for = {"num_pages": "--kv-pages",
                             "page_size": "--kv-page-size",
+                            "window_pages": "--kv-window-pages",
                             "max_prompt": "--max-prompt",
                             "max_new_tokens": "--max-new-tokens",
                             "max_batch": "--max-batch"}
@@ -1876,6 +2140,10 @@ class ServedLM:
             out["kv"] = {"pages_total": alloc.num_pages,
                          "pages_used": alloc.used_pages,
                          "page_size": alloc.page_size}
+            ring = self._scheduler.window_allocator
+            if ring is not None:
+                out["kv"].update(window_pages_total=ring.num_pages,
+                                 window_pages_used=ring.used_pages)
         if self._pool is not None:
             out["pool"] = self._pool.describe()
         return out

@@ -402,7 +402,8 @@ def _generate_worker_main(args):
     engine = TransformerLMEngine(
         lm=load_lm(args.generate), num_pages=args.kv_pages,
         page_size=args.kv_page_size, max_prompt=args.max_prompt,
-        max_new_tokens=args.max_new_tokens, max_batch=args.max_batch)
+        max_new_tokens=args.max_new_tokens, max_batch=args.max_batch,
+        window_pages=args.kv_window_pages)
     sched = GenerateScheduler(engine, name="replica%d" % args.replica,
                               warm=not args.no_warm)
     compile_entries = _compile.keys_since(compile_cursor)
@@ -531,6 +532,7 @@ def worker_main(argv=None):
                         "through the continuous-batching scheduler")
     p.add_argument("--kv-pages", type=int, default=None)
     p.add_argument("--kv-page-size", type=int, default=None)
+    p.add_argument("--kv-window-pages", type=int, default=None)
     p.add_argument("--max-prompt", type=int, default=None)
     p.add_argument("--max-new-tokens", type=int, default=None)
     args = p.parse_args(argv)

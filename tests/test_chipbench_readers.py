@@ -19,3 +19,7 @@ from chipbench.tests.test_cell_lfm2 import *  # noqa: F401,F403,E402
 # the GigaChat3 cell likewise (four rehearsals), `mla_moe_work`'s counts and
 # the four readers it brings
 from chipbench.tests.test_cell_gigachat3 import *  # noqa: F401,F403,E402
+# the SmallThinker cell likewise (five rehearsals: two page groups, a window
+# of 8 and rings of 3 pages), `smallthinker_work`'s counts and the five
+# readers it brings
+from chipbench.tests.test_cell_smallthinker import *  # noqa: F401,F403,E402

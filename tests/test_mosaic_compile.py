@@ -96,6 +96,32 @@ def _paged_gqa(b, h, kv, d, n_pages, maxp, ps, dtype):
                 ((b,), jnp.int32)]
 
 
+def _paged_ring(b, h, kv, d, n_pages, ring, ps, dtype):
+    """A window layer's call: the table is a ring of ``ring`` pages and each
+    sequence names its first live key."""
+    cp = -(-kv * d // 128) * 128
+    assert pk._paged_kernel_takes(d, ps, cp, dtype, h // kv, b, ring)
+
+    def fn(q, kp, vp, tables, lengths, starts):
+        return pk.paged_attention(q, kp, vp, tables, lengths, kv_heads=kv,
+                                  starts=starts)
+
+    return fn, [((b, h, d), dtype), ((n_pages, ps, cp), dtype),
+                ((n_pages, ps, cp), dtype), ((b, ring), jnp.int32),
+                ((b,), jnp.int32), ((b,), jnp.int32)]
+
+
+def _prompt(l, h, kv, d, window, dtype):
+    """A whole prompt's attention through jax's splash-attention kernel:
+    the triangle of a full layer, or a window layer's band."""
+    assert pk._prompt_kernel_takes(l, d)
+
+    def fn(q, k, v):
+        return pk.prompt_attention(q, k, v, d ** -0.5, window)
+
+    return fn, [((l, h, d), dtype), ((l, kv, d), dtype), ((l, kv, d), dtype)]
+
+
 def _latent(b, h, rank, rot, n_pages, maxp, ps, dtype):
     """The latent (MLA) decode kernel on a pool of compressed rows: (pages,
     page_size, Cp) with Cp = rank + rot rounded up to the lane tile."""
@@ -110,7 +136,7 @@ def _latent(b, h, rank, rot, n_pages, maxp, ps, dtype):
                 ((b, maxp), jnp.int32), ((b,), jnp.int32)]
 
 
-def _moe(n, c, f, e, k, dtype, held=None, groups=1, kept=1):
+def _moe(n, c, f, e, k, dtype, held=None, groups=1, kept=1, **router):
     """The drop-free expert layer (routing in XLA, the grouped product a
     Mosaic kernel) over n rows."""
     from mxnet_tpu.ops.contrib import moe_tile_rows, sigmoid_topk_moe
@@ -120,7 +146,7 @@ def _moe(n, c, f, e, k, dtype, held=None, groups=1, kept=1):
 
     def fn(x, wg, bias, w1, w3, w2, valid):
         return sigmoid_topk_moe(x, wg, bias, w1, w3, w2, k=k, valid=valid,
-                                n_group=groups, topk_group=kept)
+                                n_group=groups, topk_group=kept, **router)
 
     return fn, [((n, c), dtype), ((e, c), dtype), ((e,), dtype),
                 ((held, f, c), dtype), ((held, f, c), dtype),
@@ -184,6 +210,29 @@ CASES = {
         lambda: _moe(128, 7168, 2048, 256, 8, bf16, 16, 8, 4),
     "moe-gigachat3-prefill4096-bf16":
         lambda: _moe(4096, 7168, 2048, 256, 8, bf16, 16, 8, 4),
+    # chipbench/configs/smallthinker_21b_a3b.json: 28 query heads on 4 KV
+    # heads of 128 (7 a KV head), pages of 64 tokens, the cell's top decode
+    # bucket; a full layer's table of 204 pages and a window layer's ring of
+    # 65 with its first live keys
+    "paged-gqa-smallthinker-full-bf16":
+        lambda: _paged_gqa(64, 28, 4, 128, 9216, 204, 64, bf16),
+    "paged-ring-smallthinker-bf16":
+        lambda: _paged_ring(64, 28, 4, 128, 3840, 65, 64, bf16),
+    "paged-ring-tiny-f32": lambda: _paged_ring(4, 4, 2, 32, 64, 3, 8, f32),
+    # the same configuration's expert layer: 64 ReGLU experts of 768 at
+    # width 2560, 6 a token by a softmax over the selected; a decode batch
+    # of 64 and the 8192 prompt bucket
+    # and its prompts' attention at the 8192 bucket: 28 / 4 heads of 128
+    "prompt-smallthinker-full-bf16":
+        lambda: _prompt(8192, 28, 4, 128, None, bf16),
+    "prompt-smallthinker-window-bf16":
+        lambda: _prompt(8192, 28, 4, 128, 4096, bf16),
+    "moe-smallthinker-decode64-bf16":
+        lambda: _moe(64, 2560, 768, 64, 6, bf16, scores="softmax_selected",
+                     activation="relu"),
+    "moe-smallthinker-prefill8192-bf16":
+        lambda: _moe(8192, 2560, 768, 64, 6, bf16, scores="softmax_selected",
+                     activation="relu"),
 }
 
 
@@ -194,6 +243,7 @@ KERNEL_NAMES = {
     "paged": ("paged_attention_decode",),
     "latent": ("paged_latent_attention_decode",),
     "moe": ("moe_grouped_ffn",),
+    "prompt": ("splash_mqa_fwd_no_residuals",),
 }
 
 
